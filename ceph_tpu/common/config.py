@@ -346,9 +346,7 @@ SCHEMA: dict[str, Option] = {
 _SOURCES = ("default", "file", "env", "runtime", "override")
 
 # harness env vars that share the prefix but are not config options
-_RESERVED_ENV = frozenset(
-    {"CEPH_TPU_TEST_PLATFORM", "CEPH_TPU_LOCKDEP"}
-)
+_RESERVED_ENV = frozenset({"CEPH_TPU_LOCKDEP"})
 
 
 class Config:

@@ -348,8 +348,8 @@ def decode_batch(
     object — DeviceBuf tokens (device-born, zero extra transfer to
     register resident) when the device backend ran, numpy arrays on
     the host fallback.  Byte-identical to the per-object
-    ``ec._decode`` repair by construction; ANY batched-path failure
-    degrades to it.
+    ``ec._decode`` repair by construction; a group whose survivors
+    are ragged or unaligned degrades to it (device errors propagate).
 
     Each coalesced dispatch counts in
     ``l_tpu_batch_decode_{dispatches,ops_per_dispatch}``.
@@ -404,10 +404,11 @@ def decode_batch(
                 for i, rec in zip(idxs, outs):
                     out[i] = _wrap_decoded(rec, want)
                 batched = True
-            except Exception:  # noqa: BLE001 — batching is an
-                # optimization: any device/shape/solve failure
-                # degrades this group to the per-object repair path,
-                # never drops or corrupts an object
+            except ErasureCodeError:
+                # mixed geometry (ragged or unaligned survivors): this
+                # group takes the per-object repair path.  A backend,
+                # compile or runtime error is not caught — the caller
+                # asked for the device and must hear that it failed
                 batched = False
         if not batched:
             # per-object repair loop: one host-path flight-recorder

@@ -17,11 +17,4 @@ Importing this module registers the ``jax`` erasure-code backend.
 
 from .ec_backend import JaxBackend, get_jax_backend  # noqa: F401
 
-# persistent compilation cache (CEPH_TPU_COMPILE_CACHE): configured
-# before any kernel compiles so cold starts replay prior processes'
-# programs (ops/residency.configure_compile_cache; no-op unset)
-from .residency import configure_compile_cache as _configure_compile_cache
-
-_configure_compile_cache()
-
 __all__ = ["JaxBackend", "get_jax_backend"]

@@ -26,14 +26,11 @@ from .profiler import dispatch_profiler, record_pad
 
 
 def _on_tpu() -> bool:
+    # a backend that cannot initialise raises here: the caller asked
+    # for the device (backend=jax), so that is an error, not "no TPU"
     import jax
 
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        # a configured-but-unreachable accelerator plugin raises from
-        # the probe itself; that is "no TPU", not a crash
-        return False
+    return jax.default_backend() == "tpu"
 
 
 import functools

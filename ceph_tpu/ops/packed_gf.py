@@ -167,6 +167,12 @@ def _packed_call(
 ):
     kernel = _make_kernel(rows, n_in, m_out)
 
+    def block_index(i):
+        # int32 on purpose: crush/jaxmap.py turns jax_enable_x64 on for
+        # the whole process, a bare ``0`` then traces as i64 and Mosaic
+        # refuses the index map ('func.return' (i64, i32))
+        return jnp.int32(0), i
+
     @jax.jit
     def run(*xs):  # n_in arrays of (1, nwords) u32
         n4 = xs[0].shape[1]
@@ -180,11 +186,11 @@ def _packed_call(
             kernel,
             grid=(n4 // tile,),
             in_specs=[
-                pl.BlockSpec((1, tile), lambda i: (0, i))
+                pl.BlockSpec((1, tile), block_index)
                 for _ in range(n_in)
             ],
             out_specs=[
-                pl.BlockSpec((1, tile), lambda i: (0, i))
+                pl.BlockSpec((1, tile), block_index)
                 for _ in range(m_out)
             ],
             out_shape=[

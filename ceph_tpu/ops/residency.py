@@ -1,8 +1,8 @@
 """Device-resident payload plane — upload once, reuse across stages.
 
-The measured motivation (BENCH_r04, ROADMAP open item 1): the encode
-kernels run at 134 GB/s but end-to-end storage throughput is
-0.012 GB/s because every stage — EC encode, deep-scrub crc32c, EC
+The motivation (ROADMAP S1): the encode kernels are orders of
+magnitude faster than end-to-end storage throughput because every
+stage — EC encode, deep-scrub crc32c, EC
 re-encode verify — does its own host→device ``device_put``, compute,
 sync, fetch.  The reference amortizes the analogous cost (per-call
 SIMD table setup) by keeping the plugin boundary coarse
@@ -28,10 +28,9 @@ Three pieces live here:
   batch axes to powers of two so coalesced writes and CRUSH remaps
   replay compiled programs instead of compiling per ragged shape;
   ``note_shape`` feeds the reuse into the existing
-  ``l_tpu_compile_cache_{hit,miss}`` counters, and
-  ``configure_compile_cache`` points JAX's persistent compilation
-  cache at ``$CEPH_TPU_COMPILE_CACHE`` so the 4-6s cold CRUSH
-  compile approaches the 0.64s cached-replay rate across processes.
+  ``l_tpu_compile_cache_{hit,miss}`` counters (the persistent
+  compilation cache itself is set by the entry points,
+  common/compile_cache.py).
 """
 
 from __future__ import annotations
@@ -374,34 +373,3 @@ def note_shape(site: str, *shape) -> bool:
 
     record_compile(hit)
     return hit
-
-
-# -- persistent compilation cache --------------------------------------------
-
-_compile_cache_dir: str | None = None
-
-
-def configure_compile_cache() -> str | None:
-    """Point JAX's persistent compilation cache at
-    ``$CEPH_TPU_COMPILE_CACHE`` (idempotent; returns the active dir or
-    None).  Cold CRUSH compile+first-batch costs 4-6s on this mount;
-    a warm persistent cache replays in ~0.64s
-    (``crush_remap_cached_sec``, BENCH_r04) — this extends that replay
-    across process boundaries."""
-    global _compile_cache_dir
-    path = os.environ.get("CEPH_TPU_COMPILE_CACHE")
-    if not path or _compile_cache_dir == path:
-        return _compile_cache_dir
-    try:
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", path)
-        # cache every program: the CRUSH kernels are large, but the
-        # bucketed encode programs are small and just as hot
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        _compile_cache_dir = path
-    except Exception:  # noqa: BLE001 — an old jax without the knobs
-        # (or a broken backend) must not take the import down
-        return None
-    return _compile_cache_dir

@@ -36,9 +36,9 @@ implementation.  ``batch_compare`` is the deep-scrub re-encode
 verifier: stored shard bytes vs re-encoded shard bytes in one
 device-side any-mismatch reduce.
 
-Everything degrades to the native-C oracle when the device backend is
-unavailable (``backend="oracle"`` forces it), so scrub itself never
-depends on an accelerator being attached.
+``backend="oracle"`` selects the native-C loop; otherwise the JAX
+backend runs them, and a backend, compile or runtime error propagates —
+nothing here hands the work to the host behind the caller's back.
 """
 
 from __future__ import annotations
@@ -341,8 +341,8 @@ def batch_crc32c(
 
     ``inits`` is a scalar seed or a per-buffer sequence (ceph_crc32c
     running-crc semantics; the EC HashInfo convention seeds with
-    0xffffffff).  ``backend``: None = device with oracle fallback,
-    "device" = device or raise, "oracle" = the native C loop.
+    0xffffffff).  ``backend``: "oracle" = the native C loop, anything
+    else the device (errors propagate).
 
     Entries may be host bytes OR ``ops.residency.DeviceBuf`` tokens —
     a resident buffer (e.g. a shard the EC write path just encoded)
@@ -357,13 +357,7 @@ def batch_crc32c(
     inits = [int(x) & 0xFFFFFFFF for x in inits]
     if backend == "oracle":
         return _oracle(buffers, inits)
-    try:
-        return _device_crc32c(buffers, inits)
-    except Exception:  # noqa: BLE001 — no accelerator / broken
-        # runtime must never fail a scrub; the oracle is byte-exact
-        if backend == "device":
-            raise
-        return _oracle(buffers, inits)
+    return _device_crc32c(buffers, inits)
 
 
 def _device_crc32c(buffers, inits) -> np.ndarray:
@@ -469,36 +463,32 @@ def batch_compare(stored, expected, *, backend: str | None = None):
         len(stored[i]) + len(expected[i]) for i in same_len
     )
     if backend != "oracle":
-        try:
-            ks = _kstats()
-            with ks.timed(
-                "scrub_verify", bytes_in=total
-            ) as kt, dispatch_profiler().dispatch(
-                "compare", backend="jax"
-            ) as dp:
-                dp.set_ops(len(same_len))
-                dp.add_bytes_in(total)
-                dp.add_pad(2 * bwidth * len(same_len) - total)
-                with dp.stage("upload"):
-                    a_dev = _gather_rows(
-                        [stored[i] for i in same_len], bwidth,
-                        align_right=False,
-                    )
-                    b_dev = _gather_rows(
-                        [expected[i] for i in same_len], bwidth,
-                        align_right=False,
-                    )
-                note_shape("scrub_verify", len(same_len), bwidth)
-                with dp.stage("compute"):
-                    vdev = _compare_call(bwidth)(a_dev, b_dev)
-                with dp.stage("sync"):
-                    verdict = np.asarray(vdev)
-                kt.bytes_out = verdict.nbytes
-            out[same_len] = verdict
-            return out
-        except Exception:  # noqa: BLE001 — fall through to numpy
-            if backend == "device":
-                raise
+        ks = _kstats()
+        with ks.timed(
+            "scrub_verify", bytes_in=total
+        ) as kt, dispatch_profiler().dispatch(
+            "compare", backend="jax"
+        ) as dp:
+            dp.set_ops(len(same_len))
+            dp.add_bytes_in(total)
+            dp.add_pad(2 * bwidth * len(same_len) - total)
+            with dp.stage("upload"):
+                a_dev = _gather_rows(
+                    [stored[i] for i in same_len], bwidth,
+                    align_right=False,
+                )
+                b_dev = _gather_rows(
+                    [expected[i] for i in same_len], bwidth,
+                    align_right=False,
+                )
+            note_shape("scrub_verify", len(same_len), bwidth)
+            with dp.stage("compute"):
+                vdev = _compare_call(bwidth)(a_dev, b_dev)
+            with dp.stage("sync"):
+                verdict = np.asarray(vdev)
+            kt.bytes_out = verdict.nbytes
+        out[same_len] = verdict
+        return out
     with dispatch_profiler().dispatch(
         "compare", backend="cpu"
     ) as dp:

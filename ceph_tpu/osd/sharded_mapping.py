@@ -48,20 +48,24 @@ def sharded_batch_do_rule(
     if weights is None:
         weights = np.full(max(cm.max_devices, 1), 0x10000, np.int32)
     xs_np = np.asarray(xs, dtype=np.int32)
-    padded, n = meshmod.pad_to_devices(xs_np, dmesh.n)
     t0 = time.perf_counter()
-    xs_dev = jax.device_put(jnp.asarray(padded), dmesh.batch_spec(1))
     wv = jnp.asarray(weights, dtype=jnp.int32)
     fn, tables = jaxmap.batched_rule_call(
         cm, ruleno, result_max, weights
     )
-    res, counts, ok = fn(xs_dev, wv, *tables)
-    # host-side re-assembly: gather every shard, drop the pad lanes
-    res = np.asarray(res)[:n]
-    counts = np.asarray(counts)[:n]
-    ok = np.asarray(ok)[:n]
+    spec = dmesh.batch_spec(1)
+
+    def dispatch(part):
+        padded, _ = meshmod.pad_to_devices(part, dmesh.n)
+        return fn(jax.device_put(padded, spec), wv, *tables)
+
+    # host-side re-assembly: gather every shard, drop the pad lanes;
+    # every device sees at most CHUNK_LANES lanes per program
+    res, counts, ok = jaxmap.map_chunked(
+        dispatch, xs_np, jaxmap.CHUNK_LANES * dmesh.n
+    )
     meshmod.record_shard_dispatch(
-        dmesh, "crush", padded.nbytes, time.perf_counter() - t0
+        dmesh, "crush", xs_np.nbytes, time.perf_counter() - t0
     )
     return jaxmap.apply_oracle_fallback(
         cm, ruleno, xs_np, res, counts, ok, result_max, weights

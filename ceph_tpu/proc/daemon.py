@@ -208,6 +208,11 @@ def main(argv=None) -> int:
     signal.signal(signal.SIGTERM, _sig)
     signal.signal(signal.SIGINT, _sig)
 
+    if kind == "osd":
+        # the one role whose pools may ask for backend=jax
+        from ..common.compile_cache import configure_compile_cache
+
+        configure_compile_cache()
     daemon = _BOOTERS[kind](spec, int(idx))
     try:
         while not stop["flag"]:

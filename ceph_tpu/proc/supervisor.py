@@ -42,6 +42,10 @@ from ..common.perf_counters import PerfCountersBuilder
 from .spec import ClusterSpec
 
 REPO_ROOT = pathlib.Path(__file__).resolve().parents[2]
+
+# the one daemon role allowed to see the accelerator (None: no child
+# does — giving a child the chip is a later PR)
+CHIP_OWNER_ROLE: str | None = None
 SUPERVISOR_STATE = "supervisor.json"
 # crash reports ride several consecutive perf pushes (the OSD's
 # CRASH_RESEND_COUNT idiom): an mgr restart racing one push must not
@@ -162,7 +166,17 @@ class Supervisor:
             + os.pathsep
             + env.get("PYTHONPATH", "")
         ).rstrip(os.pathsep)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # A chip belongs to one process, so at most one role could own
+        # it; today none does (CHIP_OWNER_ROLE): every child is pinned
+        # to the CPU backend, whatever the parent's environment says,
+        # and its log says so.  A pool's backend=jax then runs JAX on
+        # the CPU in --processes mode (docs/PROCESSES.md).
+        if child.role != CHIP_OWNER_ROLE:
+            env["JAX_PLATFORMS"] = "cpu"
+            child.log_fh.write(
+                f"supervisor: {child.role} runs with JAX_PLATFORMS=cpu "
+                f"(chip owner role: {CHIP_OWNER_ROLE})\n".encode()
+            )
         env.update(self.extra_env)
         # setsid: the child leads its own process group, so teardown
         # can kill the GROUP and a wedged daemon's own subprocesses

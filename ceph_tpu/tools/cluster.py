@@ -355,6 +355,10 @@ def _cmd_start(args) -> int:
             return rc
     if args.processes:
         return _start_processes(args)
+    # thread-hosted daemons share this process's JAX runtime
+    from ..common.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     spec = {
         "dir": args.dir,
         "osds": args.osds,

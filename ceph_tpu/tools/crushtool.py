@@ -335,6 +335,10 @@ def main(argv=None) -> int:
         m = build_hierarchy(num_osds, per_host, hpr)
     if args.tree:
         print(format_tree(m))
+    if args.backend == "jax" and (args.test or args.compare):
+        from ..common.compile_cache import configure_compile_cache
+
+        configure_compile_cache()
     rc = 0
     if args.compare:
         with open(args.compare, "rb") as f:

@@ -155,6 +155,9 @@ def run_decode(args, ec) -> tuple[float, int]:
 
 def main(argv=None) -> int:
     args = parse_args(argv)
+    from ..common.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     ec = make_code(args)
     if args.workload == "encode":
         elapsed, kb = run_encode(args, ec)

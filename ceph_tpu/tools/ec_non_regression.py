@@ -138,6 +138,10 @@ def main(argv=None) -> int:
     p.add_argument("--backend", default="",
                    help="override backend when checking (jax vs numpy)")
     args = p.parse_args(argv)
+    if args.backend == "jax":
+        from ..common.compile_cache import configure_compile_cache
+
+        configure_compile_cache()
     return create(args) if args.create else check(args)
 
 

@@ -38,6 +38,10 @@ def parse_args(argv=None):
 
 def main(argv=None) -> int:
     args = parse_args(argv)
+    if args.backend == "jax":
+        from ..common.compile_cache import configure_compile_cache
+
+        configure_compile_cache()
     parts = [int(v) for v in args.build.split(":")]
     num_osds, per_host = parts[0], parts[1]
     hpr = parts[2] if len(parts) > 2 else 0

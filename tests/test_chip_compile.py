@@ -1,0 +1,170 @@
+"""Main-path device programs compiled for a *described* TPU v5e.
+
+No chip is attached and nothing runs: the TPU compiler installed with
+JAX compiles each program at the shapes the product dispatches and
+raises what the chip's compiler would raise (Mosaic legalization,
+memory that does not fit).  Interpret-mode and CPU tests cannot see
+those faults.  A compile that passes here is not a chip run —
+``chip_smoke.py`` is.
+
+The topology is described inside a module-scoped fixture (never at
+import: only one process may load the TPU library, and every xdist
+worker imports every test file), and all of these tests live in this
+one file so one worker owns the library.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+# x64 on, as in every product process: OSDs, the in-process cluster and
+# bench.py all import the CRUSH kernel next to the EC kernels
+import ceph_tpu.crush.jaxmap as jaxmap
+
+import jax
+import jax.numpy as jnp
+
+from ceph_tpu import gf
+from ceph_tpu.ec import ErasureCodeProfile, registry_instance
+from ceph_tpu.ops import gf_matmul, packed_gf, scrub_kernels
+
+V5E_HBM_BYTES = 16 * 10**9
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure means "skip"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but not read back without the chip: keep it off around them
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _code(plugin, k, m):
+    prof = ErasureCodeProfile(k=str(k), m=str(m), w="8")
+    if plugin == "jerasure":
+        prof["technique"] = "reed_sol_van"
+    return registry_instance().factory(plugin, prof)
+
+
+def _fits(compiled) -> int:
+    ma = compiled.memory_analysis()
+    total = (
+        ma.temp_size_in_bytes
+        + ma.argument_size_in_bytes
+        + ma.output_size_in_bytes
+    )
+    assert total < V5E_HBM_BYTES, ma
+    return total
+
+
+def test_x64_is_on_as_in_the_product():
+    assert jax.config.jax_enable_x64
+
+
+@pytest.mark.parametrize(
+    "plugin,k,m", [("jerasure", 8, 3), ("jerasure", 4, 2), ("isa", 4, 2)]
+)
+def test_packed_encode_kernel(one_chip, plugin, k, m):
+    """matrix_stripes' folded word form at ec_benchmark's 64 x 1 MiB."""
+    ec = _code(plugin, k, m)
+    bm = gf.jerasure_bitmatrix(np.asarray(ec.matrix, dtype=np.int64), 8)
+    nwords = 64 * (1 << 20) // k // 4
+    call = packed_gf.prebuilt_word_call(bm)
+    compiled = call.lower(
+        *[_sds((1, nwords), jnp.uint32, one_chip) for _ in range(k)]
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+def test_packed_decode_kernel_two_erasures(one_chip):
+    """The per-op decode of a 1 MiB k=8,m=3 object with data chunks 2
+    and 5 lost (matrix_decode -> matrix_regions)."""
+    ec = _code("jerasure", 8, 3)
+    rows, _survivors = gf.make_decoding_matrix(
+        np.asarray(ec.matrix, dtype=np.int64), [2, 5], 8, 8
+    )
+    bm = gf.jerasure_bitmatrix(np.asarray(rows, dtype=np.int64), 8)
+    assert packed_gf.supports(bm, 8)
+    call = packed_gf.prebuilt_word_call(bm)
+    compiled = call.lower(
+        *[_sds((1, (1 << 20) // 8 // 4), jnp.uint32, one_chip)] * 8
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize(
+    "b,k,m,chunk",
+    [
+        (64, 8, 3, 131072),  # ec_benchmark batch, 1 MiB objects
+        (256, 4, 2, 4096),  # one 4 MiB object of the served k=4,m=2 pool
+        (1024, 4, 2, 4096),  # a coalesced group of four of them
+    ],
+)
+def test_bitplane_stripes_program(one_chip, b, k, m, chunk):
+    """What _bitplane_dispatch runs for coalesced writes and batched
+    decode (pow2-bucketed batch axis)."""
+    compiled = gf_matmul.gf_matrix_stripes.lower(
+        _sds((m * 8, k * 8), jnp.int8, one_chip),
+        _sds((b, k, chunk), jnp.uint8, one_chip),
+        w=8,
+    ).compile()
+    _fits(compiled)
+
+
+def test_crush_chunk_program_fits_the_chip(one_chip):
+    """BASELINE #5 (10,000 OSDs, 3 replicas) at the fixed lane chunk
+    every larger batch is cut into; map_chunked keeps two in flight."""
+    from ceph_tpu.tools.crushtool import build_hierarchy
+
+    cm = jaxmap.compile_map(build_hierarchy(10000, 40, 25))
+    fn, tables = jaxmap.batched_rule_call(cm, 0, 3, None)
+    compiled = fn.lower(
+        _sds((jaxmap.CHUNK_LANES,), jnp.int32, one_chip),
+        _sds((cm.max_devices,), jnp.int32, one_chip),
+        *[_sds(t.shape, t.dtype, one_chip) for t in tables],
+    ).compile()
+    assert 2 * _fits(compiled) < V5E_HBM_BYTES // 2
+
+
+@pytest.mark.parametrize(
+    "nrows,nchunks",
+    [(64, 256), (16, 1024)],  # 1 MiB EC shards; 4 MiB whole objects
+)
+def test_crc32c_program(one_chip, nrows, nchunks):
+    chunk = scrub_kernels._CHUNK
+    compiled = scrub_kernels._crc_call(chunk, nchunks).lower(
+        _sds((nrows, nchunks, chunk), jnp.uint8, one_chip),
+        _sds((chunk * 8, 32), jnp.int8, one_chip),
+        _sds((nchunks * 32, 32), jnp.int8, one_chip),
+    ).compile()
+    _fits(compiled)
+
+
+def test_compare_program(one_chip):
+    width = 1 << 20
+    compiled = scrub_kernels._compare_call(width).lower(
+        _sds((64, width), jnp.uint8, one_chip),
+        _sds((64, width), jnp.uint8, one_chip),
+    ).compile()
+    _fits(compiled)

@@ -140,7 +140,7 @@ def test_history_filters_and_summary_rollup():
 
 
 def test_breakdown_carries_contract_keys_on_zero_activity():
-    """The bench satellite: a tunnel-down/idle section still embeds
+    """The bench satellite: an idle section still embeds
     every contract key (marked by the caller's backend tag), never a
     missing-key artifact."""
     t = dispatch_profiler().totals()

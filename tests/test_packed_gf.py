@@ -1,8 +1,8 @@
 """Packed-lane GF(2^8) kernel exactness (ops/packed_gf.py).
 
 Interpret mode runs the very kernel body on CPU; the hardware path is
-exercised when CEPH_TPU_TEST_PLATFORM selects a real TPU (and by
-bench.py on every round).  Contract: bit-identical to the numpy
+compiled for a described v5e in tests/test_chip_compile.py and run on
+the chip by chip_smoke.py.  Contract: bit-identical to the numpy
 oracle for encode AND decode matrices, including the padding path.
 """
 

@@ -202,6 +202,36 @@ def test_crash_loop_cap_live_processes(tmp_path):
         sup.stop()
 
 
+def test_children_are_pinned_to_the_cpu_backend(tmp_path, monkeypatch):
+    """No role owns the chip (CHIP_OWNER_ROLE is None): whatever the
+    supervisor's own JAX_PLATFORMS says, a child gets ``cpu`` and its
+    log says so."""
+    from ceph_tpu.proc import supervisor as supmod
+
+    assert supmod.CHIP_OWNER_ROLE is None
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    sup = _unit_supervisor(tmp_path)
+    seen = tmp_path / "seen.txt"
+    child = _Child(
+        "osd.0",
+        [
+            sys.executable, "-c",
+            "import os, sys; open(sys.argv[1], 'w').write("
+            "os.environ['JAX_PLATFORMS'])",
+            str(seen),
+        ],
+    )
+    sup.children["osd.0"] = child
+    try:
+        sup._spawn(child)
+        assert child.proc.wait(timeout=30) == 0
+        assert seen.read_text() == "cpu"
+        log = sup.spec.log_path("osd.0").read_text()
+        assert "osd.0 runs with JAX_PLATFORMS=cpu" in log
+    finally:
+        sup.stop()
+
+
 def test_clean_exit_live_process_not_respawned(tmp_path):
     """A real child exiting 0 stays down: state 'exited', zero
     restarts, empty outbox."""
