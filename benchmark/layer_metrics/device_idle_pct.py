@@ -1,0 +1,6 @@
+"""Share of the traced window in which no operation ran on the
+device: 1 - union of device-op intervals / traced window."""
+
+
+def read(run):
+    return run["trace"]["idle_pct"] if run["trace"] else None
