@@ -23,6 +23,33 @@ This module is the missing collection plane:
 
 Span buffers are bounded (drop-oldest) — tracing must never be the
 thing that OOMs a daemon.
+
+One clock: every stamp is ``time.perf_counter()`` — monotonic, and the
+clock of the OSD's ``perf.tinc`` walls and of the flight recorder — so
+a duration is the difference of two such stamps.  The wall
+``start``/``end`` that ``dump_traces`` and ``assemble_tree`` sort by
+are those stamps plus one process-constant offset.  An interval that
+begins on one thread and ends on another (a queue wait), or before
+its trace id is known (a frame read), is recorded whole from its two
+stamps (``Tracer.record``).
+
+The buffer is the collection plane's, and a tracer whose ``buffered``
+is off (``tracing_enabled`` false on a daemon; a host with no surface
+that would serve one) builds no dict and keeps nothing.  Two views
+ride every span whether it is buffered or not:
+
+- a span entered as a context manager also enters
+  ``jax.profiler.TraceAnnotation("ceph:<name>")``, so a profiler
+  session holds the program's host work on the profiler's own clock
+  (jax is only looked up, never imported, here, and the class is
+  kept once found; with no session the annotation is a TraceMe
+  no-op).  Cross-thread waits are not mirrored: a gap is labelled by
+  what the host was doing;
+- on completion a span hands its name, duration and SELF time (the
+  duration less the child spans opened on its thread while it was
+  ambient) to the process-wide ``l_stage_<name>_*`` counters, where
+  the device plane has attached its counter set
+  (``ops/kernel_stats.py``).
 """
 
 from __future__ import annotations
@@ -30,9 +57,15 @@ from __future__ import annotations
 import contextlib
 import itertools
 import os
+import sys
 import threading
 import time
 from collections import deque
+
+_now = time.perf_counter
+# monotonic stamp + _WALL = wall time, for the surfaces that sort
+# spans of different daemons (one offset per process, never re-read)
+_WALL = time.time() - time.perf_counter()
 
 # role ranks used by the mgr's cross-daemon tree assembly: a span
 # with no resolvable parent attaches under the nearest earlier span
@@ -46,8 +79,14 @@ ROLE_RANK = {ROLE_CLIENT: 0, ROLE_PRIMARY: 1, ROLE_REPLICA: 2, ROLE_SHARD: 2}
 _ambient = threading.local()  # .stack: list[(Tracer, Span)]
 
 
+# span and trace ids: a random prefix per process and a counter (no
+# system call a span)
+_ID_PREFIX = os.urandom(4).hex()
+_id_seq = itertools.count(1)
+
+
 def _new_id() -> str:
-    return os.urandom(6).hex()
+    return f"{_ID_PREFIX}{next(_id_seq):06x}"
 
 
 class Span:
@@ -56,7 +95,8 @@ class Span:
 
     __slots__ = (
         "_tracer", "trace_id", "span_id", "parent_id", "daemon",
-        "name", "role", "start", "end", "tags", "events", "_done",
+        "name", "role", "t0", "t1", "tags", "events", "_done",
+        "_parent", "_child_s", "_mirror",
     )
 
     def __init__(
@@ -67,6 +107,8 @@ class Span:
         parent_id: str = "",
         role: str = "",
         tags: dict | None = None,
+        start: float | None = None,
+        parent: "Span | None" = None,
     ):
         self._tracer = tracer
         self.trace_id = trace_id
@@ -75,32 +117,59 @@ class Span:
         self.daemon = tracer.daemon
         self.name = name
         self.role = role
-        self.start = time.time()
-        self.end = 0.0
+        self.t0 = _now() if start is None else start
+        self.t1 = 0.0
         self.tags = dict(tags or {})
         self.events: list[tuple[float, str]] = []
         self._done = False
+        # the ambient span this one was opened under, on its thread:
+        # its self time is what this span's duration comes off
+        self._parent = parent
+        self._child_s = 0.0
+        self._mirror = None
+
+    # wall-clock views of the monotonic stamps
+    @property
+    def start(self) -> float:
+        return self.t0 + _WALL
+
+    @property
+    def end(self) -> float:
+        return self.t1 + _WALL if self._done else 0.0
+
+    @property
+    def duration(self) -> float:
+        return (self.t1 if self._done else _now()) - self.t0
 
     def mark_event(self, event: str) -> None:
-        self.events.append((time.time(), event))
+        self.events.append((_now(), event))
 
     def set_tag(self, key: str, value) -> None:
         self.tags[key] = value
 
-    def finish(self) -> None:
+    def finish(self, end: float | None = None) -> None:
         if self._done:
             return
         self._done = True
-        self.end = time.time()
+        self.t1 = _now() if end is None else end
+        if self._parent is not None:
+            self._parent._child_s += self.t1 - self.t0
         self._tracer._complete(self)
 
     def __enter__(self) -> "Span":
         _push(self._tracer, self)
+        annotation = _annotation or _profiler_annotation()
+        if annotation is not None:
+            self._mirror = annotation("ceph:" + self.name)
+            self._mirror.__enter__()
         return self
 
     def __exit__(self, exc_type, *exc) -> bool:
         if exc_type is not None:
             self.mark_event(f"exception: {exc_type.__name__}")
+        if self._mirror is not None:
+            self._mirror.__exit__(None, None, None)
+            self._mirror = None
         _pop(self)
         self.finish()
         return False
@@ -114,11 +183,11 @@ class Span:
             "name": self.name,
             "role": self.role,
             "start": self.start,
-            "end": self.end or time.time(),
-            "duration": (self.end or time.time()) - self.start,
+            "end": self.end or _now() + _WALL,
+            "duration": self.duration,
             "tags": dict(self.tags),
             "events": [
-                {"time": t, "event": e} for t, e in self.events
+                {"time": t + _WALL, "event": e} for t, e in self.events
             ],
         }
 
@@ -135,7 +204,7 @@ class _NullSpan:
     def set_tag(self, key: str, value) -> None:
         pass
 
-    def finish(self) -> None:
+    def finish(self, end: float | None = None) -> None:
         pass
 
     def __enter__(self):
@@ -151,8 +220,14 @@ NULL_SPAN = _NullSpan()
 class Tracer:
     """Per-daemon span factory + bounded finished-span buffer."""
 
-    def __init__(self, daemon: str, max_spans: int = 2048):
+    def __init__(
+        self, daemon: str, max_spans: int = 2048, buffered: bool = True
+    ):
         self.daemon = daemon
+        # off: finished spans feed the stage counters and the
+        # profiler mirror only (no dict, nothing kept); a daemon
+        # follows its tracing_enabled option with this
+        self.buffered = buffered
         self._lock = threading.Lock()
         self._buffer: deque[dict] = deque(maxlen=max_spans)
         self._seq = itertools.count()
@@ -175,19 +250,53 @@ class Tracer:
                 trace_id = amb.trace_id
             else:
                 trace_id = ambient_trace_id() or _new_id()
+        parent = None
         if not parent_id and isinstance(amb, Span) and (
             amb.trace_id == trace_id
         ):
             parent_id = amb.span_id
+            parent = amb
         with self._lock:
             self.spans_started += 1
-        return Span(self, name, trace_id, parent_id, role, tags)
+        return Span(
+            self, name, trace_id, parent_id, role, tags, parent=parent
+        )
+
+    def record(
+        self,
+        name: str,
+        trace_id: str,
+        start: float,
+        end: float | None = None,
+        role: str = "",
+        tags: dict | None = None,
+    ) -> None:
+        """One finished interval from explicit ``perf_counter`` stamps
+        (``end`` = now): for code that cannot hold a span open — the
+        messenger's coroutines, a wait whose trace id arrives with
+        the message.  Never ambient, never mirrored, no self-time
+        link; a no-op without a trace id."""
+        if not trace_id:
+            return
+        with self._lock:
+            self.spans_started += 1
+        Span(self, name, trace_id, "", role, tags, start).finish(end)
 
     def _complete(self, span: Span) -> None:
-        with self._lock:
-            if len(self._buffer) == self._buffer.maxlen:
-                self.spans_dropped += 1
-            self._buffer.append(span.dump())
+        if self.buffered:
+            entry = span.dump()
+            with self._lock:
+                if len(self._buffer) == self._buffer.maxlen:
+                    self.spans_dropped += 1
+                self._buffer.append(entry)
+        sink = _stage_sink
+        if sink is not None:
+            seconds = span.t1 - span.t0
+            sink(
+                span.name,
+                int(seconds * 1e9),
+                int(max(seconds - span._child_s, 0.0) * 1e9),
+            )
 
     # -- consumers ---------------------------------------------------------
     def drain(self, limit: int = 512) -> list[dict]:
@@ -218,6 +327,45 @@ class Tracer:
             lambda args: self.dump_traces(str(args.get("trace", ""))),
             "show buffered trace spans (optional arg: trace)",
         )
+
+
+# -- stage counters and the profiler mirror --------------------------------
+
+# ``sink(name, ns, self_ns)``: ops/kernel_stats.py attaches its
+# process-wide counter set here when it is first built.  A process
+# that never loads the device plane has no such set, and no surface
+# that would show one, and pays one ``is None`` a span.
+_stage_sink = None
+
+
+def set_stage_sink(sink) -> None:
+    global _stage_sink
+    _stage_sink = sink
+
+
+_annotation = None  # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` if this process has imported
+    jax (never imported from here), else None.  Found once and kept:
+    only a process still without jax looks again, one ``sys.modules``
+    lookup a span entered."""
+    global _annotation
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
+
+
+def annotate(name: str, on: bool = True):
+    """The profiler mirror alone, ``ceph:<name>``, for a section that
+    is recorded from explicit stamps (``Tracer.record``) but runs
+    without yielding on this thread; ``on`` false (the message belongs
+    to no trace) gives the null context."""
+    annotation = (_annotation or _profiler_annotation()) if on else None
+    if annotation is None:
+        return NULL_SPAN
+    return annotation("ceph:" + name)
 
 
 # -- ambient context --------------------------------------------------------
@@ -265,6 +413,20 @@ def propagate(trace_id: str):
         yield
     finally:
         _ambient.trace_id = prev
+
+
+def carry_wait(name: str, start: float) -> None:
+    """A wait that began at ``start`` on another thread has just ended
+    on this one, before the op it belongs to has a trace id: keep
+    (name, start, now) on the thread for whoever mints the id."""
+    _ambient.wait = (name, start, _now())
+
+
+def take_wait() -> tuple[str, float, float] | None:
+    """The carried wait of this thread, once (None if there is none)."""
+    wait = getattr(_ambient, "wait", None)
+    _ambient.wait = None
+    return wait
 
 
 def current_tracer() -> Tracer | None:

@@ -25,6 +25,7 @@ u16 domain).
 from __future__ import annotations
 
 import collections
+import contextlib
 import math
 from dataclasses import dataclass
 
@@ -36,6 +37,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax import lax  # noqa: E402
 
+from ..common import tracing  # noqa: E402
 from .hashing import _mix_inner  # noqa: E402
 from .ln import _tables as _ln_tables  # noqa: E402
 from .types import (  # noqa: E402
@@ -830,9 +832,10 @@ def _make_rule_fn(
                 )
                 & jnp.uint32(0xFFFF)
             )
-            L = float(1 << 48) - _crush_ln_f64(
-                u, cm.ln_tbl1, cm.ln_tbl2
-            )
+            with jax.named_scope("crush_ln"):
+                L = float(1 << 48) - _crush_ln_f64(
+                    u, cm.ln_tbl1, cm.ln_tbl2
+                )
             q0 = jnp.floor(L / jnp.where(wf > 0, wf, 1.0))
             t = q0 * wf
             q = (
@@ -1214,17 +1217,19 @@ def _make_rule_fn(
 
             return draw
 
-        def spec_descend(steps, rows, rs, valids, target):
+        def spec_descend(steps, rows, rs, valids, target, scope):
             """Batched candidate descents: each candidate draws with
             its own fixed r at every level (the crush_choose_firstn
             contract), one specialized draw round per level; returns
-            (kind, item) per candidate."""
+            (kind, item) per candidate.  Level ``d``'s draw round is
+            named ``<scope>_d<d>`` on the device (depth from the
+            descent's start: root, rack, host for the outer one)."""
             kinds = jnp.where(
                 valids, jnp.int32(_K_OVER), jnp.int32(_K_BAD)
             )
             items = jnp.full(rows.shape, NONE)
             tt = jnp.int32(target)
-            for sinfo in steps:
+            for depth, sinfo in enumerate(steps):
                 drawer = make_step_drawer(sinfo)
 
                 def one(row, r, kind, prev_it):
@@ -1251,9 +1256,10 @@ def _make_rule_fn(
                     ).astype(jnp.int32)
                     return nrow2, nk, nit
 
-                rows, kinds, items = jax.vmap(one)(
-                    rows, rs, kinds, items
-                )
+                with jax.named_scope(f"{scope}_d{depth}"):
+                    rows, kinds, items = jax.vmap(one)(
+                        rows, rs, kinds, items
+                    )
             return kinds, items
 
         def fast_firstn(plan, weightv):
@@ -1270,12 +1276,13 @@ def _make_rule_fn(
             rows0 = jnp.full((R0,), jnp.int32(plan["take_row"]))
             kinds, items = spec_descend(
                 f["outer_steps"], rows0, rvec,
-                jnp.full((R0,), True), ttype,
+                jnp.full((R0,), True), ttype, "crush_draw_outer",
             )
             if ttype == 0:
-                oisout = jax.vmap(
-                    lambda it: is_out(weightv, it, x)
-                )(items) & (kinds == _K_FOUND)
+                with jax.named_scope("crush_is_out"):
+                    oisout = jax.vmap(
+                        lambda it: is_out(weightv, it, x)
+                    )(items) & (kinds == _K_FOUND)
             else:
                 oisout = jnp.zeros((R0,), bool)
 
@@ -1304,13 +1311,15 @@ def _make_rule_fn(
                     jnp.zeros_like(pos_flat) if stable else pos_flat
                 )
                 rleaf_flat = leaf_rep + sub_flat + l_flat
-                lkinds, litems = spec_descend(
-                    f["leaf_steps"], rows_flat, rleaf_flat,
-                    valid_flat, 0,
-                )
-                lisout = jax.vmap(
-                    lambda it: is_out(weightv, it, x)
-                )(litems) & (lkinds == _K_FOUND)
+                with jax.named_scope("crush_leaf_descent"):
+                    lkinds, litems = spec_descend(
+                        f["leaf_steps"], rows_flat, rleaf_flat,
+                        valid_flat, 0, "crush_draw_leaf",
+                    )
+                    with jax.named_scope("crush_is_out"):
+                        lisout = jax.vmap(
+                            lambda it: is_out(weightv, it, x)
+                        )(litems) & (lkinds == _K_FOUND)
 
             def cond(st):
                 return ~st[0]
@@ -1450,7 +1459,10 @@ def _make_rule_fn(
                 jnp.full((R,), NONE, dtype=jnp.int32),
                 jnp.full((R,), NONE, dtype=jnp.int32),
             )
-            st = lax.while_loop(cond, body, init)
+            # the table-driven replay of the C state machine:
+            # collisions, is_out rejects, retries
+            with jax.named_scope("crush_collide_reject"):
+                st = lax.while_loop(cond, body, init)
             okf, outpos = st[1], st[3]
             out, out2 = st[9], st[10]
             return (out2 if leaf else out), outpos, okf
@@ -1606,7 +1618,8 @@ def _make_rule_fn(
                 jnp.full((R,), NONE, dtype=jnp.int32),
                 jnp.full((R,), NONE, dtype=jnp.int32),
             )
-            st = lax.while_loop(cond, body, init)
+            with jax.named_scope("crush_choose_firstn"):
+                st = lax.while_loop(cond, body, init)
             outpos = st[2]
             out, out2 = st[9], st[10]
             return (out2 if leaf else out), outpos, jnp.bool_(True)
@@ -1796,7 +1809,8 @@ def _make_rule_fn(
                 jnp.full((R,), UNDEF, dtype=jnp.int32),
                 jnp.full((R,), UNDEF, dtype=jnp.int32),
             )
-            st = lax.while_loop(cond, body, init)
+            with jax.named_scope("crush_choose_indep"):
+                st = lax.while_loop(cond, body, init)
             out, out2 = st[10], st[11]
             out = jnp.where(out == UNDEF, NONE, out)
             out2 = jnp.where(out2 == UNDEF, NONE, out2)
@@ -1955,47 +1969,49 @@ def apply_oracle_fallback(
     numpy (results, counts).  No-op (and no copy) when every lane is
     ok — the common case for any realistically-sized map.  Accepts
     the packed int16 wire form (see _batched_range) and unpacks it."""
-    res = np.asarray(res)
-    counts = np.asarray(counts)
-    if res.dtype == np.int16:
-        res32 = res.astype(np.int32)
-        res32[res == -32768] = CRUSH_ITEM_NONE
-        res = res32
-        counts = counts.astype(np.int32)
-    bad = np.nonzero(~np.asarray(ok))[0]
-    if bad.size:
-        from ..ops.kernel_stats import kernel_stats
+    with tracing.span("crush_fallback") as span:
+        res = np.asarray(res)
+        counts = np.asarray(counts)
+        if res.dtype == np.int16:
+            res32 = res.astype(np.int32)
+            res32[res == -32768] = CRUSH_ITEM_NONE
+            res = res32
+            counts = counts.astype(np.int32)
+        bad = np.nonzero(~np.asarray(ok))[0]
+        span.set_tag("lanes", int(bad.size))
+        if bad.size:
+            from ..ops.kernel_stats import kernel_stats
 
-        ks = kernel_stats()
-        ks.perf.inc(
-            ks.counter(
-                "crush", "fallback_lanes",
-                desc="lanes re-mapped by the host oracle",
-            ),
-            int(bad.size),
-        )
-        if getattr(cm.source, "mutation", 0) != cm.source_mutation:
-            raise RuntimeError(
-                "CrushMap mutated since compile_map(): the oracle "
-                "fallback would mix old-snapshot kernel results with "
-                "new-map lanes — recompile the map first"
+            ks = kernel_stats()
+            ks.perf.inc(
+                ks.counter(
+                    "crush", "fallback_lanes",
+                    desc="lanes re-mapped by the host oracle",
+                ),
+                int(bad.size),
             )
-        if weights is None:
-            weights = np.full(
-                max(cm.max_devices, 1), 0x10000, np.int32
-            )
-        wl = [int(w) for w in np.asarray(weights)]
-        res = res.copy()
-        counts = counts.copy()
-        xs = np.asarray(xs)
-        for i in bad:
-            row = cm.source.do_rule(
-                ruleno, int(xs[i]), result_max, wl
-            )
-            res[i, :] = CRUSH_ITEM_NONE
-            res[i, : len(row)] = row
-            counts[i] = len(row)
-    return res, counts
+            if getattr(cm.source, "mutation", 0) != cm.source_mutation:
+                raise RuntimeError(
+                    "CrushMap mutated since compile_map(): the oracle "
+                    "fallback would mix old-snapshot kernel results with "
+                    "new-map lanes — recompile the map first"
+                )
+            if weights is None:
+                weights = np.full(
+                    max(cm.max_devices, 1), 0x10000, np.int32
+                )
+            wl = [int(w) for w in np.asarray(weights)]
+            res = res.copy()
+            counts = counts.copy()
+            xs = np.asarray(xs)
+            for i in bad:
+                row = cm.source.do_rule(
+                    ruleno, int(xs[i]), result_max, wl
+                )
+                res[i, :] = CRUSH_ITEM_NONE
+                res[i, : len(row)] = row
+                counts[i] = len(row)
+        return res, counts
 
 
 def _spec_boost_for(weights) -> int:
@@ -2034,6 +2050,10 @@ def batched_rule_call(cm: CompiledMap, ruleno: int, result_max: int,
 CHUNK_LANES = 1 << 16
 
 
+def _no_stage(_name: str):
+    return contextlib.nullcontext()
+
+
 def map_chunked(dispatch, xs, chunk: int | None = None):
     """Run ``dispatch(xs_part) -> (res, counts, ok)`` (non-blocking
     device arrays, at least ``len(xs_part)`` rows each) over ``xs`` in
@@ -2045,8 +2065,25 @@ def map_chunked(dispatch, xs, chunk: int | None = None):
     next one computes while the previous one is fetched)."""
     chunk = chunk or CHUNK_LANES
     n = len(xs)
+    # each part is issued and fetched under the flight recorder's
+    # compute / sync stage (and so its dev_compute / dev_sync span)
+    # where a dispatch record is open on this thread: the fetch
+    # (np.asarray) is where the host waits for the device
+    from ..ops.profiler import current_dispatch
+
+    record = current_dispatch()
+    stage = record.stage if record is not None else _no_stage
+
+    def issue(part):
+        with stage("compute"):
+            return dispatch(part)
+
+    def fetch(arrays):
+        with stage("sync"):
+            return [np.asarray(a) for a in arrays]
+
     if n <= chunk:
-        return tuple(np.asarray(a)[:n] for a in dispatch(xs))
+        return tuple(a[:n] for a in fetch(issue(xs)))
     parts = []
     prev = None
     for lo in range(0, n, chunk):
@@ -2056,11 +2093,11 @@ def map_chunked(dispatch, xs, chunk: int | None = None):
             part = np.concatenate(
                 [np.asarray(part), np.full(short, part[-1], part.dtype)]
             )
-        cur = dispatch(part)
+        cur = issue(part)
         if prev is not None:
-            parts.append([np.asarray(a) for a in prev])
+            parts.append(fetch(prev))
         prev = cur
-    parts.append([np.asarray(a) for a in prev])
+    parts.append(fetch(prev))
     return tuple(
         np.concatenate([p[i] for p in parts])[:n] for i in range(3)
     )

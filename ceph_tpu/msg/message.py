@@ -53,6 +53,15 @@ class Message:
 
     TYPE = 0
     tid: int = 0
+    # for the tracing plane, set on the instance and never encoded
+    # (plain class attributes, not dataclass fields): perf_counter
+    # stamps of when the sender began encoding an enveloped message
+    # and when the read loop saw the frame header of a message whose
+    # trace id only a later layer learns; and the message type an
+    # envelope's msgr_send span is tagged with (its inner message's)
+    send_began = 0.0
+    recv_began = 0.0
+    traced_as = ""
 
     def encode_payload(self, e: Encoder) -> None:  # pragma: no cover
         pass
@@ -729,7 +738,10 @@ class MSessionOpen(Message):
 class MSessionData(Message):
     """Seq-stamped envelope: ``inner`` is a complete message frame.
     The receiver drops seq <= its in_seq (redelivery after replay)
-    and otherwise processes the inner frame as if it arrived bare."""
+    and otherwise processes the inner frame as if it arrived bare.
+    The sender stamps ``trace`` (the inner message's trace id) on the
+    instance, unencoded, so the envelope's frame write is traced as
+    the inner message's."""
 
     TYPE = 29
     seq: int = 0

@@ -41,6 +41,7 @@ import threading
 import time
 import weakref
 
+from ..common import tracing
 from .faults import FaultInjector
 from .message import Message, MessageError
 from .stack import NetworkStack
@@ -137,6 +138,12 @@ class SecureCtx:
         )
         self.recv_ctr += 1
         return plain
+
+
+def trace_of(msg: Message) -> str:
+    """The trace id a message carries ('' = none: a reply, a
+    heartbeat, a map push leave no messenger span)."""
+    return getattr(msg, "trace", "") or getattr(msg, "reqid", "")
 
 
 class Connection:
@@ -252,18 +259,38 @@ class Connection:
     async def _write_frame(
         self, msg: Message, duplicate: bool = False
     ) -> None:
+        """The encode and frame write of a message that carries a
+        trace id are one ``msgr_send`` span in the owner's tracer,
+        recorded from stamps (a coroutine must not leave a span
+        ambient across an await), its two sections that do not yield
+        mirrored into the profiler."""
+        tracer = self.msgr.tracer
+        trace = trace_of(msg) if tracer is not None else ""
         # duplication happens at MESSAGE level: each copy is sealed
         # with its own counter in secure mode, so both arrive as
         # valid frames and the receiver's dedup layers really work
         for _ in range(2 if duplicate else 1):
-            frame = msg.to_frame()
+            # a session envelope's span began with its inner frame's
+            # encode, on the sender's thread (msg/session.py)
+            t0 = msg.send_began or time.perf_counter()
+            with tracing.annotate("msgr_send", bool(trace)):
+                frame = msg.to_frame()
             async with self._send_lock:
                 # seal under the send lock: the implicit counter must
                 # match the on-wire record order
-                if self.secure is not None:
-                    frame = self.secure.seal(frame)
-                self._writer.write(frame)
+                with tracing.annotate("msgr_send", bool(trace)):
+                    if self.secure is not None:
+                        frame = self.secure.seal(frame)
+                    self._writer.write(frame)
                 await self._writer.drain()
+            if trace:
+                tracer.record(
+                    "msgr_send", trace, t0,
+                    tags={
+                        "type": msg.traced_as or type(msg).__name__,
+                        "bytes": len(frame),
+                    },
+                )
 
     async def _read_loop(self) -> None:
         try:
@@ -272,6 +299,7 @@ class Connection:
                     clen = int.from_bytes(
                         await self._reader.readexactly(4), "little"
                     )
+                    t0 = time.perf_counter()
                     # the prefix is plaintext; bound it before
                     # buffering so a tamperer can't force a multi-GiB
                     # allocation or an indefinite readexactly hang
@@ -294,6 +322,7 @@ class Connection:
                     header = await self._reader.readexactly(
                         Message.HEADER_SIZE
                     )
+                    t0 = time.perf_counter()
                     mtype, tid, plen = Message.parse_header(header)
                     if plen > MAX_FRAME_LEN:
                         raise MessageError(
@@ -307,6 +336,23 @@ class Connection:
                     body[:plen],
                     int.from_bytes(body[plen:], "little"),
                 )
+                # frame header read -> decoded: the msgr_recv span of
+                # a message that carries a trace id (not mirrored: the
+                # id is not known until the decode is done); any other
+                # keeps the start stamp, for the session layer to
+                # finish once an envelope's inner frame has named its
+                # trace
+                trace = trace_of(msg)
+                if trace and self.msgr.tracer is not None:
+                    self.msgr.tracer.record(
+                        "msgr_recv", trace, t0,
+                        tags={
+                            "type": type(msg).__name__,
+                            "bytes": len(body),
+                        },
+                    )
+                else:
+                    msg.recv_began = t0
                 with self._plock:
                     fut = self._pending.pop(tid, None)
                 if fut is not None:
@@ -388,6 +434,10 @@ class Messenger:
             )
         self.secure = secure
         self.name = name
+        # the owning daemon's common.tracing.Tracer (it sets this): a
+        # message that carries a trace id then leaves one msgr_send
+        # and one msgr_recv span in the tracers at its two ends
+        self.tracer = None
         self._loop: asyncio.AbstractEventLoop | None = None
         self._stack: NetworkStack | None = None
         self._worker = None  # the checked-out stack Worker
@@ -802,10 +852,8 @@ class Messenger:
         # message carrying a span/trace id makes it ambient for its
         # handlers, so spans they open join the sender's trace
         # without every handler re-plumbing the id
-        trace = getattr(msg, "trace", "") or getattr(msg, "reqid", "")
+        trace = trace_of(msg)
         if trace:
-            from ..common import tracing
-
             with tracing.propagate(trace):
                 self._dispatch_inner(conn, msg)
         else:

@@ -46,7 +46,8 @@ from .message import (
     MSessionData,
     MSessionOpen,
 )
-from .messenger import Connection, Dispatcher, Messenger
+from ..common import tracing
+from .messenger import Connection, Dispatcher, Messenger, trace_of
 
 ACK_EVERY = 16
 _CALL_TIMEOUT = 30.0
@@ -61,6 +62,21 @@ def _parse_inner(blob: bytes) -> Message:
         body[plen : plen + 4], "little"
     )
     return Message.from_payload(mtype, tid, payload, crc)
+
+
+def _record_recv(msgr: Messenger, env: MSessionData, inner) -> None:
+    """The one ``msgr_recv`` span of an enveloped message, now that its
+    inner frame is parsed and has named its trace: from the read loop
+    seeing the envelope's header (stamped on it) to here, the wait for
+    the dispatch strand between the two included."""
+    trace = trace_of(inner)
+    if trace and msgr.tracer is not None and env.recv_began:
+        msgr.tracer.record(
+            "msgr_recv", trace, env.recv_began,
+            tags={
+                "type": type(inner).__name__, "bytes": len(env.inner),
+            },
+        )
 
 
 class _SessionState:
@@ -84,15 +100,23 @@ class _SessionState:
         so holding the lock across it is cheap."""
         if msg.tid == 0:
             msg.tid = new_tid()
+        trace = trace_of(msg)
         with self.lock:
             self.out_seq += 1
             seq = self.out_seq
-            inner = msg.to_frame()
+            began = time.perf_counter()
+            with tracing.annotate("msgr_send", bool(trace)):
+                inner = msg.to_frame()
             self.unacked.append((seq, inner))
             if conn is not None:
                 env = MSessionData(
                     tid=new_tid(), seq=seq, inner=inner
                 )
+                if trace:
+                    # the envelope leaves under the inner message's
+                    # trace, as ONE msgr_send span that began here
+                    env.trace, env.send_began = trace, began
+                    env.traced_as = type(msg).__name__
                 try:
                     conn.send(env)
                 except (MessageError, OSError):
@@ -353,6 +377,7 @@ class SessionConnection:
                 pass
         if msg is None:
             return
+        _record_recv(self.msgr, env, msg)
         with self._plock:
             fut = self._pending.get(msg.tid)
         if fut is not None:
@@ -498,6 +523,7 @@ class _ServerSession:
                 pass
         if inner is None:
             return
+        _record_recv(self.svc.msgr, env, inner)
         with self._plock:
             fut = self._pending.get(inner.tid)
         if fut is not None:

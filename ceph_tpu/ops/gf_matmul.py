@@ -55,9 +55,10 @@ def gf_matrix_regions(
 ) -> jnp.ndarray:
     """Apply a GF(2^w) coding matrix, given as its (m·w, k·w) bitmatrix,
     to (k, nbytes) uint8 regions → (m, nbytes) uint8."""
-    bits = unpack_word_bits(regions, w)
-    out = mod2_matmul(bm, bits)
-    return pack_word_bits(out, w)
+    with jax.named_scope("ec_bitplane_matmul"):
+        bits = unpack_word_bits(regions, w)
+        out = mod2_matmul(bm, bits)
+        return pack_word_bits(out, w)
 
 
 @functools.partial(jax.jit, static_argnames=("w", "packetsize"))
@@ -98,8 +99,11 @@ def gf_matrix_stripes(
     into one device call: stripes fold into the matmul N dimension, so
     arbitrarily many stripes ride a single kernel launch."""
     b, _k, chunk = stripes.shape
-    out = gf_matrix_regions(bm, fold_stripes(stripes), w=w)
-    return unfold_stripes(out, b, chunk)
+    # the batched encode AND the decode-from-survivors entry (the
+    # matrix decides which): fold, multiply, unfold under one name
+    with jax.named_scope("ec_bitplane_stripes"):
+        out = gf_matrix_regions(bm, fold_stripes(stripes), w=w)
+        return unfold_stripes(out, b, chunk)
 
 
 @functools.lru_cache(maxsize=512)

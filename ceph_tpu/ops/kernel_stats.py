@@ -16,6 +16,16 @@ plus the compile-cache counters:
 
     l_tpu_compile_cache_hit / l_tpu_compile_cache_miss
 
+and, because this set is the one every co-hosted daemon already
+shares and reports, the stage counters that every finished tracing
+span feeds (common/tracing.py ``Tracer._complete``), one triple per
+span name, and a third counter for the names whose self time has a
+reader (``SELF_TIME_STAGES``):
+
+    l_stage_<name>_count     u64   spans of that name finished
+    l_stage_<name>_ns        u64   their durations, summed (ns)
+    l_stage_<name>_self_ns   u64   durations less same-thread children
+
 Groups registered by the instrumented modules: ``ec_encode`` /
 ``ec_decode`` (ec/stripe.py batched seam), ``gf_matmul`` /
 ``gf_bitmatrix`` (ops/ec_backend.py device dispatch), ``crush``
@@ -33,9 +43,11 @@ same way they share the one JAX runtime.
 
 from __future__ import annotations
 
+import re
 import threading
 import time
 
+from ..common import tracing
 from ..common.histogram import LATENCY_BUCKETS, LATENCY_MIN_S, log2_bounds
 from ..common.perf_counters import (
     PERFCOUNTER_HISTOGRAM,
@@ -48,6 +60,10 @@ from ..common.perf_counters import (
 # the shared log2 latency axis (common/histogram.py): every
 # l_tpu_*_lat_hist uses it, so kernel latency histograms merge with
 # the op-path ones under one bucket layout
+# span names whose self time is counted: a name goes here with its
+# reader (benchmark/layer_metrics/osd_op_self_ms_per_op.py)
+SELF_TIME_STAGES = frozenset({"osd_op"})
+
 _LAT_HIST_BOUNDS = log2_bounds(LATENCY_MIN_S, LATENCY_BUCKETS)
 
 
@@ -57,6 +73,8 @@ class KernelStats:
         self._lock = threading.Lock()
         self._cache_call_lock = threading.Lock()
         self._groups: set[str] = set()
+        # span name -> its l_stage_* counters (count, ns, self_ns|None)
+        self._stages: dict[str, tuple] = {}
         self._ensure_counter("l_tpu_compile_cache_hit", PERFCOUNTER_U64,
                              "device bitmatrix/table cache hits")
         self._ensure_counter("l_tpu_compile_cache_miss", PERFCOUNTER_U64,
@@ -146,6 +164,45 @@ class KernelStats:
                 after.hits - before.hits, after.misses - before.misses
             )
         return out
+
+    def record_stage(self, name: str, ns: int, self_ns: int) -> None:
+        """One finished tracing span of ``name`` (the sink
+        common/tracing.py calls, on every span of the process): count
+        it and add its duration (and, for a ``SELF_TIME_STAGES``
+        name, its self time) to the ``l_stage_<name>_*`` counters,
+        registered on first sight — one lock acquisition for all."""
+        stage = self._stages.get(name)
+        if stage is None:
+            stage = self._ensure_stage(name)
+        count, total, own = stage
+        with self.perf._lock:
+            count.value += 1
+            total.value += ns
+            if own is not None:
+                own.value += self_ns
+
+    def _ensure_stage(self, name: str) -> tuple:
+        base = "l_stage_" + re.sub(r"\W", "_", name)
+        wanted = [
+            (f"{base}_count", "spans finished"),
+            (f"{base}_ns", "span durations, summed (ns)"),
+        ]
+        if name in SELF_TIME_STAGES:
+            wanted.append(
+                (
+                    f"{base}_self_ns",
+                    "span durations less same-thread child spans (ns)",
+                )
+            )
+        for counter, what in wanted:
+            self._ensure_counter(
+                counter, PERFCOUNTER_U64, f"{name} {what}"
+            )
+        stage = tuple(self.perf._counters[n] for n, _ in wanted)
+        stage += (None,) * (3 - len(stage))
+        with self._lock:
+            self._stages[name] = stage
+        return stage
 
     def record_pad(self, nbytes: int) -> None:
         """Count shape-bucketing pad bytes (device-visible bytes that
@@ -239,3 +296,12 @@ def kernel_stats() -> KernelStats:
             if _instance is None:
                 _instance = KernelStats()
     return _instance
+
+
+def _record_stage(name: str, ns: int, self_ns: int) -> None:
+    kernel_stats().record_stage(name, ns, self_ns)
+
+
+# from the moment the device plane is loaded, every finished span of
+# the process counts into the shared set
+tracing.set_stage_sink(_record_stage)

@@ -182,23 +182,25 @@ def _packed_call(
             z = jnp.zeros((1, pad), dtype=jnp.uint32)
             xs = tuple(jnp.concatenate([x, z], axis=1) for x in xs)
             n4 += pad
-        outs = pl.pallas_call(
-            kernel,
-            grid=(n4 // tile,),
-            in_specs=[
-                pl.BlockSpec((1, tile), block_index)
-                for _ in range(n_in)
-            ],
-            out_specs=[
-                pl.BlockSpec((1, tile), block_index)
-                for _ in range(m_out)
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((1, n4), jnp.uint32)
-                for _ in range(m_out)
-            ],
-            interpret=interpret,
-        )(*xs)
+        # a stable name on the device op (else ``%run.N``)
+        with jax.named_scope("ec_packed_encode"):
+            outs = pl.pallas_call(
+                kernel,
+                grid=(n4 // tile,),
+                in_specs=[
+                    pl.BlockSpec((1, tile), block_index)
+                    for _ in range(n_in)
+                ],
+                out_specs=[
+                    pl.BlockSpec((1, tile), block_index)
+                    for _ in range(m_out)
+                ],
+                out_shape=[
+                    jax.ShapeDtypeStruct((1, n4), jnp.uint32)
+                    for _ in range(m_out)
+                ],
+                interpret=interpret,
+            )(*xs)
         if pad:
             outs = [o[:, : n4 - pad] for o in outs]
         return outs

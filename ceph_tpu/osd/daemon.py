@@ -514,7 +514,9 @@ class OSD(Dispatcher):
         self.tracer = tracing.Tracer(
             f"osd.{whoami}",
             max_spans=int(self.config.get("tracing_max_spans")),
+            buffered=bool(self.config.get("tracing_enabled")),
         )
+        self.messenger.tracer = self.tracer  # msgr_send/msgr_recv
         self.admin = None
         if admin_socket_path:
             self.admin = AdminSocket(
@@ -2391,6 +2393,7 @@ class OSD(Dispatcher):
             # original outdata so retried CALLs keep their result
             return pg.reqid_cache[msg.reqid][1]
         existed = self.store.exists(pg.cid, store_oid)
+        tracing.current_span().set_tag("created", not existed)
         if msg.op == OSD_OP_DELETE and not existed:
             # only the SAME client op retried is idempotent; a fresh
             # delete of a missing object is -ENOENT (rados semantics)
@@ -2573,12 +2576,15 @@ class OSD(Dispatcher):
         # not leave a phantom entry in the in-memory log
         saved_last = pg.info.last_update
         pg.info.last_update = version
-        for txn in {id(t): t for t in txn_by_osd.values()}.values():
-            self._persist_entry(pg, entry, txn)
-            self._persist_info(pg, txn)
+        with tracing.span("txn_build"):
+            for txn in {id(t): t for t in txn_by_osd.values()}.values():
+                self._persist_entry(pg, entry, txn)
+                self._persist_info(pg, txn)
+            entry_blob = _encode_entry(entry)
         commit_t0 = time.perf_counter()
         try:
-            self.store.queue_transaction(txn_by_osd[self.whoami])
+            with tracing.span("store_commit"):
+                self.store.queue_transaction(txn_by_osd[self.whoami])
         except StoreError:
             pg.info.last_update = saved_last
             pg.seq -= 1
@@ -2599,36 +2605,36 @@ class OSD(Dispatcher):
             pg.reqid_cache[msg.reqid] = (version, outdata)
             while len(pg.reqid_cache) > 4 * self.log_keep:
                 pg.reqid_cache.pop(next(iter(pg.reqid_cache)))
-        entry_blob = _encode_entry(entry)
         failed: list[int] = []
-        for osd, txn in txn_by_osd.items():
-            if osd == self.whoami:
-                continue
-            if self._cur_op is not None:
-                self._cur_op.mark_event(f"sub_op_sent osd.{osd}")
-            tracing.current_span().mark_event(
-                f"sub_op_sent osd.{osd}"
-            )
-            try:
-                ack = self._peer_conn(osd).call(
-                    MOSDRepOp(
-                        pgid=pg.pgid, epoch=epoch, txn=txn,
-                        entry_blob=entry_blob, trace=msg.reqid,
-                    ),
-                    timeout=self.repop_timeout,
-                )
-                if isinstance(ack, MOSDRepOpReply) and not ack.ok:
-                    failed.append(osd)
-                else:
-                    if self._cur_op is not None:
-                        self._cur_op.mark_event(
-                            f"sub_op_commit_rec osd.{osd}"
-                        )
-                    tracing.current_span().mark_event(
-                        f"sub_op_commit_rec osd.{osd}"
+        # first MOSDRepOp sent -> last ack: the calls block one after
+        # another, and the per-peer events land on this span
+        with tracing.span(
+            "sub_op_wait", tags={"peers": len(txn_by_osd) - 1}
+        ) as wait:
+            for osd, txn in txn_by_osd.items():
+                if osd == self.whoami:
+                    continue
+                if self._cur_op is not None:
+                    self._cur_op.mark_event(f"sub_op_sent osd.{osd}")
+                wait.mark_event(f"sub_op_sent osd.{osd}")
+                try:
+                    ack = self._peer_conn(osd).call(
+                        MOSDRepOp(
+                            pgid=pg.pgid, epoch=epoch, txn=txn,
+                            entry_blob=entry_blob, trace=msg.reqid,
+                        ),
+                        timeout=self.repop_timeout,
                     )
-            except (MessageError, OSError):
-                failed.append(osd)
+                    if isinstance(ack, MOSDRepOpReply) and not ack.ok:
+                        failed.append(osd)
+                    else:
+                        if self._cur_op is not None:
+                            self._cur_op.mark_event(
+                                f"sub_op_commit_rec osd.{osd}"
+                            )
+                        wait.mark_event(f"sub_op_commit_rec osd.{osd}")
+                except (MessageError, OSError):
+                    failed.append(osd)
         live_failures = [
             osd for osd in failed if self.monc.osdmap.is_up(osd)
         ]
@@ -2667,37 +2673,42 @@ class OSD(Dispatcher):
         stripe range is read/encoded/shipped, gated on pg.repop_clean
         so a range write can never land on a replica whose shard may
         be stale."""
-        if msg.reqid and msg.reqid in pg.reqid_cache:
-            return pg.reqid_cache[msg.reqid][1]
-        osdmap = self.monc.osdmap
-        pool = self._pool_of(pg)
-        codec = self._ec_codec(pg)
-        ecs = self._ec_store_for(pg)
-        present = [
-            (pos, osd)
-            for pos, osd in enumerate(pg.acting)
-            if osd != CRUSH_ITEM_NONE
-            and (osd == self.whoami or osdmap.is_up(osd))
-        ]
-        if len(present) < max(codec.k, pool.min_size):
-            # the reference refuses writes below min_size (undersized)
-            raise StoreError(
-                f"pg {pg.pgid} undersized: {len(present)} shards < "
-                f"min_size {max(codec.k, pool.min_size)} (-EAGAIN)"
+        op_span = tracing.current_span()
+        with tracing.span("ec_prepare"):
+            if msg.reqid and msg.reqid in pg.reqid_cache:
+                return pg.reqid_cache[msg.reqid][1]
+            osdmap = self.monc.osdmap
+            pool = self._pool_of(pg)
+            codec = self._ec_codec(pg)
+            ecs = self._ec_store_for(pg)
+            present = [
+                (pos, osd)
+                for pos, osd in enumerate(pg.acting)
+                if osd != CRUSH_ITEM_NONE
+                and (osd == self.whoami or osdmap.is_up(osd))
+            ]
+            if len(present) < max(codec.k, pool.min_size):
+                # the reference refuses writes below min_size
+                # (undersized)
+                raise StoreError(
+                    f"pg {pg.pgid} undersized: {len(present)} shards "
+                    f"< min_size {max(codec.k, pool.min_size)} "
+                    "(-EAGAIN)"
+                )
+            try:
+                old_meta = ecs.meta(store_oid)
+            except ErasureCodeError:
+                old_meta = None
+            existed = old_meta is not None
+            op_span.set_tag("created", not existed)
+            if msg.op == OSD_OP_DELETE and not existed:
+                raise StoreError(f"no object {msg.oid} (-ENOENT)")
+            # snap context (make_writeable): the clone op copies each
+            # position's LOCAL shard, so one logged txn preserves the
+            # erasure-coded head too
+            self._maybe_clone(
+                pg, epoch, msg.oid, existed, msg.snap_seq
             )
-        try:
-            old_meta = ecs.meta(store_oid)
-        except ErasureCodeError:
-            old_meta = None
-        existed = old_meta is not None
-        if msg.op == OSD_OP_DELETE and not existed:
-            raise StoreError(f"no object {msg.oid} (-ENOENT)")
-        # snap context (make_writeable): the clone op copies each
-        # position's LOCAL shard, so one logged txn preserves the
-        # erasure-coded head too
-        self._maybe_clone(
-            pg, epoch, msg.oid, existed, msg.snap_seq
-        )
         ctx = None
         outdata = b""
         if msg.op == OSD_OP_CALL:
@@ -2723,16 +2734,20 @@ class OSD(Dispatcher):
                 and new_data is msg.data
             ):
                 # coalesced dispatch already encoded this payload
-                # (byte-identical to encode_object; tests prove it)
+                # (byte-identical to encode_object; tests prove it),
+                # under the batch's own ec_encode span
                 shards, meta = pre_encoded
             else:
-                shards, meta = codec.encode_object(new_data)
-            for pos, _osd in present:
-                txns[pos] = shard_write_txn(
-                    pg.cid, store_oid, shards[pos], meta, extra_attrs
-                )
-                if _osd == self.whoami:
-                    my_shard[:] = [shards[pos]]
+                with tracing.span("ec_encode", tags={"ops": 1}):
+                    shards, meta = codec.encode_object(new_data)
+            with tracing.span("txn_build"):
+                for pos, _osd in present:
+                    txns[pos] = shard_write_txn(
+                        pg.cid, store_oid, shards[pos], meta,
+                        extra_attrs,
+                    )
+                    if _osd == self.whoami:
+                        my_shard[:] = [shards[pos]]
 
         def remove_all() -> None:
             for pos, _osd in present:
@@ -2929,36 +2944,35 @@ class OSD(Dispatcher):
             role=tracing.ROLE_REPLICA,
             tags={"pgid": msg.pgid},
         )
-        if pg is None or pg.activated_epoch == 0:
-            # an unactivated replica must not splice mid-stream
-            # entries into an empty log (its hole-filled log could
-            # later win find_best_info's tie-break)
-            reply.ok = False
-            reply.error = "pg not activated (-EAGAIN)"
-            top.mark_event("rejected: pg not activated")
+        with span:
+            if pg is None or pg.activated_epoch == 0:
+                # an unactivated replica must not splice mid-stream
+                # entries into an empty log (its hole-filled log could
+                # later win find_best_info's tie-break)
+                reply.ok = False
+                reply.error = "pg not activated (-EAGAIN)"
+                top.mark_event("rejected: pg not activated")
+                span.mark_event("rejected: pg not activated")
+            else:
+                try:
+                    with tracing.span("store_commit"):
+                        self.store.queue_transaction(msg.txn)
+                    entry = _decode_entry(msg.entry_blob)
+                    if entry.version > pg.log.head:
+                        pg.log.append(entry)
+                    pg.info.last_update = pg.log.head
+                    pg.seq = max(pg.seq, entry.version[1])
+                    # replicas bound their logs too (the primary's
+                    # trim txn is local; unbounded replica logs would
+                    # grow forever)
+                    self._maybe_trim(pg)
+                except StoreError as e:
+                    reply.ok = False
+                    reply.error = str(e)
+                top.mark_event("applied" if reply.ok else "failed")
+                span.mark_event("applied" if reply.ok else "failed")
             top.finish()
-            span.mark_event("rejected: pg not activated")
-            span.finish()
             conn.send(reply)
-            return
-        try:
-            self.store.queue_transaction(msg.txn)
-            entry = _decode_entry(msg.entry_blob)
-            if entry.version > pg.log.head:
-                pg.log.append(entry)
-            pg.info.last_update = pg.log.head
-            pg.seq = max(pg.seq, entry.version[1])
-            # replicas bound their logs too (the primary's trim txn is
-            # local; unbounded replica logs would grow forever)
-            self._maybe_trim(pg)
-        except StoreError as e:
-            reply.ok = False
-            reply.error = str(e)
-        top.mark_event("applied" if reply.ok else "failed")
-        top.finish()
-        span.mark_event("applied" if reply.ok else "failed")
-        span.finish()
-        conn.send(reply)
 
     def _handle_query(self, conn: Connection, msg: MPGQuery) -> None:
         pg = self.pgs.get(msg.pgid)
@@ -3153,8 +3167,11 @@ class OSD(Dispatcher):
                 except (MessageError, OSError):
                     pass
                 return True
+            # the fifth field is when the op was queued: its
+            # osd_queue_wait starts here and ends on the op strand
             self._workq.enqueue(
-                self._qos_class_of(msg), cost, ("op", conn, msg, cost)
+                self._qos_class_of(msg), cost,
+                ("op", conn, msg, cost, time.perf_counter()),
             )
             return True
         if isinstance(msg, MOSDRepOp):
@@ -3704,7 +3721,7 @@ class OSD(Dispatcher):
             # cheap + lock-free: runs under the scheduler lock
             return (
                 isinstance(it, tuple)
-                and len(it) == 4
+                and len(it) == 5
                 and it[0] == "op"
                 and it[2].op == OSD_OP_WRITEFULL
                 and bool(it[2].data)
@@ -3727,9 +3744,19 @@ class OSD(Dispatcher):
         if pg is not None:
             try:
                 codec = self._ec_codec(pg)
-                encs = codec.encode_object_batch(
-                    [it[2].data for it in items]
-                )
+                # no osd_op is open yet: the batch's ec_encode is a
+                # span of its own under the FIRST folded op's trace,
+                # ambient so the dispatch's dev_* stages are its
+                # children
+                with self.tracer.start_span(
+                    "ec_encode",
+                    trace_id=items[0][2].reqid,
+                    role=tracing.ROLE_PRIMARY,
+                    tags={"pgid": pg.pgid, "ops": len(items)},
+                ):
+                    encs = codec.encode_object_batch(
+                        [it[2].data for it in items]
+                    )
                 pre = {
                     id(it[2]): enc for it, enc in zip(items, encs)
                 }
@@ -3820,6 +3847,21 @@ class OSD(Dispatcher):
         except Exception:  # noqa: BLE001 — reporting best-effort
             pass
 
+    def _note_dequeued(self, items: list) -> None:
+        """``osd_queue_wait``: ms_dispatch queued each of these ops
+        on the scheduler (it stamped the work item); the op strand
+        has now taken them off it."""
+        now = time.perf_counter()
+        for _kind, _conn, msg, _cost, queued_at in items:
+            self.tracer.record(
+                "osd_queue_wait", msg.reqid, queued_at, now,
+                role=tracing.ROLE_PRIMARY,
+                tags={
+                    "pgid": msg.pgid,
+                    "qos_class": self._qos_class_of(msg),
+                },
+            )
+
     def _process_work_item(self, item) -> None:
         kind = item[0]
         try:
@@ -3827,6 +3869,7 @@ class OSD(Dispatcher):
                 self._walk_pgs(item[1])
             elif kind == "op":
                 extra = self._coalesce_op_items(item)
+                self._note_dequeued([item] + extra)
                 if extra:
                     self._handle_op_batch([item] + extra)
                 else:
@@ -4139,11 +4182,12 @@ class OSD(Dispatcher):
             # families from the same entries
             dump.update(self.op_tracker.histogram_perf_entries())
             dump["commit_lat_hist"] = self._commit_hist.snapshot()
-            spans = (
-                self.tracer.drain()
-                if self.config.get("tracing_enabled")
-                else []
+            # tracing_enabled off: nothing is collected (the option
+            # may change while the daemon runs), nothing to push
+            self.tracer.buffered = bool(
+                self.config.get("tracing_enabled")
             )
+            spans = self.tracer.drain() if self.tracer.buffered else []
             # crash reports ride the same push (MMgrReport piggyback).
             # send() is fire-and-forget — an exception-free send does
             # NOT prove delivery — so each report rides

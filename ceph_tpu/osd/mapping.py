@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..common import tracing
 from ..crush.hashing import crush_hash32_2
 from ..crush.types import CRUSH_ITEM_NONE
 from .osdmap import (
@@ -67,24 +68,32 @@ class OSDMapMapping:
     """Caches up/acting/primaries for every PG of every pool
     (the consumer API of src/osd/OSDMapMapping.h:173-340); exposes
     reference-style perf counters (the l_osd_* analog) via
-    ``self.perf.dump()``."""
+    ``self.perf.dump()``, and traces every ``update`` as a ``remap``
+    span with one child a stage.  A host that serves the spans
+    (osdmaptool prints them) passes its ``tracer``; without one the
+    spans feed the stage counters and the profiler mirror and nothing
+    is kept."""
 
-    def __init__(self):
+    def __init__(self, tracer: tracing.Tracer | None = None):
         self.up: dict[int, np.ndarray] = {}
         self.up_primary: dict[int, np.ndarray] = {}
         self.acting: dict[int, np.ndarray] = {}
         self.acting_primary: dict[int, np.ndarray] = {}
         self.epoch = 0
         self.perf = _build_perf()
+        self.tracer = tracer or tracing.Tracer("mapping", buffered=False)
 
     # -- batch pipeline ----------------------------------------------------
     def update(self, osdmap: OSDMap, use_device: bool = True) -> None:
         """Recompute every pool's full PG mapping."""
-        self.epoch = osdmap.epoch
-        self.perf.inc("updates")
-        for pool_id, pool in osdmap.pools.items():
-            self._update_pool(osdmap, pool, use_device)
-            self.perf.inc("pgs_mapped", pool.pg_num)
+        with self.tracer.start_span(
+            "remap", tags={"epoch": osdmap.epoch}
+        ):
+            self.epoch = osdmap.epoch
+            self.perf.inc("updates")
+            for pool_id, pool in osdmap.pools.items():
+                self._update_pool(osdmap, pool, use_device)
+                self.perf.inc("pgs_mapped", pool.pg_num)
 
     def _update_pool(
         self, osdmap: OSDMap, pool: PgPool, use_device: bool
@@ -93,8 +102,9 @@ class OSDMapMapping:
 
         n = pool.pg_num
         size = pool.size
-        ps = np.arange(n, dtype=np.int64)
-        pps = pool_pps_vec(pool, ps).astype(np.int64)
+        with tracing.span("crush_inputs", tags={"pool": pool.pool_id}):
+            ps = np.arange(n, dtype=np.int64)
+            pps = pool_pps_vec(pool, ps).astype(np.int64)
 
         ks = kernel_stats()
         pgs_counter = ks.counter(
@@ -117,38 +127,45 @@ class OSDMapMapping:
         self.acting_primary[pool.pool_id] = acting_primary
 
     def _fixup(self, osdmap, pool, ps, pps, raw):
-        # _remove_nonexistent_osds + _raw_to_up_osds, fused: both drop
-        # to NONE (EC) or compact (replicated)
-        exists = np.zeros(osdmap.max_osd + 1, dtype=bool)
-        up_ok = np.zeros(osdmap.max_osd + 1, dtype=bool)
-        exists[:-1] = np.asarray(osdmap.osd_exists, dtype=bool)
-        up_ok[:-1] = exists[:-1] & np.asarray(osdmap.osd_up, dtype=bool)
-        idx = np.clip(raw, 0, osdmap.max_osd)
-        in_range = (raw >= 0) & (raw < osdmap.max_osd)
-        raw_exists = in_range & exists[idx]
-        if pool.can_shift_osds():
-            raw = _compact_rows(raw, raw_exists)
-        else:
-            raw = np.where(raw_exists | (raw == _NONE), raw, _NONE)
+        with tracing.span("fixup_exists"):
+            # _remove_nonexistent_osds + _raw_to_up_osds, fused: both
+            # drop to NONE (EC) or compact (replicated)
+            exists = np.zeros(osdmap.max_osd + 1, dtype=bool)
+            up_ok = np.zeros(osdmap.max_osd + 1, dtype=bool)
+            exists[:-1] = np.asarray(osdmap.osd_exists, dtype=bool)
+            up_ok[:-1] = exists[:-1] & np.asarray(
+                osdmap.osd_up, dtype=bool
+            )
+            idx = np.clip(raw, 0, osdmap.max_osd)
+            in_range = (raw >= 0) & (raw < osdmap.max_osd)
+            raw_exists = in_range & exists[idx]
+            if pool.can_shift_osds():
+                raw = _compact_rows(raw, raw_exists)
+            else:
+                raw = np.where(raw_exists | (raw == _NONE), raw, _NONE)
 
-        raw = self._upmap_stage(osdmap, pool, ps, raw)
+        with tracing.span("fixup_upmap"):
+            raw = self._upmap_stage(osdmap, pool, ps, raw)
 
-        idx = np.clip(raw, 0, osdmap.max_osd)
-        in_range = (raw >= 0) & (raw < osdmap.max_osd)
-        alive = in_range & up_ok[idx]
-        if pool.can_shift_osds():
-            up = _compact_rows(raw, alive)
-        else:
-            up = np.where(alive, raw, _NONE)
+        with tracing.span("fixup_up"):
+            idx = np.clip(raw, 0, osdmap.max_osd)
+            in_range = (raw >= 0) & (raw < osdmap.max_osd)
+            alive = in_range & up_ok[idx]
+            if pool.can_shift_osds():
+                up = _compact_rows(raw, alive)
+            else:
+                up = np.where(alive, raw, _NONE)
 
-        up_primary = self._primary_vec(up)
-        up, up_primary = self._affinity_stage(
-            osdmap, pool, pps, up, up_primary
-        )
+        with tracing.span("fixup_affinity"):
+            up_primary = self._primary_vec(up)
+            up, up_primary = self._affinity_stage(
+                osdmap, pool, pps, up, up_primary
+            )
 
-        acting = up.copy()
-        acting_primary = up_primary.copy()
-        self._temp_stage(osdmap, pool, acting, acting_primary)
+        with tracing.span("fixup_temp"):
+            acting = up.copy()
+            acting_primary = up_primary.copy()
+            self._temp_stage(osdmap, pool, acting, acting_primary)
 
         return up, up_primary, acting, acting_primary
 
@@ -195,15 +212,16 @@ class OSDMapMapping:
                     note_shape("crush_batch", nb, pool.size)
                     # shards across the device mesh when >1 device
                     # exists (ParallelPGMapper role); single-device
-                    # unchanged
-                    with dp.stage("compute"):
-                        res, counts = mesh_batch_do_rule(
-                            cm, ruleno, pps_in, pool.size,
-                            osdmap.osd_weight,
-                        )
-                    with dp.stage("sync"):
-                        raw = np.asarray(res, dtype=np.int64)[:n]
-                        counts = np.asarray(counts)[:n]
+                    # unchanged.  The record's compute / sync stages
+                    # are bracketed where the chunks are issued and
+                    # fetched (jaxmap.map_chunked): what comes back
+                    # here is numpy already
+                    res, counts = mesh_batch_do_rule(
+                        cm, ruleno, pps_in, pool.size,
+                        osdmap.osd_weight,
+                    )
+                    raw = np.asarray(res, dtype=np.int64)[:n]
+                    counts = np.asarray(counts)[:n]
                     # positions beyond the returned count are absent,
                     # not NONE
                     cols = np.arange(pool.size)

@@ -99,6 +99,7 @@ class Objecter(Dispatcher):
         # message already carries); spans buffer here until
         # flush_spans_to_mgr ships them on the MMgrReport path
         self.tracer = tracing.Tracer(f"client.{self._client_id}")
+        messenger.tracer = self.tracer  # msgr_send / msgr_recv spans
         self._mgr_addr: str | None = None
 
     def new_identity(self) -> None:
@@ -303,6 +304,15 @@ class Objecter(Dispatcher):
         deadline = time.monotonic() + self.op_timeout
         last_err = "no attempt"
         reqid = f"{self._client_id}.{next(self._op_seq)}"
+        wait = tracing.take_wait()
+        if wait is not None:
+            # the op sat in the client's aio pool before this thread
+            # took it up (rados aio_*): now that it has an id, that
+            # wait is its first span
+            self.tracer.record(
+                wait[0], reqid, wait[1], wait[2],
+                role=tracing.ROLE_CLIENT, tags={"oid": oid},
+            )
         root = self.tracer.start_span(
             "client_op",
             trace_id=reqid,

@@ -16,7 +16,9 @@ from __future__ import annotations
 
 import concurrent.futures
 import json
+import time
 
+from ..common import tracing
 from ..common.encoding import Decoder, Encoder
 from ..mon.monitor import MonClient
 from ..msg import Messenger
@@ -575,11 +577,27 @@ class IoCtx:
         return sorted(names)
 
     # -- async (librados completions) --------------------------------------
+    def _aio(self, fn, *args):
+        """Queue ``fn(*args)`` on the client's aio pool.  The submit
+        stamp is carried in to the thread that takes the op up, so
+        the wait for that thread becomes the op's ``client_aio_wait``
+        span once the Objecter has minted its reqid."""
+        t_submit = time.perf_counter()
+
+        def run():
+            tracing.carry_wait("client_aio_wait", t_submit)
+            try:
+                return fn(*args)
+            finally:
+                tracing.take_wait()  # an op that never got a reqid
+
+        return self.rados._pool.submit(run)
+
     def aio_write_full(self, oid: str, data: bytes):
-        return self.rados._pool.submit(self.write_full, oid, data)
+        return self._aio(self.write_full, oid, data)
 
     def aio_read(self, oid: str, length: int = -1, offset: int = 0):
-        return self.rados._pool.submit(self.read, oid, length, offset)
+        return self._aio(self.read, oid, length, offset)
 
     def aio_remove(self, oid: str):
-        return self.rados._pool.submit(self.remove, oid)
+        return self._aio(self.remove, oid)

@@ -152,18 +152,30 @@ def _map_range(m: CrushMap, args, weights, timed: bool = True):
                   file=sys.stderr)
             backend = "oracle"
     if backend == "jax":
-        res, counts = jaxmap.batch_do_rule(
-            cm, args.rule, xs, args.num_rep, weights
-        )
+        from ..ops.profiler import dispatch_profiler
+
+        def device_pass():
+            # one flight-recorder entry a pass, as OSDMapMapping
+            # records a remap's; map_chunked brackets its stages
+            with dispatch_profiler().dispatch(
+                "crush", backend="jax"
+            ) as dp:
+                dp.set_ops(1)
+                dp.set_stripes(len(xs))
+                dp.add_bytes_in(xs.nbytes)
+                dp.add_upload(xs.nbytes)
+                return jaxmap.batch_do_rule(
+                    cm, args.rule, xs, args.num_rep, weights
+                )
+
+        res, counts = device_pass()
         res = np.asarray(res)
         counts = np.asarray(counts)
         elapsed = time.perf_counter() - t0
         if timed:
             # time a second, compile-free pass for the throughput figure
             t0 = time.perf_counter()
-            res2, _ = jaxmap.batch_do_rule(
-                cm, args.rule, xs, args.num_rep, weights
-            )
+            res2, _ = device_pass()
             np.asarray(res2)
             elapsed = time.perf_counter() - t0
     else:

@@ -1,7 +1,9 @@
 """osdmaptool --test-map-pgs equivalent (src/tools/osdmaptool.cc:41-53,
 147-218): bulk-map every PG of every pool, print distribution stats and
 timing — the full-map-recompute benchmark (ParallelPGMapper's job, done
-as one batched device call per pool)."""
+as one batched device call per pool).  The timed remap's stage spans
+(``remap`` and its children, common/tracing.py) are printed beside the
+rate: where a slow remap spends its time."""
 
 from __future__ import annotations
 
@@ -11,6 +13,7 @@ import time
 
 import numpy as np
 
+from ..common import tracing
 from ..crush.types import (
     CRUSH_ITEM_NONE,
     PG_POOL_TYPE_ERASURE,
@@ -36,6 +39,19 @@ def parse_args(argv=None):
     return p.parse_args(argv)
 
 
+def stage_line(spans: list[dict]) -> str:
+    """``name ms`` for each stage of the one ``remap`` in ``spans``
+    (same-named children summed, in order of first start) and
+    ``other`` for what no child covers."""
+    root = next(s for s in spans if s["name"] == "remap")
+    stages: dict[str, float] = {}
+    for s in sorted(spans, key=lambda s: s["start"]):
+        if s["parent_id"] == root["span_id"]:
+            stages[s["name"]] = stages.get(s["name"], 0.0) + s["duration"]
+    stages["other"] = root["duration"] - sum(stages.values())
+    return ", ".join(f"{n} {1e3 * d:.1f}" for n, d in stages.items())
+
+
 def main(argv=None) -> int:
     args = parse_args(argv)
     if args.backend == "jax":
@@ -59,9 +75,11 @@ def main(argv=None) -> int:
         )
     om.add_pool(pool)
 
-    mapping = OSDMapMapping()
+    tracer = tracing.Tracer("osdmaptool", max_spans=1 << 16)
+    mapping = OSDMapMapping(tracer=tracer)
     use_device = args.backend == "jax"
     mapping.update(om, use_device=use_device)  # warm-up incl. compile
+    tracer.drain(1 << 16)
     t0 = time.perf_counter()
     mapping.update(om, use_device=use_device)
     elapsed = time.perf_counter() - t0
@@ -76,6 +94,7 @@ def main(argv=None) -> int:
         f"{num_osds} osds in {elapsed:.4f}s = "
         f"{pool.pg_num / elapsed:.0f} pg mappings/sec [{args.backend}]"
     )
+    print(f"  remap stages (ms): {stage_line(tracer.drain(1 << 16))}")
     print(
         f"  per-osd pgs: min {per_osd.min()} max {per_osd.max()} "
         f"avg {per_osd.mean():.1f} stddev {per_osd.std():.1f}"

@@ -123,6 +123,21 @@ DISPATCH_COUNTERS = (
     "l_tpu_dispatch_sync_lat_hist",
     "l_tpu_pad_bytes_wasted",
 )
+# tracing-plane stage counters (common/tracing.py Tracer._complete →
+# ops/kernel_stats.py record_stage): every finished span of one of
+# these names — the served write from the client's aio queue to the
+# shard commit, and the remap's stages — feeds
+# l_stage_<name>_{count,ns} (and _self_ns for a kernel_stats
+# SELF_TIME_STAGES name) that the benchmark's per-layer readers and
+# /metrics read
+STAGE_SPANS = (
+    "client_aio_wait", "client_op", "msgr_send", "msgr_recv",
+    "osd_queue_wait", "osd_op", "ec_prepare", "ec_encode", "txn_build",
+    "store_commit", "sub_op_wait", "rep_op",
+    "dev_upload", "dev_compute", "dev_sync",
+    "remap", "crush_inputs", "crush_fallback", "fixup_exists",
+    "fixup_upmap", "fixup_up", "fixup_affinity", "fixup_temp",
+)
 # sharded bucket-index + reshard families the RGW schema must
 # declare (rgw/index.py build_rgw_perf — the bench rgw_index section
 # and the reshard-under-load tests read exactly these)
@@ -599,6 +614,38 @@ def check_dispatch_counters() -> list[str]:
     return errors
 
 
+def check_stage_counters() -> list[str]:
+    """The tracing plane's stage family, through the REAL sink: a
+    finished span of every product span name must register its
+    ``l_stage_<name>_*`` counters on the kernel set as plain u64s —
+    ``_self_ns`` where the name's self time has a reader and nowhere
+    else (the set itself is linted by check_perf_counters in the
+    schema walk)."""
+    from ceph_tpu.common.perf_counters import PERFCOUNTER_U64
+    from ceph_tpu.ops.kernel_stats import SELF_TIME_STAGES, KernelStats
+
+    ks = KernelStats()
+    for name in STAGE_SPANS:
+        ks.record_stage(name, 0, 0)
+    errors = []
+    for name in STAGE_SPANS:
+        for suffix in ("count", "ns", "self_ns"):
+            counter = ks.perf._counters.get(f"l_stage_{name}_{suffix}")
+            wanted = suffix != "self_ns" or name in SELF_TIME_STAGES
+            if (counter is not None) != wanted:
+                errors.append(
+                    f"kernel schema: stage counter "
+                    f"l_stage_{name}_{suffix} "
+                    + ("missing" if wanted else "has no reader")
+                )
+            elif wanted and counter.kind != PERFCOUNTER_U64:
+                errors.append(
+                    f"kernel schema: l_stage_{name}_{suffix} is "
+                    f"{counter.kind}, not u64"
+                )
+    return errors
+
+
 def product_event_samples() -> list[str]:
     """Generate one real clog entry and one real crash report through
     the product code paths and lint them — the schemas daemons
@@ -993,6 +1040,9 @@ def product_counter_sets():
     from ceph_tpu.ops.profiler import ensure_dispatch_counters
 
     ensure_dispatch_counters(ks)
+    # tracing-plane stage family (one triple a span name) likewise
+    for name in STAGE_SPANS:
+        ks.record_stage(name, 0, 0)
     return [
         build_osd_perf(0), build_mapping_perf(), ks.perf,
         build_msgr_perf("osd.0"),
@@ -1031,6 +1081,7 @@ def check_all(sets=None) -> list[str]:
         errors.extend(check_worker_counters())
         errors.extend(check_residency_counters())
         errors.extend(check_dispatch_counters())
+        errors.extend(check_stage_counters())
         errors.extend(check_proc_counters())
         errors.extend(check_thrash_counters())
         errors.extend(check_objecter_counters())
