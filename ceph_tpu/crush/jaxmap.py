@@ -106,19 +106,67 @@ def _hash4(a, b, c, d):
     return h.astype(jnp.uint32)
 
 
+# A straw2-logarithm table index is read as two digits, the low one
+# of _LN_LO_BITS bits: _ln_lookup finds row (hi << _LN_LO_BITS) + lo of
+# a table that _fold_ln_table folded.
+_LN_LO_BITS = 3
+_LN_RADIX = 1 << _LN_LO_BITS
+
+
+def _fold_ln_table(cols) -> np.ndarray:
+    """(rows,) int columns -> the (ceil(rows / 8), 8 * C) f32 table
+    _ln_lookup reads: folded row h holds rows 8h .. 8h+7 side by side
+    (zeros past the end).  Every value must be f32-exact (<= 2^24)."""
+    tbl = np.stack(cols, axis=1)
+    assert tbl.max() <= 1 << 24 and tbl.min() >= 0
+    rows, ncols = tbl.shape
+    n_hi = -(-rows // _LN_RADIX)
+    tbl = np.concatenate(
+        [tbl, np.zeros((n_hi * _LN_RADIX - rows, ncols), tbl.dtype)]
+    )
+    return tbl.reshape(n_hi, _LN_RADIX * ncols).astype(np.float32)
+
+
+def _ln_lookup(idx, tbl):
+    """Row ``idx`` of a folded table for every element of ``idx``:
+    (..., C) f32, exact.
+
+    The high digit picks a folded row through a one-hot matmul (f32
+    HIGHEST: a one-hot times an integer <= 2^24 is exact), the low one
+    picks one of that row's 8 groups through a select-sum that the
+    compiler fuses into the matmul's output.  What this pins is the
+    layout.  The callers vmap this over PGs x replicas x items, and
+    the PG batch has to stay in the 128 lanes through both operands
+    and the result.  The compiler puts a one-hot's table axis there
+    instead as soon as that axis fills them — a 128, 255, 256, 384 or
+    512 row one-hot compiled so, 120, 129, 136, 257 and 264 rows did
+    not (jax 0.9.0, libtpu 0.0.34) — and then spreads every lane's
+    index over all of them: for the 256-row table that took 9 x the
+    time of the same look-up the right way round (PERF.md section 6,
+    PR 27).  No one-hot here is wider than ceil(rows / 8), 32 and 17
+    for the two tables, whatever a table's row count;
+    tests/test_chip_compile.py holds that at three item widths."""
+    n_hi, width = tbl.shape
+    hi_hot = (
+        jnp.arange(n_hi) == (idx >> _LN_LO_BITS)[..., None]
+    ).astype(jnp.float32)
+    folded = jnp.matmul(
+        hi_hot, tbl, precision=lax.Precision.HIGHEST
+    ).reshape(*idx.shape, _LN_RADIX, width // _LN_RADIX)
+    lo_hot = jnp.arange(_LN_RADIX) == (idx & (_LN_RADIX - 1))[..., None]
+    return jnp.sum(jnp.where(lo_hot[..., None], folded, 0.0), axis=-2)
+
+
 def _crush_ln_f64(u, ln_tbl1, ln_tbl2):
     """2^44*log2(u+1) exactly, in float64 (mapper.c:248-290).
 
-    The tables arrive BYTE-SPLIT in bfloat16 (3 bf16 columns per
-    24-bit half, built by compile_map): a one-hot lookup of byte
-    values <= 255 is exact in bf16 with f32 accumulation, and the
-    native-bf16 MXU pass is several times cheaper than the f32
-    HIGHEST-precision emulation — this lookup pair is the hot loop of
-    every straw2 draw.  Downstream arithmetic stays on f64 integers
-    < 2^53.  index2 reproduces ((x*RH) >> 48) & 0xff via the 24-bit
-    split (the C's int64 wraparound only ever touches bits that the
-    mod-256 discards).  Value-exact against
-    ceph_tpu.crush.ln.crush_ln over the full u16 domain
+    The two table look-ups (_ln_lookup) return whole 24-bit halves —
+    rh_hi (25 bits: RH[0] = 2^48), rh_lo, lh_hi, lh_lo, then ll_hi,
+    ll_lo — and everything after them runs on f64 integers < 2^53.
+    index2 reproduces ((x*RH) >> 48) & 0xff via the 24-bit split (the
+    C's int64 wraparound only ever touches bits that the mod-256
+    discards).  Value-exact against ceph_tpu.crush.ln.crush_ln over
+    the full u16 domain, flat and under the callers' double vmap
     (tests/test_crush_jax.py)."""
     x = u.astype(jnp.int32) + 1
     masked = x & 0x1FFFF
@@ -132,31 +180,16 @@ def _crush_ln_f64(u, ln_tbl1, ln_tbl2):
     x = x << shift_amt
     iexp = 15 - shift_amt
     k = ((x >> 8) << 1) - 256 >> 1
-    oh1 = (jnp.arange(129) == k[:, None]).astype(jnp.bfloat16)
-    b1 = jnp.matmul(
-        oh1, ln_tbl1, preferred_element_type=jnp.float32
-    )
-
-    def recon(b, off, nbytes=3):
-        """Exact byte lanes -> the value half, in f64 (f32 arithmetic
-        is exact: every partial sum < 2^25)."""
-        v = b[:, off]
-        for i in range(1, nbytes):
-            v = v * 256.0 + b[:, off + i]
-        return v.astype(jnp.float64)
-
-    rh_hi, rh_lo = recon(b1, 0, 4), recon(b1, 4)
-    lh_v = recon(b1, 7) * float(1 << 24) + recon(b1, 10)
+    b1 = _ln_lookup(k, ln_tbl1).astype(jnp.float64)
+    rh_hi, rh_lo = b1[..., 0], b1[..., 1]
+    lh_v = b1[..., 2] * float(1 << 24) + b1[..., 3]
     xf = x.astype(jnp.float64)
     T = xf * rh_hi + jnp.floor(xf * rh_lo / float(1 << 24))
     index2 = jnp.mod(
         jnp.floor(T / float(1 << 24)), 256.0
     ).astype(jnp.int32)
-    oh2 = (jnp.arange(256) == index2[:, None]).astype(jnp.bfloat16)
-    b2 = jnp.matmul(
-        oh2, ln_tbl2, preferred_element_type=jnp.float32
-    )
-    ll_v = recon(b2, 0) * float(1 << 24) + recon(b2, 3)
+    b2 = _ln_lookup(index2, ln_tbl2).astype(jnp.float64)
+    ll_v = b2[..., 0] * float(1 << 24) + b2[..., 1]
     return iexp.astype(jnp.float64) * float(1 << 44) + jnp.floor(
         (lh_v + ll_v) / 16.0
     )
@@ -189,8 +222,9 @@ class CompiledMap:
     arg_positions: int  # P (max weight_set positions; 0 without args)
     types_f: jnp.ndarray  # (nb,) f32 bucket types
     bidx_f: jnp.ndarray  # (max_neg,) f32: (-1-id) -> row, -1 for gaps
-    ln_tbl1: jnp.ndarray  # (129, 4) f32: rh_hi, rh_lo, lh_hi, lh_lo
-    ln_tbl2: jnp.ndarray  # (256, 2) f32: ll_hi, ll_lo
+    # crush_ln's tables as 24-bit halves, folded by _fold_ln_table
+    ln_tbl1: jnp.ndarray  # (17, 8*4) f32: rh_hi, rh_lo, lh_hi, lh_lo
+    ln_tbl2: jnp.ndarray  # (32, 8*2) f32: ll_hi, ll_lo
     sz: int
     nb: int
     has_uniform: bool
@@ -405,24 +439,10 @@ def compile_map(cmap) -> CompiledMap:
         axis=1,
     )
     rh, lh, ll = _ln_tables()
-
-    def _bytesplit(col, nbytes):
-        """Value column -> nbytes byte columns (each bf16-exact).
-        rh_hi needs FOUR bytes: RH[0] = ceil(2^55/128) = 2^48 makes
-        its high half a 25-bit value."""
-        return [
-            (col >> (8 * i)) & 0xFF for i in range(nbytes - 1, -1, -1)
-        ]
-
-    tbl1_cols = (
-        _bytesplit(rh >> 24, 4)
-        + _bytesplit(rh & 0xFFFFFF, 3)
-        + _bytesplit(lh >> 24, 3)
-        + _bytesplit(lh & 0xFFFFFF, 3)
+    ln_tbl1 = _fold_ln_table(
+        [rh >> 24, rh & 0xFFFFFF, lh >> 24, lh & 0xFFFFFF]
     )
-    tbl2_cols = _bytesplit(ll >> 24, 3) + _bytesplit(ll & 0xFFFFFF, 3)
-    ln_tbl1 = np.stack(tbl1_cols, axis=1).astype(np.float32)
-    ln_tbl2 = np.stack(tbl2_cols, axis=1).astype(np.float32)
+    ln_tbl2 = _fold_ln_table([ll >> 24, ll & 0xFFFFFF])
     skey = (
         sz,
         nb,
@@ -449,8 +469,8 @@ def compile_map(cmap) -> CompiledMap:
         arg_positions=P,
         types_f=jnp.asarray(types.astype(np.float32)),
         bidx_f=jnp.asarray(bidx.astype(np.float32)),
-        ln_tbl1=jnp.asarray(ln_tbl1, dtype=jnp.bfloat16),
-        ln_tbl2=jnp.asarray(ln_tbl2, dtype=jnp.bfloat16),
+        ln_tbl1=jnp.asarray(ln_tbl1),
+        ln_tbl2=jnp.asarray(ln_tbl2),
         sz=sz,
         nb=nb,
         has_uniform=bool((algs == CRUSH_BUCKET_UNIFORM).any()),

@@ -14,6 +14,7 @@ one file so one worker owns the library.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -145,6 +146,41 @@ def test_crush_chunk_program_fits_the_chip(one_chip):
         *[_sds(t.shape, t.dtype, one_chip) for t in tables],
     ).compile()
     assert 2 * _fits(compiled) < V5E_HBM_BYTES // 2
+
+
+@pytest.mark.parametrize("items", [10, 25, 40])
+def test_crush_ln_lookups_keep_the_pg_batch_in_lanes(one_chip, items):
+    """The straw2 logarithm as the chunk program runs it (vmapped over
+    2^16 lanes x 5 speculative replicas, ``items`` bucket items: the
+    root, rack and host levels of BASELINE #5).  Neither table
+    look-up's one-hot may be laid out with the table index minor-most:
+    the compiler then spreads every lane's index over the lanes, which
+    cost 9 x the look-up's time on the chip (PERF.md section 6, PR 27).
+    """
+    from ceph_tpu.tools.crushtool import build_hierarchy
+
+    cm = jaxmap.compile_map(build_hierarchy(8, 2, 2))
+    lanes = jaxmap.CHUNK_LANES
+
+    def ln(u):
+        return jaxmap._crush_ln_f64(u, cm.ln_tbl1, cm.ln_tbl2)
+
+    text = (
+        jax.jit(jax.vmap(jax.vmap(ln)))
+        .lower(_sds((lanes, 5, items), jnp.uint32, one_chip))
+        .compile()
+        .as_text()
+    )
+    # every boolean array over the batch with an axis beside it: the
+    # one-hots, whatever the compiler fused them into
+    hots = []  # (shape, size of its minor-most axis)
+    for m in re.finditer(r"pred\[([0-9,]+)\]\{([0-9,]+)", text):
+        dims = [int(d) for d in m.group(1).split(",")]
+        if lanes in dims and len(dims) > 3:
+            hots.append((dims, dims[int(m.group(2).split(",")[0])]))
+    for rows in (cm.ln_tbl1.shape[0], cm.ln_tbl2.shape[0]):
+        assert any(rows in dims for dims, _ in hots), (rows, hots)
+    assert all(minor == lanes for _, minor in hots), hots
 
 
 @pytest.mark.parametrize(

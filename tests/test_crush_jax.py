@@ -301,19 +301,30 @@ def test_set_tries_zero_override_ignored_like_c():
         assert np.asarray(got)[x, : counts[x]].tolist() == expect, x
 
 
-def test_device_crush_ln_exact_full_domain():
-    """The f64 one-hot crush_ln must equal the int64 table version for
-    every 16-bit input — exercised on the PRODUCTION helper."""
+@pytest.mark.parametrize("items", [None, 1, 10, 25, 40])
+def test_device_crush_ln_exact_full_domain(items):
+    """The device crush_ln must equal the int64 table version for
+    every 16-bit input — exercised on the PRODUCTION helper, flat
+    (``items`` None) and as straw2_draw's callers run it: vmapped over
+    lanes x replicas with ``items`` bucket items a call."""
     import jax
 
     from ceph_tpu.crush.jaxmap import _crush_ln_f64
     from ceph_tpu.crush.ln import crush_ln as ln_ref
 
     cm = compile_map(flat_map())
+
+    def fn(u):
+        return _crush_ln_f64(u, cm.ln_tbl1, cm.ln_tbl2)
+
     us = np.arange(0x10000, dtype=np.uint32)
-    got = np.asarray(
-        jax.jit(lambda u: _crush_ln_f64(u, cm.ln_tbl1, cm.ln_tbl2))(us)
-    ).astype(np.int64)
+    if items is not None:
+        # the whole domain over (lanes, 3 replicas, items); the tail
+        # of the last lane wraps round to u = 0, 1, ...
+        lanes = -(-len(us) // (3 * items))
+        us = np.resize(us, (lanes, 3, items))
+        fn = jax.vmap(jax.vmap(fn))
+    got = np.asarray(jax.jit(fn)(us)).astype(np.int64)
     np.testing.assert_array_equal(got, ln_ref(us))
 
 
