@@ -444,6 +444,22 @@ def span(name: str, tags: dict | None = None, role: str = ""):
     return tracer.start_span(name, role=role, tags=tags)
 
 
+# stages whose time has a reader whoever the caller is (the EC seam's
+# host copies): with no daemon above them their spans are roots here
+# -- nothing is kept, the stage counters and the profiler mirror see
+# them all the same
+_STAGE_TRACER = Tracer("stage", buffered=False)
+
+
+def stage(name: str, tags: dict | None = None):
+    """Child span of the ambient span as :func:`span` gives it, and
+    without one (a tool, the benchmark's plugin driver) a root span of
+    a process-wide unbuffered tracer, so ``l_stage_<name>_*`` and the
+    ``ceph:<name>`` mirror count the stage under every caller."""
+    tracer = current_tracer() or _STAGE_TRACER
+    return tracer.start_span(name, tags=tags)
+
+
 # -- cross-daemon tree assembly (shared by the mgr tracing module) ----------
 
 

@@ -15,8 +15,11 @@ first use of ``backend=jax``).  Both implement:
 
 from __future__ import annotations
 
+import warnings
+
 import numpy as np
 
+from ..common import allocator
 from ..gf import matrix_vector_mul_region
 from ..layout import fold_stripes, unfold_stripes
 
@@ -111,6 +114,25 @@ class NumpyBackend:
             dp.set_stripes(sum(o.shape[0] for o in outs))
             return outs
 
+    def matrix_shards(
+        self, matrix: np.ndarray, shards, w: int, stripes: int
+    ) -> list[np.ndarray]:
+        """One object's reconstruction in shard form (the jax backend
+        makes it one device dispatch): the s survivor shards are the
+        regions as they are stored, the (r, s) reconstruction rows
+        give the r rebuilt shards.  A host flight-recorder entry, as
+        the other batched seams of this oracle leave."""
+        from ..ops.profiler import dispatch_profiler
+
+        regions = np.stack([_host_row(s) for s in shards])
+        with dispatch_profiler().dispatch(
+            "ec_decode", backend=self.name
+        ) as dp:
+            dp.set_ops(1)
+            dp.set_stripes(stripes)
+            dp.add_bytes_in(regions.nbytes)
+            return list(self.matrix_regions(matrix, regions, w))
+
     def bitmatrix_regions(
         self,
         bm: np.ndarray,
@@ -149,10 +171,22 @@ def register_backend(name: str, backend) -> None:
 
 
 def get_backend(name: str):
-    if name == "jax" and "jax" not in _backends:
-        from .. import ops  # self-registers the jax backend
+    if name == "jax":
+        if "jax" not in _backends:
+            from .. import ops  # self-registers the jax backend
 
-        assert "jax" in _backends
+            assert "jax" in _backends
+        # a codec on the device moves whole objects through fresh host
+        # buffers call after call: the process that makes one keeps
+        # its freed blocks -- daemon, tool and benchmark alike, and
+        # nobody else sets the policy
+        if not allocator.keep_large_blocks():
+            warnings.warn(
+                "the C library took no allocator policy: large EC "
+                "buffers will fault fresh pages on every call",
+                RuntimeWarning,
+                stacklevel=2,
+            )
     if name not in _backends:
         raise ValueError(f"unknown EC backend {name!r} (have {sorted(_backends)})")
     return _backends[name]

@@ -64,6 +64,10 @@ _table_cache = IsaTableCache()
 class ErasureCodeIsa(ErasureCode):
     """matrixtype: reed_sol_van (default) or cauchy."""
 
+    # isa-l is GF(2^8) only; the stripe seam reads the word size of
+    # every matrix code here
+    w = 8
+
     def __init__(self, matrixtype: str = "reed_sol_van"):
         super().__init__()
         self.matrixtype = matrixtype

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..common import tracing
 from ..native import ceph_crc32c
 from .interface import ErasureCodeError
 
@@ -142,7 +143,8 @@ def encode(
         if ok:
             stripes = buf.reshape(nstripes, k, sinfo.chunk_size)
             coding = backend.matrix_stripes(matrix, stripes, ec.w)
-            out = _assemble_shards(stripes, coding, k, n, want)
+            with tracing.stage("ec_assemble"):
+                out = _assemble_shards(stripes, coding, k, n, want)
         else:
             # layered/bitmatrix per-stripe loop: one host-path
             # flight-recorder entry for the whole object (the inner
@@ -293,22 +295,26 @@ def reconstruction_rows(
     return np.array(rows, dtype=np.int64).reshape(len(rows), k), survivors
 
 
-def decode_reconstruction(ec, want, available):
+def decode_reconstruction(
+    ec, want, available, needs: str = "decode_stripes_batch"
+):
     """The decode analog of :func:`_matrix_fast_path`: a
     (rows, survivors, w, backend) plan that rebuilds ``want`` from
-    ``available`` in one batched device dispatch, or None when the
-    code family cannot express its repair as whole-word matrix math
-    (bitmatrix/layered codes without a ``decode_matrix`` hook, chunk
-    remapping, unsolvable systems)."""
+    ``available`` in one batched device dispatch through the
+    backend's ``needs`` entry point, or None when the backend lacks
+    it or the code family cannot express its repair as whole-word
+    matrix math (bitmatrix/layered codes without a ``decode_matrix``
+    hook, chunk remapping, unsolvable systems)."""
     hook = getattr(ec, "decode_matrix", None)
     if hook is not None:
         try:
-            return hook(set(want), set(available))
+            plan = hook(set(want), set(available))
         except ErasureCodeError:
             return None
-    matrix, backend, ok = _matrix_fast_path(
-        ec, "decode_stripes_batch"
-    )
+        if plan is not None and not hasattr(plan[3], needs):
+            return None
+        return plan
+    matrix, backend, ok = _matrix_fast_path(ec, needs)
     if not ok:
         return None
     try:
@@ -456,35 +462,117 @@ def _wrap_decoded(rec, want) -> dict:
     }
 
 
-def decode_concat(
-    sinfo: StripeInfo, ec, shards: dict[int, np.ndarray]
-) -> np.ndarray:
-    """Concat-decode every stripe back to logical bytes
-    (ECUtil.cc:12-48)."""
+def _shard_views(
+    sinfo: StripeInfo, shards
+) -> tuple[dict[int, np.ndarray], int]:
+    """{position: 1-D uint8 view} of one object's shards and the
+    number of stripes they hold; ragged or unaligned shards are an
+    error."""
     lengths = {len(v) for v in shards.values()}
     if len(lengths) != 1:
         raise ErasureCodeError("shards must be equal length")
     (shard_len,) = lengths
     if shard_len % sinfo.chunk_size:
         raise ErasureCodeError("shard length not chunk aligned")
-    nstripes = shard_len // sinfo.chunk_size
     views = {
         i: np.frombuffer(bytes(v), dtype=np.uint8)
         if isinstance(v, (bytes, bytearray, memoryview))
         else np.ascontiguousarray(v, dtype=np.uint8)
         for i, v in shards.items()
     }
+    return views, shard_len // sinfo.chunk_size
+
+
+def _rebuild(
+    sinfo: StripeInfo, ec, views: dict, nstripes: int, missing
+) -> dict[int, np.ndarray]:
+    """The ``missing`` shards of one object from the shards in
+    ``views``, every stripe at once.  A code whose repair is
+    whole-word matrix math (:func:`decode_reconstruction`) takes ONE
+    dispatch: a shard is chunk i of every stripe, which is the folded
+    region layout already, so the survivors go to the backend as they
+    are stored and the rebuilt shards come back whole
+    (``matrix_shards``).  Bitmatrix and layered codes run the
+    reference's per-stripe ``ec._decode`` loop inside one flight-
+    recorder entry, as :func:`encode`'s fallback does.  Byte-identical
+    either way."""
+    with tracing.stage("ec_plan"):
+        plan = decode_reconstruction(
+            ec, missing, views, needs="matrix_shards"
+        )
+    # shards travel to the device as 32-bit words
+    if plan is not None and sinfo.chunk_size % 4 == 0:
+        rows, survivors, w, backend = plan
+        rebuilt = backend.matrix_shards(
+            rows, [views[s] for s in survivors], w, nstripes
+        )
+        return dict(zip(sorted(missing), rebuilt))
+    from ..ops.profiler import dispatch_profiler
+
+    cs = sinfo.chunk_size
+    bname = getattr(getattr(ec, "backend", None), "name", None) or "cpu"
+    with dispatch_profiler().dispatch("ec_decode", backend=bname) as dp:
+        dp.set_ops(1)
+        dp.set_stripes(nstripes)
+        dp.add_bytes_in(sum(v.nbytes for v in views.values()))
+        parts: dict[int, list] = {p: [] for p in missing}
+        for s in range(nstripes):
+            chunks = {
+                i: v[s * cs : (s + 1) * cs] for i, v in views.items()
+            }
+            decoded = ec._decode(set(missing), chunks)
+            for p in missing:
+                parts[p].append(decoded[p])
+        return {p: np.concatenate(c) for p, c in parts.items()}
+
+
+def decode(
+    sinfo: StripeInfo, ec, shards: dict, want
+) -> dict[int, np.ndarray]:
+    """The B-stripe form of ``ErasureCode.decode(want, chunks)``
+    (ECUtil::decode's per-stripe loop hoisted, the twin of
+    :func:`encode`): the ``want`` shards of one object from the
+    shards at hand, as host arrays.  A wanted shard that is at hand
+    comes back as it was given; the others are rebuilt, for a matrix
+    code in one ``ec_decode`` dispatch (:func:`_rebuild`)."""
+    views, nstripes = _shard_views(sinfo, shards)
+    want = sorted(set(want))
+    out = {p: views[p] for p in want if p in views}
+    missing = [p for p in want if p not in views]
+    if not missing or nstripes == 0:
+        return out
     with _kstats().timed(
         "ec_decode", bytes_in=sum(v.nbytes for v in views.values())
     ) as kt:
-        out = []
-        for s in range(nstripes):
-            chunks = {
-                i: v[s * sinfo.chunk_size : (s + 1) * sinfo.chunk_size]
-                for i, v in views.items()
+        rebuilt = _rebuild(sinfo, ec, views, nstripes, missing)
+        kt.bytes_out = sum(v.nbytes for v in rebuilt.values())
+    out.update(rebuilt)
+    return out
+
+
+def decode_concat(
+    sinfo: StripeInfo, ec, shards: dict[int, np.ndarray]
+) -> np.ndarray:
+    """Concat-decode every stripe back to logical bytes
+    (ECUtil.cc:12-48): the data shards, the missing ones rebuilt by
+    :func:`decode`'s one dispatch, interleaved a chunk a stripe."""
+    views, nstripes = _shard_views(sinfo, shards)
+    if nstripes == 0:
+        return np.zeros(0, dtype=np.uint8)
+    data = [ec.chunk_index(i) for i in range(ec.get_data_chunk_count())]
+    with _kstats().timed(
+        "ec_decode", bytes_in=sum(v.nbytes for v in views.values())
+    ) as kt:
+        missing = [p for p in data if p not in views]
+        if missing:
+            views = {
+                **views,
+                **_rebuild(sinfo, ec, views, nstripes, missing),
             }
-            out.append(ec.decode_concat(chunks))
-        res = np.concatenate(out)
+        with tracing.stage("ec_assemble"):
+            res = np.stack(
+                [views[p].reshape(nstripes, -1) for p in data], axis=1
+            ).reshape(-1)
         kt.bytes_out = res.nbytes
         return res
 

@@ -13,7 +13,9 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from ..common import tracing
 from ..ec.backend import register_backend
+from ..layout import fold_stripes, unfold_stripes
 from . import mesh, packed_gf
 from .gf_matmul import (
     bitmatrix_packet_regions,
@@ -111,7 +113,7 @@ class JaxBackend:
 
         Device-array pipelines that want to keep results on-chip call
         ``ops.gf_matmul.gf_matrix_stripes`` (or
-        ``ops.packed_gf.packed_matrix_stripes``) directly instead."""
+        ``ops.packed_gf.prebuilt_word_call``) directly instead."""
         stripes = np.ascontiguousarray(stripes, dtype=np.uint8)
         b, _k, chunk = stripes.shape
         with kernel_stats().timed(
@@ -144,13 +146,7 @@ class JaxBackend:
             if w == 8 and _on_tpu() and (b * chunk) % 4 == 0:
                 bm_np, ok = _host_bm(matrix, w)
                 if ok:
-                    dp.add_upload(stripes.nbytes)
-                    with dp.stage("compute"):
-                        out = np.asarray(
-                            packed_gf.packed_matrix_stripes(
-                                bm_np, stripes
-                            )
-                        )
+                    out = self._packed_stripes(dp, bm_np, stripes)
                     kt.bytes_out = out.nbytes
                     return out
             bm = matrix_to_device_bitmatrix(matrix, w)
@@ -163,6 +159,80 @@ class JaxBackend:
                 out = np.asarray(odev)[:b]
             kt.bytes_out = out.nbytes
             return out
+
+    @staticmethod
+    def _packed_stripes(dp, bm_np: np.ndarray, stripes: np.ndarray):
+        """The packed-lane path of :meth:`matrix_stripes`, the one
+        stripes form of the packed kernel, each stage bracketed where
+        it happens: the host fold (span ``ec_fold``; a device-side
+        transpose is the relayout this kernel exists to avoid),
+        the k row uploads, the kernel's issue, the wait for and copy
+        of the m result rows, the host unfold (span ``ec_unfold``)."""
+        import jax
+
+        b, _k, chunk = stripes.shape
+        with tracing.stage("ec_fold"):
+            words = packed_gf.to_words(fold_stripes(stripes))
+        dp.add_upload(stripes.nbytes)
+        with dp.stage("upload"):
+            dev = [jax.device_put(x) for x in words]
+        with dp.stage("compute"):
+            outs = packed_gf.prebuilt_word_call(bm_np)(*dev)
+        with dp.stage("sync"):
+            host = [np.asarray(o) for o in outs]
+        with tracing.stage("ec_unfold"):
+            return unfold_stripes(packed_gf.from_words(host), b, chunk)
+
+    def matrix_shards(
+        self, matrix: np.ndarray, shards, w: int, stripes: int
+    ) -> list[np.ndarray]:
+        """One object's reconstruction in ONE device dispatch, in shard
+        form: ``shards`` are the s survivor shards named by the plan
+        (equal-length 1-D payloads of whole 32-bit words: chunk i of
+        every stripe, concatenated — the folded region layout as
+        stored, so nothing is transposed on the host), ``matrix`` the
+        (r, s) reconstruction rows; returns the r rebuilt shards as
+        host arrays.  Recorded as kind ``ec_decode`` (ops 1,
+        ``stripes``), upload / issue / fetch bracketed as their stages.
+
+        The matrix is an OPERAND of the bitplane program
+        (``gf_matrix_words``): a reconstruction matrix is one of
+        C(k+m, e) a pool may meet, and the packed-lane kernel unrolls
+        its matrix at trace time — a compile a pattern, inside a
+        client's degraded read (PERF.md section 6, PR 28).  The length
+        buckets to a power of two of stripes, as
+        ``_bitplane_dispatch`` buckets batches."""
+        import jax
+
+        from .gf_matmul import gf_matrix_words
+        from .residency import bucket_pow2, note_shape
+
+        rows = [_row_u8(s).view(np.uint32) for s in shards]
+        n4 = len(rows[0])
+        total = 4 * n4 * len(rows)
+        with kernel_stats().timed(
+            "gf_matmul", bytes_in=total
+        ) as kt, dispatch_profiler().dispatch(
+            "ec_decode", backend=self.name
+        ) as dp:
+            dp.set_ops(1)
+            dp.set_stripes(stripes)
+            dp.add_bytes_in(total)
+            bm = matrix_to_device_bitmatrix(matrix, w)
+            dp.add_upload(total)
+            with dp.stage("upload"):
+                dev = [jax.device_put(r) for r in rows]
+            with dp.stage("compute"):
+                pad = (bucket_pow2(stripes) - stripes) * (n4 // stripes)
+                if pad:
+                    dev = [jnp.pad(d, (0, pad)) for d in dev]
+                    record_pad(4 * pad * len(dev))
+                note_shape("ec_shards", n4 + pad, len(dev), len(matrix), w)
+                odev = gf_matrix_words(bm, tuple(dev), w=w)
+            with dp.stage("sync"):
+                out = np.asarray(odev)
+            kt.bytes_out = 4 * n4 * len(matrix)
+        return [out[j, :n4].view(np.uint8) for j in range(out.shape[0])]
 
     @staticmethod
     def _bitplane_call(bm, stripes: np.ndarray, w: int):

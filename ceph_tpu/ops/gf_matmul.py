@@ -106,6 +106,40 @@ def gf_matrix_stripes(
         return unfold_stripes(out, b, chunk)
 
 
+@functools.partial(jax.jit, static_argnames=("w",))
+def gf_matrix_words(bm: jnp.ndarray, words, *, w: int) -> jnp.ndarray:
+    """Decode-from-survivors in shard form.  ``words`` is a tuple of
+    s equal-length 1-D uint32 arrays — survivor shards as they are
+    stored (chunk i of every stripe concatenated, which IS the folded
+    region layout) viewed as little-endian words, the form that
+    crosses the link at its full rate (a uint8 array of the same
+    bytes is re-tiled on the way: PERF.md section 6, PR 28) — and
+    ``bm`` the reconstruction matrix's bitmatrix, an OPERAND: one
+    program per (rows out, rows in, length) serves every erasure
+    pattern.  → (r, nwords) uint32, row j the j-th rebuilt shard.
+
+    A uint32 holds 32 // w code words; sub-word q's bit x is bit
+    ``w*q + x``.  Each q is one mod-2 matmul over its bit planes, and
+    the planes of the result are shifted back where they came from —
+    no uint8 array exists on the device."""
+    per = 32 // w
+    with jax.named_scope("ec_bitplane_decode"):
+        x = jnp.stack(words)
+        s, n = x.shape
+        r = bm.shape[0] // w
+        acc = jnp.zeros((r, n), dtype=jnp.uint32)
+        for q in range(per):
+            shift = jnp.arange(w * q, w * q + w, dtype=jnp.uint32)
+            bits = (x[:, None, :] >> shift[None, :, None]) & jnp.uint32(1)
+            out = mod2_matmul(bm, bits.astype(jnp.int8).reshape(s * w, n))
+            planes = out.reshape(r, w, n).astype(jnp.uint32)
+            # the w planes of a sub-word touch disjoint bits: sum is OR
+            acc = acc | (planes << shift[None, :, None]).sum(
+                axis=1, dtype=jnp.uint32
+            )
+        return acc
+
+
 @functools.lru_cache(maxsize=512)
 def _bitmatrix_cache(key: bytes, shape: tuple, w: int, dtype) -> jnp.ndarray:
     from .. import gf

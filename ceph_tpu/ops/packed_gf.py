@@ -16,10 +16,12 @@ This kernel keeps bytes PACKED four-per-u32 lane end to end:
   once, so the OR-accumulated result IS the byte-packed output lane.
 
 Per input byte this costs ~15 single VPU ops (after the pair-CSE
-schedule below) with NO 8x blowup and no MXU dependence; measured on
-a v5e the k=8,m=3 encode runs at 124-139 GB/s of input vs 72-77 GB/s
-for the bitplane matmul (bench.py methodology; ops/pallas_gf.py keeps
-the older measurement history).  The add-chain is unrolled per
+schedule below) with NO 8x blowup and no MXU dependence; on a v5e
+the k=8,m=3 encode of 64 MiB takes 0.28 ms of device time (0.0042 ns
+a byte of input, 40% of the HBM roofline; PERF.md section 5, PR 28:
+the benchmark's traced ``ec_plugin_k8m3.encode_1m``), where the
+bitplane program with its matrix as an operand takes 1.73 ms for the
+same 64 MiB of survivors.  The add-chain is unrolled per
 bitmatrix at trace time — kernels cache per matrix exactly like the
 reference's per-signature table expansion (ErasureCodeIsa.cc:402
 ec_init_tables).
@@ -243,20 +245,3 @@ def packed_bitmatrix_regions(
         bm, to_words(np.asarray(regions)), interpret=interpret
     )
     return from_words([np.asarray(o) for o in outs])
-
-
-def packed_matrix_stripes(
-    bm: np.ndarray, stripes: np.ndarray, *, interpret: bool = False
-) -> np.ndarray:
-    """Batched (B, k, chunk) u8 → (B, m, chunk) u8 through the packed
-    kernel (the hoisted ECUtil::encode seam).  Host-side fold: the
-    device-side transpose is exactly the relayout this kernel exists
-    to avoid."""
-    from ..layout import fold_stripes, unfold_stripes
-
-    stripes = np.ascontiguousarray(stripes, dtype=np.uint8)
-    b, _k, chunk = stripes.shape
-    out = packed_bitmatrix_regions(
-        bm, fold_stripes(stripes), interpret=interpret
-    )
-    return unfold_stripes(out, b, chunk)

@@ -3,9 +3,11 @@
 
 Same options (-s/-i/-p/-w/-e/--erased/-E/-P), same output contract —
 one line ``<seconds>\\t<KB>`` so qa/workunits/erasure-code/bench.sh's
-GB/s conversion works unchanged.  Extension: ``--batch B`` encodes B
-stripes per iteration through the hoisted batched path (the TPU seam,
-ECUtil::encode's per-stripe loop in one device call).
+GB/s conversion works unchanged.  Extension: ``--batch B`` encodes or
+decodes B buffers per iteration through the stripe seam the OSD calls
+(ec/stripe.py: ECUtil::encode's and ::decode's per-stripe loops, each
+in one device call).  One untimed iteration runs before the clock, so
+the seconds printed hold no compilation.
 """
 
 from __future__ import annotations
@@ -42,7 +44,7 @@ def parse_args(argv=None):
     p.add_argument("-P", "--parameter", action="append", default=[],
                    help="add key=value to the erasure code profile")
     p.add_argument("--batch", type=int, default=1,
-                   help="stripes per device call (TPU batched path)")
+                   help="buffers per call of the stripe seam (one dispatch)")
     return p.parse_args(argv)
 
 
@@ -60,36 +62,45 @@ def make_code(args):
     return registry_instance().factory(args.plugin, profile)
 
 
+def _batch_payload(args, ec):
+    """``--batch B``: B buffers of ``--size`` as one object for the
+    stripe seam (ec/stripe.py, what the OSD calls) — the StripeInfo
+    whose stripe is one padded buffer, and the B buffers back to
+    back."""
+    from ..ec.stripe import StripeInfo
+
+    k = ec.get_data_chunk_count()
+    width = ec.get_chunk_size(args.size) * k
+    one = (b"X" * args.size).ljust(width, b"\0")
+    return StripeInfo(k, width), np.frombuffer(
+        one * args.batch, dtype=np.uint8
+    )
+
+
 def run_encode(args, ec) -> tuple[float, int]:
+    """One untimed iteration first (on a device backend the first
+    call compiles; the printed seconds hold none of that), then the
+    clock."""
     data = b"X" * args.size
     want = set(range(ec.get_chunk_count()))
     if args.batch > 1:
-        # hoisted path: B identical-geometry stripes in one call
-        chunk = ec.get_chunk_size(args.size)
-        k = ec.get_data_chunk_count()
-        stripes = np.frombuffer(
-            data.ljust(chunk * k, b"\0"), dtype=np.uint8
-        ).reshape(1, k, chunk)
-        stripes = np.broadcast_to(
-            stripes, (args.batch, k, chunk)
-        ).copy()
-        backend = ec.backend
-        matrix = getattr(ec, "matrix", None)
-        if matrix is None or not hasattr(backend, "matrix_stripes"):
-            raise SystemExit(
-                "--batch needs a matrix technique (reed_sol_*, isa)"
-            )
-        begin = time.perf_counter()
-        for _ in range(args.iterations):
-            backend.matrix_stripes(matrix, stripes, ec.w)
-        elapsed = time.perf_counter() - begin
-        kb = args.iterations * args.batch * (args.size // 1024)
-        return elapsed, kb
+        # hoisted path: B buffers in one call of the stripe seam
+        from ..ec import stripe
+
+        sinfo, payload = _batch_payload(args, ec)
+
+        def once():
+            stripe.encode(sinfo, ec, payload)
+    else:
+        def once():
+            ec.encode(want, data)
+
+    once()
     begin = time.perf_counter()
     for _ in range(args.iterations):
-        ec.encode(want, data)
+        once()
     elapsed = time.perf_counter() - begin
-    return elapsed, args.iterations * (args.size // 1024)
+    return elapsed, args.iterations * args.batch * (args.size // 1024)
 
 
 def _display_chunks(chunks, count):
@@ -99,15 +110,15 @@ def _display_chunks(chunks, count):
     print(out + "(X) is an erased chunk")
 
 
-def _decode_exhaustive(ec, all_chunks, chunks, start, want, verbose):
+def _decode_exhaustive(decode, all_chunks, chunks, start, want, verbose):
     """Recursive exhaustive erasure sweep with content verification
     (decode_erasures, ceph_erasure_code_benchmark.cc:202-249)."""
-    n = ec.get_chunk_count()
+    n = len(all_chunks)
     if want == 0:
         if verbose:
             _display_chunks(chunks, n)
         want_to_read = {c for c in range(n) if c not in chunks}
-        decoded = ec.decode(want_to_read, chunks)
+        decoded = decode(want_to_read, chunks)
         for c in want_to_read:
             if not np.array_equal(decoded[c], all_chunks[c]):
                 raise SystemExit(
@@ -118,28 +129,44 @@ def _decode_exhaustive(ec, all_chunks, chunks, start, want, verbose):
         if i not in chunks:
             continue
         one_less = {c: v for c, v in chunks.items() if c != i}
-        _decode_exhaustive(ec, all_chunks, one_less, i + 1, want - 1,
+        _decode_exhaustive(decode, all_chunks, one_less, i + 1, want - 1,
                            verbose)
 
 
 def run_decode(args, ec) -> tuple[float, int]:
-    data = b"X" * args.size
+    """As :func:`run_encode`: one untimed iteration, then the clock.
+    With ``--batch B`` the B buffers' shards go through
+    ``ec/stripe.decode`` — one dispatch rebuilds the erased shards of
+    all B, whatever the erasure pattern; without it a pattern the
+    untimed iteration did not draw may still build its program inside
+    the clock."""
     n = ec.get_chunk_count()
     want = set(range(n))
-    encoded = ec.encode(want, data)
+    if args.batch > 1:
+        from ..ec import stripe
+
+        sinfo, payload = _batch_payload(args, ec)
+        encoded = stripe.encode(sinfo, ec, payload)
+
+        def decode(want_to_read, chunks):
+            return stripe.decode(sinfo, ec, chunks, want_to_read)
+    else:
+        encoded = ec.encode(want, b"X" * args.size)
+        decode = ec.decode
     if args.erased:
         for c in args.erased:
             encoded.pop(c, None)
         _display_chunks(encoded, n)
     rng = random.Random()
-    begin = time.perf_counter()
-    for _ in range(args.iterations):
+
+    def once():
         if args.erasures_generation == "exhaustive":
             _decode_exhaustive(
-                ec, encoded, dict(encoded), 0, args.erasures, args.verbose
+                decode, encoded, dict(encoded), 0, args.erasures,
+                args.verbose,
             )
         elif args.erased:
-            ec.decode(want, encoded)
+            decode(want, encoded)
         else:
             chunks = dict(encoded)
             for _ in range(args.erasures):
@@ -148,9 +175,14 @@ def run_decode(args, ec) -> tuple[float, int]:
                     if erasure in chunks:
                         break
                 chunks.pop(erasure)
-            ec.decode(want, chunks)
+            decode(want, chunks)
+
+    once()
+    begin = time.perf_counter()
+    for _ in range(args.iterations):
+        once()
     elapsed = time.perf_counter() - begin
-    return elapsed, args.iterations * (args.size // 1024)
+    return elapsed, args.iterations * args.batch * (args.size // 1024)
 
 
 def main(argv=None) -> int:

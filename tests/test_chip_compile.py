@@ -115,6 +115,25 @@ def test_packed_decode_kernel_two_erasures(one_chip):
 
 
 @pytest.mark.parametrize(
+    "rebuilt,survivors,nbytes",
+    [
+        (2, 8, 64 * 131072),  # the decode cell: 64 x 1 MiB, two lost of k=8 m=3
+        (1, 4, 256 * 4096),  # a degraded read of one 4 MiB object of the k=4 m=2 pool
+    ],
+)
+def test_shard_form_decode_program(one_chip, rebuilt, survivors, nbytes):
+    """What matrix_shards runs for ec/stripe.decode: the survivors as
+    uint32 words, the reconstruction bitmatrix an operand."""
+    compiled = gf_matmul.gf_matrix_words.lower(
+        _sds((rebuilt * 8, survivors * 8), jnp.int8, one_chip),
+        tuple(_sds((nbytes // 4,), jnp.uint32, one_chip) for _ in range(survivors)),
+        w=8,
+    ).compile()
+    assert "u8[" not in compiled.as_text()  # no byte array to re-tile
+    _fits(compiled)
+
+
+@pytest.mark.parametrize(
     "b,k,m,chunk",
     [
         (64, 8, 3, 131072),  # ec_benchmark batch, 1 MiB objects

@@ -51,14 +51,28 @@ def test_decode_matrix_matches_oracle():
     _check(np.asarray(dec), k, 2048)
 
 
-def test_stripes_layout():
+def test_stripes_layout(monkeypatch):
+    """The one stripes path of the packed kernel, the jax backend's
+    ``matrix_stripes`` (fold, word form, kernel, unfold), through the
+    kernel's interpreter."""
+    from ceph_tpu.ops import ec_backend
+
     k, m = 8, 3
     mat = reed_sol_vandermonde_coding_matrix(k, m, 8)
-    bm = np.asarray(matrix_to_device_bitmatrix(mat, 8))
     stripes = rng.integers(0, 256, (5, k, 512), dtype=np.uint8)
-    got = np.asarray(
-        packed_gf.packed_matrix_stripes(bm, stripes, interpret=True)
-    )
+    monkeypatch.setattr(ec_backend, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ec_backend.mesh, "default_mesh", lambda: None)
+    built = packed_gf.prebuilt_word_call
+    calls = []
+
+    def interpreted(bm, w=8):
+        calls.append(bm.shape)
+        return built(bm, w, interpret=True)
+
+    monkeypatch.setattr(packed_gf, "prebuilt_word_call", interpreted)
+    got = ec_backend.get_jax_backend().matrix_stripes(mat, stripes, 8)
+    assert calls == [(m * 8, k * 8)]
+    assert got.shape == (5, m, 512)
     for s in range(5):
         want = matrix_vector_mul_region(mat, stripes[s], 8)
         np.testing.assert_array_equal(got[s], want)

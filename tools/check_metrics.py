@@ -126,7 +126,8 @@ DISPATCH_COUNTERS = (
 # tracing-plane stage counters (common/tracing.py Tracer._complete →
 # ops/kernel_stats.py record_stage): every finished span of one of
 # these names — the served write from the client's aio queue to the
-# shard commit, and the remap's stages — feeds
+# shard commit, the remap's stages, and the EC seam's host copies
+# round a plugin call — feeds
 # l_stage_<name>_{count,ns} (and _self_ns for a kernel_stats
 # SELF_TIME_STAGES name) that the benchmark's per-layer readers and
 # /metrics read
@@ -137,6 +138,7 @@ STAGE_SPANS = (
     "dev_upload", "dev_compute", "dev_sync",
     "remap", "crush_inputs", "crush_fallback", "fixup_exists",
     "fixup_upmap", "fixup_up", "fixup_affinity", "fixup_temp",
+    "ec_fold", "ec_unfold", "ec_assemble", "ec_plan",
 )
 # sharded bucket-index + reshard families the RGW schema must
 # declare (rgw/index.py build_rgw_perf — the bench rgw_index section
