@@ -62,6 +62,30 @@ class NumpyBackend:
         out = self.matrix_regions(matrix, fold_stripes(stripes), w)
         return unfold_stripes(out, b, chunk)
 
+    def matrix_stripe_shards(
+        self, matrix: np.ndarray, stripes: np.ndarray, w: int
+    ) -> tuple[list[np.ndarray], list[np.ndarray]]:
+        """``matrix_stripes`` in shard form, what ``stripe.encode``
+        hands out: (B, k, chunk) → the k data shards and the m coding
+        shards, each 1-D and contiguous, B*chunk long.  The fold is
+        the one copy of the input: its rows are the regions the math
+        reads and the data shards (views of the input when B is 1).
+        A host flight-recorder entry, as the other seams of this
+        oracle leave."""
+        from ..ops.profiler import dispatch_profiler
+
+        stripes = np.ascontiguousarray(stripes, dtype=np.uint8)
+        with dispatch_profiler().dispatch(
+            "ec_encode", backend=self.name
+        ) as dp:
+            dp.set_ops(1)
+            dp.set_stripes(stripes.shape[0])
+            dp.add_bytes_in(stripes.nbytes)
+            regions = np.ascontiguousarray(fold_stripes(stripes))
+            return list(regions), list(
+                self.matrix_regions(matrix, regions, w)
+            )
+
     def matrix_stripes_batch(
         self, matrix: np.ndarray, stripe_batches, w: int
     ) -> list[np.ndarray]:

@@ -7,6 +7,17 @@ batched device call across all stripes for matrix code families — the
 hoisted seam SURVEY.md §3.1 identifies — falling back to the per-stripe
 loop for layered codes.  ``HashInfo`` keeps the cumulative per-shard
 crc32c persisted as the hinfo xattr (ECUtil.cc:164-248).
+
+What an encode copies: its input ONCE.  A shard is chunk i of every
+stripe, concatenated — the folded region layout the kernels read — so
+the backend's fold (``matrix_stripe_shards``; span ``ec_fold`` round
+the row copies on the packed device path) is at once the upload's
+source and the k data shards, and the m rows that come back are the
+coding shards as they arrive (``ec_unfold`` brackets their views as
+bytes, ``ec_assemble`` the ``{position: shard}`` dict here: no payload
+byte moves in either).  The coalesced ``encode_batch`` gets stripe-form
+results and lays them out with ``_assemble_shards``; ``decode`` takes
+and returns shards as stored and copies nothing but the fetch.
 """
 
 from __future__ import annotations
@@ -97,9 +108,10 @@ def _assemble_shards(
     stripes: np.ndarray, coding: np.ndarray, k: int, n: int, want=None
 ) -> dict[int, np.ndarray]:
     """(B, k, chunk) data stripes + (B, m, chunk) coding → the
-    per-shard concatenated-chunk dict — the ONE layout assembly both
-    encode and encode_batch share (byte identity between the two
-    rests on there being a single copy of this)."""
+    per-shard concatenated-chunk dict — the ONE assembly of
+    stripe-form results, ``encode_batch``'s (``encode`` gets its
+    shards whole from the backend; tests/test_residency.py holds the
+    two byte-identical)."""
     out: dict[int, np.ndarray] = {}
     for i in range(k):
         if want is None or i in want:
@@ -120,9 +132,13 @@ def encode(
     """All stripes of ``data`` → per-shard concatenated chunks.
 
     Matrix code families take the batched path: (B, k, chunk) in one
-    device call; others run the reference's per-stripe loop.  Either
-    way the call lands in the ``l_tpu_ec_encode_*`` kernel counters
-    (calls, bytes in/out, sync-bounded latency)."""
+    device call (``matrix_stripe_shards``), whose k folded data rows
+    and m coding rows ARE the shards — the dict is built of them with
+    no further copy, so the data shards of one call are rows of one
+    buffer (for one stripe: views of ``data``) and nobody writes into
+    them; others run the reference's per-stripe loop.  Either way the
+    call lands in the ``l_tpu_ec_encode_*`` kernel counters (calls,
+    bytes in/out, sync-bounded latency)."""
     buf = np.frombuffer(bytes(data), dtype=np.uint8) if isinstance(
         data, (bytes, bytearray, memoryview)
     ) else np.ascontiguousarray(data, dtype=np.uint8).ravel()
@@ -139,12 +155,20 @@ def encode(
         return {}
 
     with _kstats().timed("ec_encode", bytes_in=buf.nbytes) as kt:
-        matrix, backend, ok = _matrix_fast_path(ec, "matrix_stripes")
+        matrix, backend, ok = _matrix_fast_path(
+            ec, "matrix_stripe_shards"
+        )
         if ok:
             stripes = buf.reshape(nstripes, k, sinfo.chunk_size)
-            coding = backend.matrix_stripes(matrix, stripes, ec.w)
+            data_rows, coding_rows = backend.matrix_stripe_shards(
+                matrix, stripes, ec.w
+            )
             with tracing.stage("ec_assemble"):
-                out = _assemble_shards(stripes, coding, k, n, want)
+                out = {
+                    p: row
+                    for p, row in enumerate(data_rows + coding_rows)
+                    if p in want
+                }
         else:
             # layered/bitmatrix per-stripe loop: one host-path
             # flight-recorder entry for the whole object (the inner

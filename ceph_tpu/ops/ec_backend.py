@@ -39,6 +39,28 @@ import functools
 
 from ..ec.backend import _host_row as _row_u8
 
+
+def _shard_rows(stripes: np.ndarray) -> list[np.ndarray]:
+    """(B, n, chunk) host stripes → the n shards, 1-D and contiguous:
+    one copy when B > 1; one stripe is its own fold, so its rows are
+    views."""
+    return list(np.ascontiguousarray(fold_stripes(stripes)))
+
+
+def _fold_buffer(stripes: np.ndarray, k: int, n: int) -> np.ndarray:
+    """An empty (k, n) uint8 array for the fold of ``stripes``, started
+    half a page off the input within a page.  Where a copy's
+    destination lies 1 to 31 bytes above its source modulo 4096 (16
+    is what two neighbouring heap blocks give) the chip host's memcpy
+    runs at a quarter of its speed — the 64 MiB fold 24 ms for 6:
+    PERF.md section 6, PR 30 — and exactly 0 costs a third more on
+    another CPU.  Chunks and rows of whole pages keep the half page
+    for every piece of the fold; one page of slack buys it."""
+    raw = np.empty(k * n + 4096, dtype=np.uint8)
+    lead = (stripes.ctypes.data + 2048 - raw.ctypes.data) % 4096
+    return raw[lead : lead + k * n].reshape(k, n)
+
+
 @functools.lru_cache(maxsize=512)
 def _host_bitmatrix(key: bytes, shape: tuple, w: int):
     """Host-side bitmatrix + packed-kernel eligibility, cached per
@@ -109,11 +131,29 @@ class JaxBackend:
     def matrix_stripes(
         self, matrix: np.ndarray, stripes, w: int
     ) -> np.ndarray:
-        """Batched (B, k, chunk) → (B, m, chunk); numpy in, numpy out.
+        """Batched (B, k, chunk) → (B, m, chunk); numpy in, numpy out:
+        the coding shards of :meth:`matrix_stripe_shards`, stacked
+        back into stripe form.
 
         Device-array pipelines that want to keep results on-chip call
         ``ops.gf_matmul.gf_matrix_stripes`` (or
         ``ops.packed_gf.prebuilt_word_call``) directly instead."""
+        b, _k, chunk = np.shape(stripes)
+        _data, coding = self.matrix_stripe_shards(matrix, stripes, w)
+        return unfold_stripes(np.stack(coding), b, chunk)
+
+    def matrix_stripe_shards(
+        self, matrix: np.ndarray, stripes, w: int
+    ) -> tuple[list[np.ndarray], list[np.ndarray]]:
+        """One encode dispatch in shard form, what ``stripe.encode``
+        hands out: (B, k, chunk) → the k data shards and the m coding
+        shards as host arrays, each 1-D and contiguous, B*chunk long
+        (shard i is chunk i of every stripe, concatenated: the folded
+        region layout).  One flight-recorder entry of kind
+        ``ec_encode`` (ops 1, stripes B).  The packed path folds the
+        input once and hands that copy out (:meth:`_packed_stripes`);
+        the mesh and bitplane paths compute in stripe form and fold
+        both sides at their edge."""
         stripes = np.ascontiguousarray(stripes, dtype=np.uint8)
         b, _k, chunk = stripes.shape
         with kernel_stats().timed(
@@ -142,13 +182,15 @@ class JaxBackend:
                         bm, stripes, w, dmesh
                     )
                 kt.bytes_out = out.nbytes
-                return out
+                return _shard_rows(stripes), _shard_rows(out)
             if w == 8 and _on_tpu() and (b * chunk) % 4 == 0:
                 bm_np, ok = _host_bm(matrix, w)
                 if ok:
-                    out = self._packed_stripes(dp, bm_np, stripes)
-                    kt.bytes_out = out.nbytes
-                    return out
+                    data, coding = self._packed_stripes(
+                        dp, bm_np, stripes
+                    )
+                    kt.bytes_out = sum(c.nbytes for c in coding)
+                    return data, coding
             bm = matrix_to_device_bitmatrix(matrix, w)
             with dp.stage("upload"):
                 dev = jnp.asarray(stripes)
@@ -158,30 +200,51 @@ class JaxBackend:
             with dp.stage("sync"):
                 out = np.asarray(odev)[:b]
             kt.bytes_out = out.nbytes
-            return out
+            return _shard_rows(stripes), _shard_rows(out)
 
     @staticmethod
     def _packed_stripes(dp, bm_np: np.ndarray, stripes: np.ndarray):
-        """The packed-lane path of :meth:`matrix_stripes`, the one
-        stripes form of the packed kernel, each stage bracketed where
-        it happens: the host fold (span ``ec_fold``; a device-side
-        transpose is the relayout this kernel exists to avoid),
-        the k row uploads, the kernel's issue, the wait for and copy
-        of the m result rows, the host unfold (span ``ec_unfold``)."""
+        """The packed-lane path of :meth:`matrix_stripe_shards`, the
+        one stripes form of the packed kernel.  An encode makes ONE
+        host copy of its input, the fold (a device-side transpose is
+        the relayout this kernel exists to avoid): row i of the fold
+        is data shard i, the upload's source and part of the answer,
+        and fetched row j is coding shard k+j as it arrives — nothing
+        is stacked or laid out again.  Each row goes up the link as
+        soon as it is folded, so the transfer drains under the rest
+        of the fold.  Each stage is bracketed where it happens, the
+        repeated ones accumulating: the row copies (span ``ec_fold``),
+        the row ``device_put``s (``upload``), the kernel's issue
+        (``compute``), the wait for and copy of the m result rows
+        (``sync``), their views as bytes (span ``ec_unfold``)."""
         import jax
 
-        b, _k, chunk = stripes.shape
-        with tracing.stage("ec_fold"):
-            words = packed_gf.to_words(fold_stripes(stripes))
+        b, k, chunk = stripes.shape
+        # one stripe is its own fold: its rows are views of the input
+        # and there is nothing to copy
+        folded = (
+            stripes.reshape(k, chunk)
+            if b == 1
+            else _fold_buffer(stripes, k, b * chunk)
+        )
         dp.add_upload(stripes.nbytes)
-        with dp.stage("upload"):
-            dev = [jax.device_put(x) for x in words]
+        dev = []
+        for i, row in enumerate(folded):
+            with tracing.stage("ec_fold"):
+                if b > 1:
+                    np.copyto(row.reshape(b, chunk), stripes[:, i, :])
+                words = row.view(np.uint32).reshape(1, -1)
+            with dp.stage("upload"):
+                dev.append(jax.device_put(words))
         with dp.stage("compute"):
             outs = packed_gf.prebuilt_word_call(bm_np)(*dev)
         with dp.stage("sync"):
+            for o in outs:
+                o.copy_to_host_async()
             host = [np.asarray(o) for o in outs]
         with tracing.stage("ec_unfold"):
-            return unfold_stripes(packed_gf.from_words(host), b, chunk)
+            coding = [h.reshape(-1).view(np.uint8) for h in host]
+        return list(folded), coding
 
     def matrix_shards(
         self, matrix: np.ndarray, shards, w: int, stripes: int

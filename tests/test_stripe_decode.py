@@ -220,7 +220,8 @@ def test_the_packed_encode_path_brackets_its_stages(monkeypatch):
     """The chip-only path of matrix_stripes, run here through the
     kernel's interpreter: the same bytes as the bitplane path, the
     recorder's upload / compute / sync each bracketing its own part,
-    the host fold and unfold in spans."""
+    the host fold (a span a row, each row going up as it is folded)
+    and unfold in spans."""
     ec = _code(technique="reed_sol_van", k="4", m="2", w="8", backend="jax")
     sinfo = StripeInfo(4, 4 * 512)
     data = np.random.default_rng(4).integers(0, 256, 4 * 4 * 512, dtype=np.uint8)
@@ -240,4 +241,5 @@ def test_the_packed_encode_path_brackets_its_stages(monkeypatch):
     assert rec["bytes_uploaded"] == data.nbytes
     assert rec["transfer_s"] > 0 and rec["compute_s"] > 0 and rec["sync_s"] > 0
     assert rec["transfer_s"] + rec["compute_s"] + rec["sync_s"] <= rec["wall_s"]
-    assert _stage_counts("ec_fold", "ec_unfold", "ec_assemble") == [n + 1 for n in spans]
+    assert _stage_counts("ec_fold", "ec_unfold", "ec_assemble") == [
+        spans[0] + 4, spans[1] + 1, spans[2] + 1]
