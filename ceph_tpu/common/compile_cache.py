@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
-One rule for every entry point (``chip_smoke.py``, ``bench.py``, the
-device-touching tools' ``main`` and ``proc/daemon.py``): where
+One rule for every entry point (``chip_smoke.py``, the device-touching
+tools' ``main`` and ``proc/daemon.py``): where
 ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and nothing
 is set in code; where it is not, the cache is ``<checkout>/.jax_cache``
 (listed in ``.gitignore``).  The path is part of the cache key, so it

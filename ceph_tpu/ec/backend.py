@@ -3,7 +3,9 @@
 The reference dispatches its GF region kernels to CPU SIMD libraries
 (gf-complete / isa-l asm); here the same seam dispatches to either the
 numpy oracle or the TPU kernels in ``ceph_tpu.ops`` (registered lazily on
-first use of ``backend=jax``).  Both implement:
+first use of ``backend=jax``).  Both implement six entries, one job
+each, and the stripe seam (``ec/stripe.py``) asks for none of them by
+name:
 
 - ``matrix_regions(matrix, regions, w)``      — GF(2^w) matrix x chunk
   regions (the jerasure_matrix_encode / ec_encode_data contract).
@@ -11,6 +13,10 @@ first use of ``backend=jax``).  Both implement:
   packet-interleaved regions (the jerasure_bitmatrix_dotprod contract:
   each chunk is blocks of w packets of ``packetsize`` bytes; output packet
   (i) of a block = XOR of input packets (j) where bm[i, j] == 1).
+- ``matrix_stripe_shards`` / ``matrix_shards`` — one object's encode /
+  rebuild, every stripe in one dispatch, shards in and out.
+- ``matrix_stripes_batch`` / ``decode_stripes_batch`` — many objects'
+  encode / rebuild in one coalesced dispatch.
 """
 
 from __future__ import annotations
@@ -56,11 +62,26 @@ class NumpyBackend:
         self, matrix: np.ndarray, stripes: np.ndarray, w: int
     ) -> np.ndarray:
         """Batched (B, k, chunk) → (B, m, chunk): stripes fold into the
-        region byte dimension (same layout as the jax backend)."""
+        region byte dimension (same layout as the jax backend).  The
+        stripe-form reference the batched seams of this oracle, the
+        tests and ``chip_smoke.py`` compare with; the jax backend has
+        no such entry."""
         stripes = np.ascontiguousarray(stripes, dtype=np.uint8)
         b, _k, chunk = stripes.shape
         out = self.matrix_regions(matrix, fold_stripes(stripes), w)
         return unfold_stripes(out, b, chunk)
+
+    def _host_entry(self, kind: str, **totals):
+        """A host flight-recorder entry (``totals``: its ``ops``,
+        ``stripes`` and ``bytes_in``), what every batched seam of this
+        oracle leaves so the dispatch plane stays populated
+        deviceless.  Lazy: ceph_tpu.ops registers the jax backend
+        through this module."""
+        from ..ops.profiler import dispatch_profiler
+
+        return dispatch_profiler().dispatch(
+            kind, backend=self.name, **totals
+        )
 
     def matrix_stripe_shards(
         self, matrix: np.ndarray, stripes: np.ndarray, w: int
@@ -69,18 +90,14 @@ class NumpyBackend:
         hands out: (B, k, chunk) → the k data shards and the m coding
         shards, each 1-D and contiguous, B*chunk long.  The fold is
         the one copy of the input: its rows are the regions the math
-        reads and the data shards (views of the input when B is 1).
-        A host flight-recorder entry, as the other seams of this
-        oracle leave."""
-        from ..ops.profiler import dispatch_profiler
-
+        reads and the data shards (views of the input when B is 1)."""
         stripes = np.ascontiguousarray(stripes, dtype=np.uint8)
-        with dispatch_profiler().dispatch(
-            "ec_encode", backend=self.name
-        ) as dp:
-            dp.set_ops(1)
-            dp.set_stripes(stripes.shape[0])
-            dp.add_bytes_in(stripes.nbytes)
+        with self._host_entry(
+            "ec_encode",
+            ops=1,
+            stripes=stripes.shape[0],
+            bytes_in=stripes.nbytes,
+        ):
             regions = np.ascontiguousarray(fold_stripes(stripes))
             return list(regions), list(
                 self.matrix_regions(matrix, regions, w)
@@ -92,17 +109,14 @@ class NumpyBackend:
         """Coalesced-encode seam (the jax backend double-buffers
         device transfers here); the oracle just loops — coalescing is
         a dispatch-cost optimization, and the oracle has no dispatch
-        cost to amortize.  Still records a flight-recorder host entry
-        so the dispatch plane stays populated deviceless."""
-        from ..ops.profiler import dispatch_profiler
-
+        cost to amortize."""
         batches = list(stripe_batches)
-        with dispatch_profiler().dispatch(
-            "ec_encode", backend=self.name
-        ) as dp:
-            dp.set_ops(len(batches))
-            dp.set_stripes(sum(s.shape[0] for s in batches))
-            dp.add_bytes_in(sum(s.nbytes for s in batches))
+        with self._host_entry(
+            "ec_encode",
+            ops=len(batches),
+            stripes=sum(s.shape[0] for s in batches),
+            bytes_in=sum(s.nbytes for s in batches),
+        ):
             return [
                 self.matrix_stripes(matrix, s, w) for s in batches
             ]
@@ -119,15 +133,11 @@ class NumpyBackend:
         matrix.  The oracle loops — it has no dispatch cost to
         amortize — through the same C region-MAC fast path the
         encode side uses."""
-        from ..ops.profiler import dispatch_profiler
-
-        with dispatch_profiler().dispatch(
-            "ec_decode", backend=self.name
+        with self._host_entry(
+            "ec_decode",
+            ops=len(row_sets),
+            bytes_in=sum(len(r) for rows in row_sets for r in rows),
         ) as dp:
-            dp.set_ops(len(row_sets))
-            dp.add_bytes_in(
-                sum(len(r) for rows in row_sets for r in rows)
-            )
             outs: list[np.ndarray] = []
             for rows in row_sets:
                 arr = np.stack(
@@ -144,17 +154,11 @@ class NumpyBackend:
         """One object's reconstruction in shard form (the jax backend
         makes it one device dispatch): the s survivor shards are the
         regions as they are stored, the (r, s) reconstruction rows
-        give the r rebuilt shards.  A host flight-recorder entry, as
-        the other batched seams of this oracle leave."""
-        from ..ops.profiler import dispatch_profiler
-
+        give the r rebuilt shards."""
         regions = np.stack([_host_row(s) for s in shards])
-        with dispatch_profiler().dispatch(
-            "ec_decode", backend=self.name
-        ) as dp:
-            dp.set_ops(1)
-            dp.set_stripes(stripes)
-            dp.add_bytes_in(regions.nbytes)
+        with self._host_entry(
+            "ec_decode", ops=1, stripes=stripes, bytes_in=regions.nbytes
+        ):
             return list(self.matrix_regions(matrix, regions, w))
 
     def bitmatrix_regions(

@@ -335,9 +335,7 @@ class ErasureCodeLrc(ErasureCode):
                 inner.get_coding_chunk_count()
             ):
                 continue
-            matrix, backend, ok = _matrix_fast_path(
-                inner, "decode_stripes_batch"
-            )
+            matrix, backend, ok = _matrix_fast_path(inner)
             if not ok:
                 continue
             k_l, w = inner.get_data_chunk_count(), inner.w

@@ -26,6 +26,7 @@ import numpy as np
 
 from ..common import tracing
 from ..native import ceph_crc32c
+from .backend import _host_row
 from .interface import ErasureCodeError
 
 
@@ -83,12 +84,11 @@ def _kstats():
     return kernel_stats()
 
 
-def _matrix_fast_path(ec, needs: str):
+def _matrix_fast_path(ec):
     """The ONE eligibility gate for the batched matrix device path
     (shared by encode and encode_batch so the two can never drift):
     returns (matrix, backend, ok) where ok means the code family's
-    whole-word matrix math is safe to batch AND the backend has the
-    ``needs`` entry point.  Bitmatrix techniques
+    whole-word matrix math is safe to batch.  Bitmatrix techniques
     (cauchy/liberation/blaum_roth) carry a .matrix too, but encode
     through XOR schedules over packet planes — the word-wise matrix
     path would corrupt them; chunk remapping likewise bails."""
@@ -98,10 +98,38 @@ def _matrix_fast_path(ec, needs: str):
         matrix is not None
         and getattr(ec, "bitmatrix", None) is None
         and backend is not None
-        and hasattr(backend, needs)
         and not ec.get_chunk_mapping()
     )
     return matrix, backend, ok
+
+
+def _host_loop(kind: str, ec, ops: int, stripes: int, bytes_in: int):
+    """THE bracket of the per-stripe fallbacks (layered and bitmatrix
+    codes, shards that are not whole words): one host-path
+    flight-recorder entry of ``kind`` for the whole loop, under the
+    codec's backend name — the inner ``ec.encode`` / ``ec._decode``
+    calls record nothing themselves."""
+    from ..ops.profiler import dispatch_profiler
+
+    return dispatch_profiler().dispatch(
+        kind,
+        backend=getattr(getattr(ec, "backend", None), "name", None)
+        or "cpu",
+        ops=ops,
+        stripes=stripes,
+        bytes_in=bytes_in,
+    )
+
+
+def _logical_buffer(sinfo: StripeInfo, data) -> np.ndarray:
+    """An encode's input as a 1-D uint8 array (the one coercion of
+    ec/backend._host_row); whole stripes or an error."""
+    buf = _host_row(data)
+    if len(buf) % sinfo.stripe_width:
+        raise ErasureCodeError(
+            f"logical size {len(buf)} not stripe aligned"
+        )
+    return buf
 
 
 def _assemble_shards(
@@ -139,13 +167,7 @@ def encode(
     them; others run the reference's per-stripe loop.  Either way the
     call lands in the ``l_tpu_ec_encode_*`` kernel counters (calls,
     bytes in/out, sync-bounded latency)."""
-    buf = np.frombuffer(bytes(data), dtype=np.uint8) if isinstance(
-        data, (bytes, bytearray, memoryview)
-    ) else np.ascontiguousarray(data, dtype=np.uint8).ravel()
-    if len(buf) % sinfo.stripe_width:
-        raise ErasureCodeError(
-            f"logical size {len(buf)} not stripe aligned"
-        )
+    buf = _logical_buffer(sinfo, data)
     n = ec.get_chunk_count()
     k = ec.get_data_chunk_count()
     if want is None:
@@ -155,9 +177,7 @@ def encode(
         return {}
 
     with _kstats().timed("ec_encode", bytes_in=buf.nbytes) as kt:
-        matrix, backend, ok = _matrix_fast_path(
-            ec, "matrix_stripe_shards"
-        )
+        matrix, backend, ok = _matrix_fast_path(ec)
         if ok:
             stripes = buf.reshape(nstripes, k, sinfo.chunk_size)
             data_rows, coding_rows = backend.matrix_stripe_shards(
@@ -170,21 +190,7 @@ def encode(
                     if p in want
                 }
         else:
-            # layered/bitmatrix per-stripe loop: one host-path
-            # flight-recorder entry for the whole object (the inner
-            # ec.encode calls record nothing themselves)
-            from ..ops.profiler import dispatch_profiler
-
-            bname = (
-                getattr(getattr(ec, "backend", None), "name", None)
-                or "cpu"
-            )
-            with dispatch_profiler().dispatch(
-                "ec_encode", backend=bname
-            ) as dp:
-                dp.set_ops(1)
-                dp.set_stripes(nstripes)
-                dp.add_bytes_in(buf.nbytes)
+            with _host_loop("ec_encode", ec, 1, nstripes, buf.nbytes):
                 parts = {i: [] for i in range(n)}
                 for s in range(nstripes):
                     stripe = buf[
@@ -216,22 +222,10 @@ def encode_batch(
     Each coalesced dispatch counts in
     ``l_tpu_batch_encode_{dispatches,ops_per_dispatch}``.
     """
-    bufs = [
-        np.frombuffer(bytes(b), dtype=np.uint8)
-        if isinstance(b, (bytes, bytearray, memoryview))
-        else np.ascontiguousarray(b, dtype=np.uint8).ravel()
-        for b in buffers
-    ]
-    for buf in bufs:
-        if len(buf) % sinfo.stripe_width:
-            raise ErasureCodeError(
-                f"logical size {len(buf)} not stripe aligned"
-            )
+    bufs = [_logical_buffer(sinfo, b) for b in buffers]
     n = ec.get_chunk_count()
     k = ec.get_data_chunk_count()
-    matrix, backend, ok = _matrix_fast_path(
-        ec, "matrix_stripes_batch"
-    )
+    matrix, backend, ok = _matrix_fast_path(ec)
     if not ok or len(bufs) < 2:
         return [encode(sinfo, ec, buf) for buf in bufs]
 
@@ -262,16 +256,6 @@ def encode_batch(
             v.nbytes for shards in out for v in shards.values()
         )
     return out
-
-
-def _as_row(x) -> np.ndarray:
-    """1-D uint8 view of a survivor payload: the ONE coercion helper
-    (ec/backend._host_row) shared by the stripe seam and both compute
-    backends — DeviceBuf tokens fetch host-side, bytes-likes go
-    through frombuffer."""
-    from .backend import _host_row
-
-    return _host_row(x)
 
 
 def survivor_basis(
@@ -319,29 +303,20 @@ def reconstruction_rows(
     return np.array(rows, dtype=np.int64).reshape(len(rows), k), survivors
 
 
-def decode_reconstruction(
-    ec, want, available, needs: str = "decode_stripes_batch"
-):
+def decode_reconstruction(ec, want, available):
     """The decode analog of :func:`_matrix_fast_path`: a
     (rows, survivors, w, backend) plan that rebuilds ``want`` from
-    ``available`` in one batched device dispatch through the
-    backend's ``needs`` entry point, or None when the backend lacks
-    it or the code family cannot express its repair as whole-word
-    matrix math (bitmatrix/layered codes without a ``decode_matrix``
-    hook, chunk remapping, unsolvable systems)."""
+    ``available`` in one batched device dispatch, or None when the
+    code family cannot express its repair as whole-word matrix math
+    (bitmatrix/layered codes without a ``decode_matrix`` hook, chunk
+    remapping, unsolvable systems)."""
     hook = getattr(ec, "decode_matrix", None)
-    if hook is not None:
-        try:
-            plan = hook(set(want), set(available))
-        except ErasureCodeError:
-            return None
-        if plan is not None and not hasattr(plan[3], needs):
-            return None
-        return plan
-    matrix, backend, ok = _matrix_fast_path(ec, needs)
-    if not ok:
-        return None
     try:
+        if hook is not None:
+            return hook(set(want), set(available))
+        matrix, backend, ok = _matrix_fast_path(ec)
+        if not ok:
+            return None
         rows, survivors = reconstruction_rows(
             matrix, want, available, ec.get_data_chunk_count(), ec.w
         )
@@ -353,13 +328,19 @@ def decode_reconstruction(
 def _decode_one(ec, shards: dict[int, np.ndarray], want) -> dict:
     """Per-object decode-from-survivors — the reference per-op repair
     path (ErasureCode::_decode) and the oracle the batched dispatch
-    must match byte for byte."""
-    chunks = {i: _as_row(v) for i, v in shards.items()}
-    decoded = ec._decode(set(want), chunks)
-    return {
-        p: np.ascontiguousarray(decoded[p], dtype=np.uint8)
-        for p in sorted(want)
-    }
+    must match byte for byte; one ``l_tpu_ec_decode_*`` call an
+    object."""
+    with _kstats().timed(
+        "ec_decode", bytes_in=sum(len(v) for v in shards.values())
+    ) as kt:
+        chunks = {i: _host_row(v) for i, v in shards.items()}
+        decoded = ec._decode(set(want), chunks)
+        out = {
+            p: np.ascontiguousarray(decoded[p], dtype=np.uint8)
+            for p in sorted(want)
+        }
+        kt.bytes_out = sum(len(v) for v in out.values())
+    return out
 
 
 def decode_batch(
@@ -441,29 +422,12 @@ def decode_batch(
                 # asked for the device and must hear that it failed
                 batched = False
         if not batched:
-            # per-object repair loop: one host-path flight-recorder
-            # entry per degraded group (the inner ec._decode calls
-            # record nothing themselves)
-            from ..ops.profiler import dispatch_profiler
-
-            bname = (
-                getattr(getattr(ec, "backend", None), "name", None)
-                or "cpu"
+            nbytes = sum(
+                len(v) for i in idxs for v in shard_sets[i].values()
             )
-            with dispatch_profiler().dispatch(
-                "ec_decode", backend=bname
-            ) as dp:
-                dp.set_ops(len(idxs))
+            with _host_loop("ec_decode", ec, len(idxs), 0, nbytes):
                 for i in idxs:
-                    nbytes = sum(
-                        len(v) for v in shard_sets[i].values()
-                    )
-                    dp.add_bytes_in(nbytes)
-                    with ks.timed("ec_decode", bytes_in=nbytes) as kt:
-                        out[i] = _decode_one(ec, shard_sets[i], want)
-                        kt.bytes_out = sum(
-                            len(v) for v in out[i].values()
-                        )
+                    out[i] = _decode_one(ec, shard_sets[i], want)
     return out
 
 
@@ -521,9 +485,7 @@ def _rebuild(
     recorder entry, as :func:`encode`'s fallback does.  Byte-identical
     either way."""
     with tracing.stage("ec_plan"):
-        plan = decode_reconstruction(
-            ec, missing, views, needs="matrix_shards"
-        )
+        plan = decode_reconstruction(ec, missing, views)
     # shards travel to the device as 32-bit words
     if plan is not None and sinfo.chunk_size % 4 == 0:
         rows, survivors, w, backend = plan
@@ -531,14 +493,10 @@ def _rebuild(
             rows, [views[s] for s in survivors], w, nstripes
         )
         return dict(zip(sorted(missing), rebuilt))
-    from ..ops.profiler import dispatch_profiler
-
     cs = sinfo.chunk_size
-    bname = getattr(getattr(ec, "backend", None), "name", None) or "cpu"
-    with dispatch_profiler().dispatch("ec_decode", backend=bname) as dp:
-        dp.set_ops(1)
-        dp.set_stripes(nstripes)
-        dp.add_bytes_in(sum(v.nbytes for v in views.values()))
+    with _host_loop(
+        "ec_decode", ec, 1, nstripes, sum(v.nbytes for v in views.values())
+    ):
         parts: dict[int, list] = {p: [] for p in missing}
         for s in range(nstripes):
             chunks = {

@@ -10,7 +10,7 @@ Reed-Solomon coding matrix lifts to a (m·w, k·w) GF(2) bitmatrix and
 matmul on the systolic array per stripe batch, instead of k·m table-lookup
 region passes.  XOR-schedule (bitmatrix) techniques are the same primitive
 with packet-interleaved bit layout.  See ``gf_matmul`` for layout contracts
-and ``pallas_gf`` for the experimental fused VMEM kernel.
+and ``packed_gf`` for the packed-lane kernel of a generator matrix.
 
 Importing this module registers the ``jax`` erasure-code backend.
 """

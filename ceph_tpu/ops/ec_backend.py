@@ -10,12 +10,16 @@ table expansion (src/erasure-code/isa/ErasureCodeIsa.cc:402).
 
 from __future__ import annotations
 
+import functools
+
 import jax.numpy as jnp
 import numpy as np
 
 from ..common import tracing
+from ..ec.backend import _host_row as _row_u8
 from ..ec.backend import register_backend
-from ..layout import fold_stripes, unfold_stripes
+from ..ec.interface import ErasureCodeError
+from ..layout import fold_stripes
 from . import mesh, packed_gf
 from .gf_matmul import (
     bitmatrix_packet_regions,
@@ -33,11 +37,6 @@ def _on_tpu() -> bool:
     import jax
 
     return jax.default_backend() == "tpu"
-
-
-import functools
-
-from ..ec.backend import _host_row as _row_u8
 
 
 def _shard_rows(stripes: np.ndarray) -> list[np.ndarray]:
@@ -72,15 +71,31 @@ def _host_bitmatrix(key: bytes, shape: tuple, w: int):
     return bm, packed_gf.supports(bm, w)
 
 
-def _host_bm(matrix: np.ndarray, w: int):
+def _packed_bm(matrix: np.ndarray, w: int, region_bytes: int):
+    """The host bitmatrix the packed-lane kernel unrolls, or None where
+    the bitplane program runs instead: off the TPU, at another word
+    size, for regions that are not whole 32-bit words, or for a matrix
+    the kernel does not support."""
+    if not (w == 8 and _on_tpu() and region_bytes % 4 == 0):
+        return None
     mat = np.ascontiguousarray(matrix, dtype=np.int64)
-    return kernel_stats().counted_cache_call(
+    bm_np, ok = kernel_stats().counted_cache_call(
         _host_bitmatrix, mat.tobytes(), mat.shape, w
     )
+    return bm_np if ok else None
 
 
 class JaxBackend:
     name = "jax"
+
+    def _dispatch(self, kind: str, **totals):
+        """THE instrument of a dispatch site: one flight-recorder
+        entry of ``kind`` (``totals``: its ``ops``, ``stripes`` and
+        ``bytes_in``) whose commit also feeds the ``gf_matmul`` kernel
+        counters (ops/profiler.py)."""
+        return dispatch_profiler().dispatch(
+            kind, backend=self.name, group="gf_matmul", **totals
+        )
 
     def matrix_regions(
         self, matrix: np.ndarray, regions: np.ndarray, w: int
@@ -90,20 +105,16 @@ class JaxBackend:
         with kernel_stats().timed(
             "gf_matmul", bytes_in=regions.nbytes
         ) as kt:
-            if w == 8 and _on_tpu() and regions.shape[1] % 4 == 0:
-                bm_np, ok = _host_bm(matrix, w)
-                if ok:
-                    out = np.asarray(
-                        packed_gf.packed_bitmatrix_regions(
-                            bm_np, regions
-                        )
-                    )
-                    kt.bytes_out = out.nbytes
-                    return out
-            bm = matrix_to_device_bitmatrix(matrix, w)
-            out = np.asarray(
-                gf_matrix_regions(bm, jnp.asarray(regions), w=w)
-            )
+            bm_np = _packed_bm(matrix, w, regions.shape[1])
+            if bm_np is not None:
+                out = packed_gf.packed_bitmatrix_regions(bm_np, regions)
+            else:
+                out = gf_matrix_regions(
+                    matrix_to_device_bitmatrix(matrix, w),
+                    jnp.asarray(regions),
+                    w=w,
+                )
+            out = np.asarray(out)
             kt.bytes_out = out.nbytes
             return out
 
@@ -128,79 +139,60 @@ class JaxBackend:
             kt.bytes_out = out.nbytes
             return out
 
-    def matrix_stripes(
+    def matrix_stripe_shards(
         self, matrix: np.ndarray, stripes, w: int
-    ) -> np.ndarray:
-        """Batched (B, k, chunk) → (B, m, chunk); numpy in, numpy out:
-        the coding shards of :meth:`matrix_stripe_shards`, stacked
-        back into stripe form.
+    ) -> tuple[list[np.ndarray], list[np.ndarray]]:
+        """One object's encode in ONE device dispatch, in shard form,
+        what ``stripe.encode`` hands out: (B, k, chunk) → the k data
+        shards and the m coding shards as host arrays, each 1-D and
+        contiguous, B*chunk long (shard i is chunk i of every stripe,
+        concatenated: the folded region layout).  One flight-recorder
+        entry of kind ``ec_encode`` (ops 1, stripes B).  The packed
+        path folds the input once and hands that copy out
+        (:meth:`_packed_stripes`); the mesh and bitplane paths compute
+        in stripe form and fold both sides at their edge.
 
         Device-array pipelines that want to keep results on-chip call
         ``ops.gf_matmul.gf_matrix_stripes`` (or
         ``ops.packed_gf.prebuilt_word_call``) directly instead."""
-        b, _k, chunk = np.shape(stripes)
-        _data, coding = self.matrix_stripe_shards(matrix, stripes, w)
-        return unfold_stripes(np.stack(coding), b, chunk)
-
-    def matrix_stripe_shards(
-        self, matrix: np.ndarray, stripes, w: int
-    ) -> tuple[list[np.ndarray], list[np.ndarray]]:
-        """One encode dispatch in shard form, what ``stripe.encode``
-        hands out: (B, k, chunk) → the k data shards and the m coding
-        shards as host arrays, each 1-D and contiguous, B*chunk long
-        (shard i is chunk i of every stripe, concatenated: the folded
-        region layout).  One flight-recorder entry of kind
-        ``ec_encode`` (ops 1, stripes B).  The packed path folds the
-        input once and hands that copy out (:meth:`_packed_stripes`);
-        the mesh and bitplane paths compute in stripe form and fold
-        both sides at their edge."""
         stripes = np.ascontiguousarray(stripes, dtype=np.uint8)
         b, _k, chunk = stripes.shape
-        with kernel_stats().timed(
-            "gf_matmul", bytes_in=stripes.nbytes
-        ) as kt, dispatch_profiler().dispatch(
-            "ec_encode", backend=self.name
+        with self._dispatch(
+            "ec_encode", ops=1, stripes=b, bytes_in=stripes.nbytes
         ) as dp:
-            dp.set_ops(1)
-            dp.set_stripes(b)
-            dp.add_bytes_in(stripes.nbytes)
             # batch axis sharded across the device mesh when >1 device
             # exists and the batch is worth splitting — byte-identical
             # per-stripe math, just spread over chips (ops/mesh.py).
-            # Checked BEFORE the packed fast path: N chips of bitplane
-            # (~75 GB/s each) beat one chip of packed (~130 GB/s) for
-            # every N >= 2; the packed kernel folds the batch into its
-            # byte axis, so sharding it is future work
+            # Checked BEFORE the packed fast path: a mesh batch takes
+            # the bitplane program because the packed kernel folds the
+            # batch into its byte axis, so there is no batch axis left
+            # to shard.  Which of the two is faster is not measured
+            # (ROADMAP S7)
             dmesh = mesh.default_mesh()
-            if dmesh is not None and b >= dmesh.n:
+            on_mesh = dmesh is not None and b >= dmesh.n
+            bm_np = None if on_mesh else _packed_bm(matrix, w, b * chunk)
+            if bm_np is not None:
+                data, coding = self._packed_stripes(dp, bm_np, stripes)
+            else:
                 bm = matrix_to_device_bitmatrix(matrix, w)
                 dp.add_upload(stripes.nbytes)
-                # upload/compute/sync all live inside the sharded
-                # helper; attribute its wall to compute
-                with dp.stage("compute"):
-                    out = mesh.sharded_matrix_stripes(
-                        bm, stripes, w, dmesh
-                    )
-                kt.bytes_out = out.nbytes
-                return _shard_rows(stripes), _shard_rows(out)
-            if w == 8 and _on_tpu() and (b * chunk) % 4 == 0:
-                bm_np, ok = _host_bm(matrix, w)
-                if ok:
-                    data, coding = self._packed_stripes(
-                        dp, bm_np, stripes
-                    )
-                    kt.bytes_out = sum(c.nbytes for c in coding)
-                    return data, coding
-            bm = matrix_to_device_bitmatrix(matrix, w)
-            with dp.stage("upload"):
-                dev = jnp.asarray(stripes)
-            dp.add_upload(stripes.nbytes)
-            with dp.stage("compute"):
-                odev = self._bitplane_dispatch(bm, dev, w)
-            with dp.stage("sync"):
-                out = np.asarray(odev)[:b]
-            kt.bytes_out = out.nbytes
-            return _shard_rows(stripes), _shard_rows(out)
+                if on_mesh:
+                    # upload/compute/sync all live inside the sharded
+                    # helper; attribute its wall to compute
+                    with dp.stage("compute"):
+                        out = mesh.sharded_matrix_stripes(
+                            bm, stripes, w, dmesh
+                        )
+                else:
+                    with dp.stage("upload"):
+                        dev = jnp.asarray(stripes)
+                    with dp.stage("compute"):
+                        odev = self._bitplane_dispatch(bm, dev, w)
+                    with dp.stage("sync"):
+                        out = np.asarray(odev)[:b]
+                data, coding = _shard_rows(stripes), _shard_rows(out)
+            dp.set_bytes_out(sum(c.nbytes for c in coding))
+            return data, coding
 
     @staticmethod
     def _packed_stripes(dp, bm_np: np.ndarray, stripes: np.ndarray):
@@ -273,14 +265,9 @@ class JaxBackend:
         rows = [_row_u8(s).view(np.uint32) for s in shards]
         n4 = len(rows[0])
         total = 4 * n4 * len(rows)
-        with kernel_stats().timed(
-            "gf_matmul", bytes_in=total
-        ) as kt, dispatch_profiler().dispatch(
-            "ec_decode", backend=self.name
+        with self._dispatch(
+            "ec_decode", ops=1, stripes=stripes, bytes_in=total
         ) as dp:
-            dp.set_ops(1)
-            dp.set_stripes(stripes)
-            dp.add_bytes_in(total)
             bm = matrix_to_device_bitmatrix(matrix, w)
             dp.add_upload(total)
             with dp.stage("upload"):
@@ -294,15 +281,8 @@ class JaxBackend:
                 odev = gf_matrix_words(bm, tuple(dev), w=w)
             with dp.stage("sync"):
                 out = np.asarray(odev)
-            kt.bytes_out = 4 * n4 * len(matrix)
+            dp.set_bytes_out(4 * n4 * len(matrix))
         return [out[j, :n4].view(np.uint8) for j in range(out.shape[0])]
-
-    @staticmethod
-    def _bitplane_call(bm, stripes: np.ndarray, w: int):
-        """Upload + dispatch the generic bitplane encode.  Returns
-        the UNSLICED device array — callers slice [:b] after their
-        sync, so pipelined callers keep results on device."""
-        return JaxBackend._bitplane_dispatch(bm, jnp.asarray(stripes), w)
 
     def matrix_stripes_batch(
         self,
@@ -312,88 +292,92 @@ class JaxBackend:
         group_stripes: int = 256,
     ) -> list[np.ndarray]:
         """Coalesced encode of MANY stripe batches (one per queued
-        object) with async double-buffered transfers: batches pack
-        greedily into ~``group_stripes``-stripe groups, group j+1's
-        ``jax.device_put`` is issued while group j's encode computes
-        (both are async dispatches), and the ONLY sync is the final
-        materialization — the commit point.  Per-group batch shapes
-        bucket to powers of two so ragged coalesced batches replay
-        compiled programs.  Byte-identical to per-batch
-        ``matrix_stripes`` (same per-stripe math; padding is sliced
-        away).  Returns one (Bi, m, chunk) array per input batch."""
-        import jax
-
+        object) through :meth:`_coalesced`, fetched at its one sync —
+        the commit point.  Byte-identical to a per-batch
+        :meth:`matrix_stripe_shards` (same per-stripe math; padding is
+        sliced away).  Returns one (Bi, m, chunk) host array per input
+        batch."""
         batches = [
             np.ascontiguousarray(s, dtype=np.uint8)
             for s in stripe_batches
         ]
         if not batches:
             return []
-        shapes = {s.shape[1:] for s in batches}
-        if len(shapes) != 1:
-            # heterogeneous geometry (should not happen for one
-            # profile): encode per batch, still correct
-            return [self.matrix_stripes(matrix, s, w) for s in batches]
-        total = sum(s.nbytes for s in batches)
-        with kernel_stats().timed(
-            "gf_matmul", bytes_in=total
-        ) as kt, dispatch_profiler().dispatch(
-            "ec_encode", backend=self.name
+        with self._dispatch(
+            "ec_encode",
+            ops=len(batches),
+            stripes=sum(s.shape[0] for s in batches),
+            bytes_in=sum(s.nbytes for s in batches),
         ) as dp:
-            dp.set_ops(len(batches))
-            dp.set_stripes(sum(s.shape[0] for s in batches))
-            dp.add_bytes_in(total)
             bm = matrix_to_device_bitmatrix(matrix, w)
-            groups: list[list[np.ndarray]] = []
-            cur: list[np.ndarray] = []
-            cur_b = 0
-            for s in batches:
-                if cur and cur_b + s.shape[0] > group_stripes:
-                    groups.append(cur)
-                    cur, cur_b = [], 0
-                cur.append(s)
-                cur_b += s.shape[0]
-            if cur:
+            outs = self._coalesced(
+                dp, bm, batches, w, group_stripes, fetch=True
+            )
+            dp.set_bytes_out(sum(o.nbytes for o in outs))
+        return outs
+
+    def _coalesced(
+        self, dp, bm, arrays, w: int, group_stripes: int, fetch: bool
+    ) -> list:
+        """THE coalesced pipeline, the write path's and the repair
+        path's alike: ``arrays`` — one (Bi, k, chunk) uint8 host array
+        an object, in order — pack greedily into ~``group_stripes``-
+        stripe groups, group j+1's ``jax.device_put`` is issued while
+        group j computes (both are async dispatches), and each group's
+        batch axis buckets to a power of two so ragged coalesced
+        batches replay compiled programs (:meth:`_bitplane_dispatch`).
+        Returns each object's (Bi, r, chunk) slice of its group's
+        output, in order: with ``fetch`` the group outputs come to the
+        host first, under the one ``sync`` bracket (the commit: every
+        dispatched transfer and encode drains together), and the
+        slices are host views; without it nothing waits and the slices
+        stay DEVICE arrays for the caller to sync."""
+        import jax
+
+        if len({a.shape[1:] for a in arrays}) > 1:
+            raise ErasureCodeError(
+                "the objects of one coalesced dispatch must share "
+                "their stripe geometry (rows, chunk)"
+            )
+        groups: list[list[np.ndarray]] = []
+        cur: list[np.ndarray] = []
+        cur_b = 0
+        for a in arrays:
+            if cur and cur_b + a.shape[0] > group_stripes:
                 groups.append(cur)
+                cur, cur_b = [], 0
+            cur.append(a)
+            cur_b += a.shape[0]
+        if cur:
+            groups.append(cur)
 
-            def upload(group):
-                arr = (
-                    np.concatenate(group)
-                    if len(group) > 1
-                    else group[0]
-                )
-                # device_put is async: the transfer overlaps whatever
-                # compute is already dispatched
-                with dp.stage("upload"):
-                    dev = jax.device_put(arr)
-                dp.add_upload(arr.nbytes)
-                return dev, arr.shape[0]
+        def upload(group):
+            arr = np.concatenate(group) if len(group) > 1 else group[0]
+            # device_put is async: the transfer overlaps whatever
+            # compute is already dispatched
+            with dp.stage("upload"):
+                dev = jax.device_put(arr)
+            dp.add_upload(arr.nbytes)
+            return dev
 
-            dev, nb = upload(groups[0])
-            pending: list[tuple] = []
-            for j in range(len(groups)):
-                with dp.stage("compute"):
-                    out = self._bitplane_dispatch(bm, dev, w)
-                pending.append((out, nb))
-                if j + 1 < len(groups):
-                    # next group's transfer overlaps this group's
-                    # compute — the double buffer
-                    dev, nb = upload(groups[j + 1])
-            # sync ONLY here (the commit): every dispatched transfer
-            # and encode drains together
+        mats = []
+        dev = upload(groups[0]) if groups else None
+        for j in range(len(groups)):
+            with dp.stage("compute"):
+                mats.append(self._bitplane_dispatch(bm, dev, w))
+            if j + 1 < len(groups):
+                # next group's transfer overlaps this group's
+                # compute — the double buffer
+                dev = upload(groups[j + 1])
+        if fetch:
             with dp.stage("sync"):
-                mats = [np.asarray(o)[:b] for o, b in pending]
-            kt.bytes_out = sum(m.nbytes for m in mats)
-        outs: list[np.ndarray] = []
-        gi = 0
-        off = 0
-        for s in batches:
-            nb = s.shape[0]
-            if off + nb > mats[gi].shape[0]:
-                gi += 1
-                off = 0
-            outs.append(mats[gi][off : off + nb])
-            off += nb
+                mats = [np.asarray(o) for o in mats]
+        outs = []
+        for group, mat in zip(groups, mats):
+            off = 0
+            for a in group:
+                outs.append(mat[off : off + a.shape[0]])
+                off += a.shape[0]
         return outs
 
     def decode_stripes_batch(
@@ -409,29 +393,24 @@ class JaxBackend:
         object of equal-length 1-D survivor shard payloads — numpy
         arrays or resident DeviceBuf tokens.  Resident survivors ride
         the dispatch with ZERO re-upload (their link cost was paid at
-        registration); host-only objects pack into
-        ~``group_stripes``-stripe groups whose uploads double-buffer
-        against compute, exactly like the write path.  The ONLY sync
-        is the final block_until_ready, and the outputs stay DEVICE
-        arrays — reconstructed shards leave device-born (the caller
-        wraps them in DeviceBufs; host bytes are fetched at most once
-        by whoever pushes/writes them)."""
+        registration); host-only objects go through
+        :meth:`_coalesced`, exactly like the write path.  The ONLY
+        sync is the final block_until_ready, and the outputs stay
+        DEVICE arrays — reconstructed shards leave device-born (the
+        caller wraps them in DeviceBufs; host bytes are fetched at
+        most once by whoever pushes/writes them)."""
         import jax
 
         from .residency import is_device_buf
 
-        total = sum(len(r) for rows in row_sets for r in rows)
-        with kernel_stats().timed(
-            "gf_matmul", bytes_in=total
-        ) as kt, dispatch_profiler().dispatch(
-            "ec_decode", backend=self.name
+        with self._dispatch(
+            "ec_decode",
+            ops=len(row_sets),
+            bytes_in=sum(len(r) for rows in row_sets for r in rows),
         ) as dp:
-            dp.set_ops(len(row_sets))
-            dp.add_bytes_in(total)
             bm = matrix_to_device_bitmatrix(matrix, w)
             outs: list = [None] * len(row_sets)
             host_idx: list[int] = []
-            pending: dict[int, tuple] = {}
             for i, rows in enumerate(row_sets):
                 if any(is_device_buf(r) for r in rows):
                     # already-resident survivors ride with zero link
@@ -484,76 +463,30 @@ class JaxBackend:
                                 hi += 1
                         dev = jnp.stack(devs, axis=1)
                     with dp.stage("compute"):
-                        pending[i] = (
-                            self._bitplane_dispatch(bm, dev, w),
-                            dev.shape[0],
-                        )
+                        out = self._bitplane_dispatch(bm, dev, w)
+                    outs[i] = out[: dev.shape[0]]
                 else:
                     host_idx.append(i)
-            arrays = {
-                i: np.stack(
-                    [
-                        _row_u8(r).reshape(-1, chunk)
-                        for r in row_sets[i]
-                    ],
+            arrays = [
+                np.stack(
+                    [_row_u8(r).reshape(-1, chunk) for r in row_sets[i]],
                     axis=1,
                 )
                 for i in host_idx
-            }
-            groups: list[list[int]] = []
-            cur: list[int] = []
-            cur_b = 0
-            for i in host_idx:
-                b = arrays[i].shape[0]
-                if cur and cur_b + b > group_stripes:
-                    groups.append(cur)
-                    cur, cur_b = [], 0
-                cur.append(i)
-                cur_b += b
-            if cur:
-                groups.append(cur)
-
-            def upload(group):
-                arr = (
-                    np.concatenate([arrays[i] for i in group])
-                    if len(group) > 1
-                    else arrays[group[0]]
-                )
-                # async transfer: overlaps the already-dispatched
-                # decode of the previous group — the double buffer
-                with dp.stage("upload"):
-                    dev = jax.device_put(arr)
-                dp.add_upload(arr.nbytes)
-                return dev
-
-            gouts = []
-            if groups:
-                dev = upload(groups[0])
-                for j in range(len(groups)):
-                    with dp.stage("compute"):
-                        gouts.append(
-                            self._bitplane_dispatch(bm, dev, w)
-                        )
-                    if j + 1 < len(groups):
-                        dev = upload(groups[j + 1])
-            for j, group in enumerate(groups):
-                mat = gouts[j]
-                off = 0
-                for i in group:
-                    b = arrays[i].shape[0]
-                    outs[i] = mat[off : off + b]
-                    off += b
-            for i, (mat, b) in pending.items():
-                outs[i] = mat[:b]
-            dp.set_stripes(
-                sum(b for _, b in pending.values())
-                + sum(arrays[i].shape[0] for i in host_idx)
-            )
+            ]
+            for i, out in zip(
+                host_idx,
+                self._coalesced(
+                    dp, bm, arrays, w, group_stripes, fetch=False
+                ),
+            ):
+                outs[i] = out
+            dp.set_stripes(sum(o.shape[0] for o in outs))
             # sync ONLY here (the commit point); results STAY on
             # device for device-born registration downstream
             with dp.stage("sync"):
                 outs = [jax.block_until_ready(o) for o in outs]
-            kt.bytes_out = sum(int(np.prod(o.shape)) for o in outs)
+            dp.set_bytes_out(sum(int(np.prod(o.shape)) for o in outs))
         return outs
 
     @staticmethod

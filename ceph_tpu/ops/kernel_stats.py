@@ -27,8 +27,11 @@ reader (``SELF_TIME_STAGES``):
     l_stage_<name>_self_ns   u64   durations less same-thread children
 
 Groups registered by the instrumented modules: ``ec_encode`` /
-``ec_decode`` (ec/stripe.py batched seam), ``gf_matmul`` /
-``gf_bitmatrix`` (ops/ec_backend.py device dispatch), ``crush``
+``ec_decode`` (ec/stripe.py batched seam: one ``timed`` a seam
+function), ``gf_matmul`` (ops/ec_backend.py: a batched dispatch feeds
+it from its flight-recorder entry's commit — ops/profiler.py
+``dispatch(group=)`` — and the per-call region math through
+``timed``), ``gf_bitmatrix`` (the same region math), ``crush``
 (osd/mapping.py batched PG mapping, where bytes_in counts PGs mapped
 via the extra ``l_tpu_crush_pgs`` counter).
 
@@ -228,9 +231,10 @@ class KernelStats:
         return self.perf.dump()
 
     def snapshot(self) -> dict:
-        """Compact rollup for result artifacts (bench.py embeds this
-        in the BENCH JSON line): compile-cache hit ratio plus per-group
-        call/byte totals — kernel behavior, not just GB/s."""
+        """Compact rollup for result artifacts (JSON-embeddable as it
+        is): compile-cache hit ratio plus per-group call/byte totals —
+        kernel behavior, not just GB/s.  No program reads it today
+        (ROADMAP D4)."""
         dump = self.dump()
         hits = int(dump.get("l_tpu_compile_cache_hit", 0))
         misses = int(dump.get("l_tpu_compile_cache_miss", 0))

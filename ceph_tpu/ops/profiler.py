@@ -31,7 +31,8 @@ and the compile-cache events the dispatch produced.  Records land in
 a bounded drop-oldest ring (``CEPH_TPU_DISPATCH_RING`` entries,
 default 1024) served raw over ``ceph tell osd.N dispatch history``
 and the admin socket, plus unbounded per-kind totals behind
-``summary()`` and the bench breakdown.
+``summary()`` and ``totals()`` (the benchmark's harness diffs them
+over its window: ``dispatch.<kind>.<field>``).
 
 Three surfaces ride one instrumentation:
 
@@ -41,10 +42,13 @@ Three surfaces ride one instrumentation:
   device time went;
 - telemetry — ``l_tpu_dispatch_*`` counters + LogHistogram variants
   on the process-global kernel set, flowing perf dump → MMgrReport →
-  /metrics with no new plumbing;
-- bench — :func:`breakdown` diffs two ``totals()`` snapshots into the
-  artifact keys (``transfer_ms``/``compute_ms``/``sync_ms``/
-  ``occupancy``/``pad_waste_ratio``/``resident_byte_ratio``).
+  /metrics with no new plumbing; a dispatch opened with a ``group``
+  feeds that group's ``l_tpu_<group>_*`` kernel counters from the same
+  commit, so an EC dispatch site has this one instrument and no
+  stopwatch of its own;
+- rollups — :func:`breakdown` diffs two ``totals()`` snapshots into
+  ``transfer_ms``/``compute_ms``/``sync_ms``/``occupancy``/
+  ``pad_waste_ratio``/``resident_byte_ratio``.
 """
 
 from __future__ import annotations
@@ -169,22 +173,30 @@ class _Stage:
 class _Dispatch:
     """One device dispatch in flight; commits a ring entry on clean
     exit (an exception means the dispatch fell back — the fallback
-    path records its own host entry instead)."""
+    path records its own host entry instead).  With a ``group`` the
+    same commit feeds that group's ``l_tpu_<group>_*`` kernel counters
+    from the entry's own wall and byte totals: one clock, and every
+    byte count given once."""
 
     __slots__ = (
-        "_prof", "kind", "backend", "ops", "stripes", "bytes_in",
-        "bytes_uploaded", "bytes_resident", "bytes_padded",
-        "compile_hits", "compile_misses", "transfer_s", "compute_s",
-        "sync_s", "wall_s", "_t0",
+        "_prof", "kind", "backend", "group", "ops", "stripes",
+        "bytes_in", "bytes_out", "bytes_uploaded", "bytes_resident",
+        "bytes_padded", "compile_hits", "compile_misses", "transfer_s",
+        "compute_s", "sync_s", "wall_s", "_t0",
     )
 
-    def __init__(self, prof: "DispatchProfiler", kind: str, backend: str):
+    def __init__(
+        self, prof: "DispatchProfiler", kind: str, backend: str,
+        group: str | None, ops: int, stripes: int, bytes_in: int,
+    ):
         self._prof = prof
         self.kind = kind
         self.backend = backend
-        self.ops = 0
-        self.stripes = 0
-        self.bytes_in = 0
+        self.group = group
+        self.ops = int(ops)
+        self.stripes = int(stripes)
+        self.bytes_in = int(bytes_in)
+        self.bytes_out = 0
         self.bytes_uploaded = 0
         self.bytes_resident = 0
         self.bytes_padded = 0
@@ -204,6 +216,11 @@ class _Dispatch:
 
     def add_bytes_in(self, nbytes: int) -> None:
         self.bytes_in += int(nbytes)
+
+    def set_bytes_out(self, nbytes: int) -> None:
+        """What the kernel produced: the group counters' ``bytes_out``
+        (the ring entry and the per-kind totals do not carry it)."""
+        self.bytes_out = int(nbytes)
 
     def add_upload(self, nbytes: int) -> None:
         self.bytes_uploaded += int(nbytes)
@@ -268,11 +285,27 @@ class DispatchProfiler:
         self._ks = ks or kernel_stats()
         ensure_dispatch_counters(self._ks)
 
-    def dispatch(self, kind: str, backend: str = "jax") -> _Dispatch:
+    def dispatch(
+        self,
+        kind: str,
+        backend: str = "jax",
+        group: str | None = None,
+        ops: int = 0,
+        stripes: int = 0,
+        bytes_in: int = 0,
+    ) -> _Dispatch:
         """Context manager recording one device dispatch of ``kind``
         (``ec_encode``/``ec_decode``/``crc32c``/``compare``/
-        ``crush``)."""
-        return _Dispatch(self, kind, backend)
+        ``crush``): THE instrument of a dispatch site.  ``ops``,
+        ``stripes`` and ``bytes_in`` may be given here or through the
+        record's setters once they are known.  ``group`` names the
+        kernel-counter group (``gf_matmul``) the committed entry also
+        counts into: ``l_tpu_<group>_{calls,bytes_in,bytes_out,lat}``
+        come from the entry's ``bytes_in``, ``set_bytes_out`` and
+        ``wall_s``, so a site opens no stopwatch of its own."""
+        return _Dispatch(
+            self, kind, backend, group, ops, stripes, bytes_in
+        )
 
     # -- commit ------------------------------------------------------------
     def _commit(self, d: _Dispatch) -> None:
@@ -337,6 +370,13 @@ class DispatchProfiler:
         ):
             perf.tinc(f"l_tpu_dispatch_{stage}_lat", secs)
             perf.hinc(f"l_tpu_dispatch_{stage}_lat_hist", secs)
+        if d.group:
+            self._ks.record(
+                d.group,
+                bytes_in=d.bytes_in,
+                bytes_out=d.bytes_out,
+                seconds=d.wall_s,
+            )
 
     # -- consumers ---------------------------------------------------------
     def history(self, kind: str | None = None, limit: int = 0) -> dict:
@@ -359,7 +399,7 @@ class DispatchProfiler:
 
     def totals(self) -> dict:
         """Cumulative per-kind raw sums since process start (survives
-        ring wrap — the bench diffs two of these)."""
+        ring wrap — the benchmark's harness diffs two of these)."""
         with self._lock:
             return {k: dict(v) for k, v in self._totals.items()}
 
@@ -383,8 +423,8 @@ class DispatchProfiler:
         }
 
     def clear(self) -> None:
-        """Drop the ring and totals (tests/bench isolation; the
-        perf counters are monotonic and stay)."""
+        """Drop the ring and totals (test isolation; the perf
+        counters are monotonic and stay)."""
         with self._lock:
             self._ring.clear()
             self._totals.clear()
@@ -392,7 +432,8 @@ class DispatchProfiler:
 
 
 def _derive(t: dict) -> dict:
-    """Raw per-kind sums → the human/bench rollup shape."""
+    """Raw per-kind sums → the rollup shape ``dispatch summary``
+    serves."""
     nd = max(t.get("dispatches", 0), 1)
     bytes_in = t.get("bytes_in", 0)
     padded = t.get("bytes_padded", 0)
@@ -428,8 +469,8 @@ def _derive(t: dict) -> dict:
 def breakdown(
     before: dict, after: dict, backend: str = "jax"
 ) -> dict:
-    """Diff two :meth:`DispatchProfiler.totals` snapshots into the
-    bench artifact's dispatch-breakdown keys.  ALWAYS carries the six
+    """Diff two :meth:`DispatchProfiler.totals` snapshots into one
+    window's rollup.  ALWAYS carries the six
     contract keys (``transfer_ms``/``compute_ms``/``sync_ms``/
     ``occupancy``/``pad_waste_ratio``/``resident_byte_ratio``) plus
     the ``backend`` marker — on a JAX_PLATFORMS=cpu run the values are

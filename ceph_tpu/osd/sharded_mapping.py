@@ -84,8 +84,8 @@ def mesh_batch_do_rule(cm, ruleno, xs, result_max, weights=None):
 
 
 class ShardedPGMapper:
-    """Thin OO wrapper over one (map, mesh) pair — the shape bench.py
-    and the dryrun drive: compile once, map many PG ranges."""
+    """Thin OO wrapper over one (map, mesh) pair — the shape the
+    dryrun drives: compile once, map many PG ranges."""
 
     def __init__(self, crush_map, dmesh: meshmod.DeviceMesh):
         self.cm = jaxmap.compile_map(crush_map)

@@ -24,8 +24,7 @@ An OPEN-LOOP workload generator over a live in-process cluster:
 
 Per-op latency (arrival → completion, queue wait included) lands in
 ``common/histogram.py`` LogHistograms; scenarios report per-class
-p50/p99 curves plus a reservation-floor verdict.  ``bench.py --slo``
-runs ``run_suite`` and emits the JSON artifact;
+p50/p99 curves plus a reservation-floor verdict.
 ``python tests/simulator.py [scenario ...]`` runs standalone;
 tests/test_slo.py drives the fast variants in tier-1.
 """
@@ -616,32 +615,6 @@ def _cluster_reservation(cluster: SimCluster, klass: str) -> float:
     per-OSD figure)."""
     triple = cluster.qos_profiles.get(klass)
     return float(triple[0]) if triple else 0.0
-
-
-def run_suite(
-    fast: bool = False, seed: int = DEFAULT_SEED
-) -> dict:
-    """The bench.py --slo payload: baseline + fault weather + the
-    overload floor, scaled down when ``fast``."""
-    dur = 4.0 if fast else 8.0
-    rate = 40.0 if fast else 80.0
-    conditions = [
-        scenario_baseline(duration=dur, rate=rate, seed=seed),
-        scenario_weather(
-            "lossy", duration=dur, rate=rate, seed=seed
-        ),
-    ]
-    floor = scenario_overload_floor(
-        duration=dur,
-        gold_rate=30.0 if fast else 40.0,
-        bulk_rate=400.0 if fast else 700.0,
-        seed=seed,
-    )
-    conditions.append(floor)
-    return {
-        "conditions": conditions,
-        "reservation_floor": floor["reservation_floor"],
-    }
 
 
 SCENARIOS = {

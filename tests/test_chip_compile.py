@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 # x64 on, as in every product process: OSDs, the in-process cluster and
-# bench.py all import the CRUSH kernel next to the EC kernels
+# the benchmark's drivers all import the CRUSH kernel next to the EC kernels
 import ceph_tpu.crush.jaxmap as jaxmap
 
 import jax
@@ -86,7 +86,7 @@ def test_x64_is_on_as_in_the_product():
     "plugin,k,m", [("jerasure", 8, 3), ("jerasure", 4, 2), ("isa", 4, 2)]
 )
 def test_packed_encode_kernel(one_chip, plugin, k, m):
-    """matrix_stripes' folded word form at ec_benchmark's 64 x 1 MiB."""
+    """matrix_stripe_shards' folded word form at ec_benchmark's 64 x 1 MiB."""
     ec = _code(plugin, k, m)
     bm = gf.jerasure_bitmatrix(np.asarray(ec.matrix, dtype=np.int64), 8)
     nwords = 64 * (1 << 20) // k // 4

@@ -8,7 +8,9 @@ tracing module with residency hits visibly cutting upload bytes."""
 from __future__ import annotations
 
 import json
+import os
 import pathlib
+import subprocess
 import sys
 import time
 
@@ -198,13 +200,13 @@ def test_ec_batch_axis_pad_counted():
     stripes = rng.integers(0, 256, size=(3, k, chunk), dtype=np.uint8)
     before = _pad_wasted()
     seq = _last_seq()
-    get_backend("jax").matrix_stripes(matrix, stripes, w)
+    get_backend("jax").matrix_stripe_shards(matrix, stripes, w)
     assert _pad_wasted() - before == (4 - 3) * k * chunk
     ents = _entries_after(seq, kind="ec_encode")
     assert ents and ents[-1]["bytes_padded"] == (4 - 3) * k * chunk
     # a pow2 batch pads nothing
     before = _pad_wasted()
-    get_backend("jax").matrix_stripes(
+    get_backend("jax").matrix_stripe_shards(
         matrix,
         rng.integers(0, 256, size=(4, k, chunk), dtype=np.uint8),
         w,
@@ -284,6 +286,240 @@ def test_numpy_backend_records_host_entries():
     ents = _entries_after(seq, kind="ec_decode")
     assert ents and ents[-1]["backend"] == "numpy"
     assert ents[-1]["ops"] == 2
+
+
+def _batched_call(entry: str, backend):
+    """One call of a batched seam entry at a small k=4 m=2 geometry:
+    (kind, ops, stripes, bytes in, bytes out) as the recorder and the
+    kernel counters should report it."""
+    k, m, w, chunk = 4, 2, 8, 64
+    matrix = gf.reed_sol_vandermonde_coding_matrix(k, m, w)
+    sizes = (3, 2)
+    objects = [
+        rng.integers(0, 256, size=(b, k, chunk), dtype=np.uint8)
+        for b in sizes
+    ]
+    # an object's shards as stored: chunk i of every stripe, concatenated
+    shards = [
+        [np.ascontiguousarray(o[:, i, :]).reshape(-1) for i in range(k)]
+        for o in objects
+    ]
+    if entry == "matrix_stripe_shards":
+        _data, coding = backend.matrix_stripe_shards(matrix, objects[0], w)
+        assert [len(c) for c in coding] == [3 * chunk] * m
+        return "ec_encode", 1, 3, 3 * k * chunk, 3 * m * chunk
+    if entry == "matrix_shards":
+        rebuilt = backend.matrix_shards(matrix, shards[0], w, 3)
+        assert [len(r) for r in rebuilt] == [3 * chunk] * m
+        return "ec_decode", 1, 3, 3 * k * chunk, 3 * m * chunk
+    if entry == "matrix_stripes_batch":
+        outs = backend.matrix_stripes_batch(matrix, objects, w)
+        kind = "ec_encode"
+    else:
+        outs = backend.decode_stripes_batch(matrix, shards, w, chunk)
+        kind = "ec_decode"
+    assert [o.shape for o in outs] == [(b, m, chunk) for b in sizes]
+    return kind, 2, 5, 5 * k * chunk, 5 * m * chunk
+
+
+@pytest.mark.parametrize("backend", ["jax", "numpy"])
+@pytest.mark.parametrize(
+    "entry",
+    [
+        "matrix_stripe_shards", "matrix_shards",
+        "matrix_stripes_batch", "decode_stripes_batch",
+    ],
+)
+def test_a_batched_entry_has_one_instrument(entry, backend):
+    """One call of a batched seam entry leaves exactly ONE recorder
+    entry, ``kind:backend``, with the call's ops, stripes and input
+    bytes; on the device backend the same commit is the one
+    ``l_tpu_gf_matmul_*`` call, with the entry's own byte count (the
+    oracle never counted there)."""
+    ks = kernel_stats()
+    before = ks.dump()
+    totals = dispatch_profiler().totals()
+    seq = _last_seq()
+    kind, ops, stripes, bytes_in, bytes_out = _batched_call(
+        entry, get_backend(backend)
+    )
+    (rec,) = _entries_after(seq)
+    assert (rec["kind"], rec["backend"]) == (kind, backend)
+    assert (rec["ops"], rec["stripes"], rec["bytes_in"]) == (
+        ops, stripes, bytes_in,
+    )
+    was = totals.get(kind, {})
+    now = dispatch_profiler().totals()[kind]
+    moved = {
+        f: now[f] - was.get(f, 0)
+        for f in ("dispatches", "ops", "stripes", "bytes_in")
+    }
+    assert moved == {
+        "dispatches": 1, "ops": ops, "stripes": stripes,
+        "bytes_in": bytes_in,
+    }
+    after = ks.dump()
+    group = {
+        f: after.get(f"l_tpu_gf_matmul_{f}", 0)
+        - before.get(f"l_tpu_gf_matmul_{f}", 0)
+        for f in ("calls", "bytes_in", "bytes_out")
+    }
+    lat = (
+        after.get("l_tpu_gf_matmul_lat", {}).get("avgcount", 0)
+        - before.get("l_tpu_gf_matmul_lat", {}).get("avgcount", 0)
+    )
+    if backend == "jax":
+        assert group == {
+            "calls": 1, "bytes_in": rec["bytes_in"], "bytes_out": bytes_out,
+        }
+        assert lat == 1
+        assert rec["bytes_uploaded"] == rec["bytes_in"]
+    else:
+        assert group == {"calls": 0, "bytes_in": 0, "bytes_out": 0}
+        assert lat == 0 and rec["compute_s"] == rec["wall_s"]
+
+
+# the benchmark's harness reads this code's counters by name
+# (benchmark/harness.flat_counters: every layer metric diffs them over
+# the window).  The script below runs, in a process of its own, one
+# stripe.encode, one stripe.decode, one encode_batch and one
+# decode_batch on the jax backend -- once through the bitplane program
+# and once through the chip's packed kernel, interpreted -- and prints
+# the counters' names, the ones that count (not the clocks), and the
+# programs JAX compiles when the same calls come again.
+_SEAM_CALLS = """
+import json
+import numpy as np
+import ceph_tpu.ops
+from benchmark import harness
+from ceph_tpu.ec import ErasureCodeProfile, registry_instance, stripe
+from ceph_tpu.ops import ec_backend, packed_gf
+
+clock = harness.CompileClock()
+recorder = harness.Dispatches()
+ec = registry_instance().factory("jerasure", ErasureCodeProfile(
+    technique="reed_sol_van", k="4", m="2", w="8", backend="jax"))
+sinfo = stripe.StripeInfo(4, 4 * 512)
+rng = np.random.default_rng(0)
+a, b = (rng.integers(0, 256, n * 4 * 512, dtype=np.uint8) for n in (4, 3))
+
+
+class Driver:
+    def counters(self):
+        return {}
+
+
+def four_calls():
+    shards = stripe.encode(sinfo, ec, a)
+    have = {p: s for p, s in shards.items() if p not in (1, 4)}
+    stripe.decode(sinfo, ec, have, (1, 4))
+    sets = stripe.encode_batch(sinfo, ec, [a, b])
+    stripe.decode_batch(
+        sinfo, ec,
+        [{p: s for p, s in one.items() if p not in (1, 4)} for one in sets],
+        (1, 4))
+
+
+def both_encode_kernels():
+    four_calls()
+    on_tpu, built = ec_backend._on_tpu, packed_gf.prebuilt_word_call
+    ec_backend._on_tpu = lambda: True
+    packed_gf.prebuilt_word_call = lambda bm, w=8: built(bm, w, interpret=True)
+    try:
+        four_calls()
+    finally:
+        ec_backend._on_tpu, packed_gf.prebuilt_word_call = on_tpu, built
+
+
+both_encode_kernels()
+counters = harness.flat_counters(Driver())
+programs = clock.programs
+both_encode_kernels()
+print(json.dumps({
+    "counts": {k: v for k, v in counters.items() if not k.endswith(("_s", "_ns"))},
+    "clocks": sorted(k for k in counters if k.endswith(("_s", "_ns"))),
+    "programs": programs, "compiled_again": clock.programs - programs,
+    "recorded": recorder.harvest(), "on_host": recorder.host_backend_entries(),
+}))
+"""
+
+# taken from the parent of PR 32 (the tree before the one instrument):
+# the names are the harness's contract, the numbers what the same
+# eight calls moved there
+_SEAM_COUNTS = {
+    **{
+        f"dispatch.{kind}.{field}": value
+        for kind in ("ec_encode", "ec_decode")
+        for field, value in {
+            "dispatches": 4, "ops": 6, "stripes": 22, "bytes_in": 45056,
+            "bytes_uploaded": 45056, "bytes_resident": 0,
+            "bytes_padded": 4096,
+        }.items()
+    },
+    "dispatch.ec_encode.compile_hits": 1,
+    "dispatch.ec_encode.compile_misses": 2,
+    "dispatch.ec_decode.compile_hits": 3,
+    "dispatch.ec_decode.compile_misses": 1,
+    "l_stage_ec_assemble_count": 2,
+    "l_stage_ec_fold_count": 4,
+    "l_stage_ec_plan_count": 2,
+    "l_stage_ec_unfold_count": 1,
+    "l_tpu_batch_decode_dispatches": 2,
+    "l_tpu_batch_decode_ops_per_dispatch": 4,
+    "l_tpu_batch_encode_dispatches": 2,
+    "l_tpu_batch_encode_ops_per_dispatch": 4,
+    "l_tpu_compile_cache_hit": 9,
+    "l_tpu_compile_cache_miss": 6,
+    "l_tpu_dispatch_bytes_resident": 0,
+    "l_tpu_dispatch_bytes_uploaded": 90112,
+    "l_tpu_dispatch_count": 8,
+    "l_tpu_dispatch_ops": 12,
+    "l_tpu_dispatch_ring_dropped": 0,
+    "l_tpu_dispatch_stripes": 44,
+    "l_tpu_ec_decode_bytes_in": 45056,
+    "l_tpu_ec_decode_bytes_out": 22528,
+    "l_tpu_ec_decode_calls": 4,
+    "l_tpu_ec_encode_bytes_in": 45056,
+    "l_tpu_ec_encode_bytes_out": 67584,
+    "l_tpu_ec_encode_calls": 4,
+    "l_tpu_gf_matmul_bytes_in": 90112,
+    "l_tpu_gf_matmul_bytes_out": 45056,
+    "l_tpu_gf_matmul_calls": 8,
+    "l_tpu_pad_bytes_wasted": 8192,
+    "l_tpu_residency_bytes_resident": 0,
+    "l_tpu_residency_evictions": 0,
+    "l_tpu_residency_hits": 0,
+    "l_tpu_residency_misses": 0,
+}
+_SEAM_CLOCKS = sorted(
+    [f"dispatch.{kind}.{stage}_s"
+     for kind in ("ec_encode", "ec_decode")
+     for stage in ("compute", "sync", "transfer", "wall")]
+    + [f"l_stage_{span}_ns"
+       for span in ("ec_assemble", "ec_fold", "ec_plan", "ec_unfold")]
+)
+
+
+def test_the_harness_reads_the_counters_it_read_before():
+    """``benchmark.harness.flat_counters`` after the four seam calls:
+    no key gone, none new, every count what the parent counted; the
+    recorder's entries one a call, ``<kind>:jax``, in sequence; and the same calls again
+    compile nothing (``CompileClock.programs`` stands)."""
+    repo = pathlib.Path(__file__).resolve().parent.parent
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=str(repo))
+    proc = subprocess.run(
+        [sys.executable, "-c", _SEAM_CALLS], cwd=repo, env=env,
+        capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    said = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert said["counts"] == _SEAM_COUNTS
+    assert said["clocks"] == _SEAM_CLOCKS
+    # 8 calls a kind over the two rounds, one entry each, none lost
+    assert said["recorded"] == {"ec_encode:jax": 8, "ec_decode:jax": 8}
+    assert said["on_host"] == {}
+    assert said["programs"] > 0 and said["compiled_again"] == 0
 
 
 # -- CLI grammar ------------------------------------------------------------

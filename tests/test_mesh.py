@@ -4,22 +4,13 @@ The contract under test: sharding a batch across the mesh NEVER
 changes a byte — sharded CRUSH mapping and EC encode are identical to
 the single-device paths, including ragged batch sizes that don't
 divide the device count — plus per-device telemetry, product routing
-(ec_backend / osd mapping go through the mesh when >1 device exists),
-the measured scaling curve (bench.measure_mesh), and the bench's
-strictness (a CPU run is labelled as one, a failed section fails the
-run).
+(ec_backend / osd mapping go through the mesh when >1 device exists).
 
 conftest.py pins the suite to an 8-device virtual CPU mesh
 (``--xla_force_host_platform_device_count=8``).
 """
 
 from __future__ import annotations
-
-import json
-import os
-import pathlib
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -33,8 +24,6 @@ from ceph_tpu.osd.sharded_mapping import (
     sharded_batch_do_rule,
 )
 from ceph_tpu.tools.crushtool import build_hierarchy
-
-REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 @pytest.fixture()
@@ -246,95 +235,3 @@ def test_mesh_batch_do_rule_product_dispatch(fresh_default_mesh):
     res_on, cnt_on = mesh_batch_do_rule(cm, 0, xs, 3)
     assert np.array_equal(res_off, res_on)
     assert np.array_equal(cnt_off, cnt_on)
-
-
-def test_measure_mesh_scaling_curve(monkeypatch):
-    """bench.measure_mesh: a 1..N per-device curve with positive
-    throughput at every point and a monotone non-decreasing envelope
-    (the scaling headline) — structural assertions only; absolute
-    speedups on a shared-core virtual mesh are noise."""
-    import bench
-
-    monkeypatch.setenv("CEPH_TPU_BENCH_MESH_OSDS", "16:4:2")
-    out = bench.measure_mesh(
-        device_counts=[1, 2],
-        pgs=256,
-        batch=4,
-        chunk=1024,
-        trials=1,
-    )
-    assert out["device_count"] == 8 and out["platform"] == "cpu"
-    curve = out["curve"]
-    assert [c["devices"] for c in curve] == [1, 2]
-    for c in curve:
-        assert c["crush_mappings_per_sec"] > 0
-        assert c["ec_encode_GBps"] > 0
-    env = out["envelope"]
-    assert [e["devices"] for e in env] == [1, 2]
-    for a, b in zip(env, env[1:]):
-        assert b["crush_mappings_per_sec"] >= a["crush_mappings_per_sec"]
-        assert b["ec_encode_GBps"] >= a["ec_encode_GBps"]
-
-
-def test_bench_mesh_cpu_run_is_labelled():
-    """``bench.py --mesh`` with JAX_PLATFORMS=cpu set by the caller:
-    ONE JSON line, exit 0, labelled ``backend: cpu``, and every
-    measurement under ``cpu_backend`` — no device metric's name at the
-    top level."""
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-    env["CEPH_TPU_BENCH_MESH_COUNTS"] = "1,2"
-    env["CEPH_TPU_BENCH_MESH_PGS"] = "128"
-    env["CEPH_TPU_BENCH_MESH_BATCH"] = "4"
-    env["CEPH_TPU_BENCH_MESH_CHUNK"] = "1024"
-    env["CEPH_TPU_BENCH_MESH_OSDS"] = "16:4:2"
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "--mesh"],
-        cwd=REPO,
-        env=env,
-        capture_output=True,
-        text=True,
-        timeout=480,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [ln for ln in proc.stdout.splitlines() if ln.strip()]
-    assert len(lines) == 1, proc.stdout  # exactly ONE JSON line
-    out = json.loads(lines[0])
-    assert out["backend"] == "cpu"
-    assert out["metric"] == "cpu_backend_run"
-    assert out["value"] is None and "mesh" not in out, out
-    curve = out["cpu_backend"]["mesh"]["curve"]
-    assert [c["devices"] for c in curve] == [1, 2]
-
-
-def test_bench_backend_is_strict(monkeypatch):
-    """No TPU and no JAX_PLATFORMS=cpu from the caller: the bench
-    refuses instead of measuring whatever backend it finds."""
-    sys.path.insert(0, str(REPO))
-    import bench
-
-    monkeypatch.setattr(bench, "_BACKEND", None)
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    with pytest.raises(RuntimeError, match="needs a TPU"):
-        bench._backend()
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    assert bench._backend() == "cpu"
-
-
-def test_bench_exits_nonzero_on_section_error(monkeypatch, tmp_path, capsys):
-    """A section that fails is recorded in the line AND in the exit
-    code."""
-    sys.path.insert(0, str(REPO))
-    import bench
-
-    def boom():
-        raise RuntimeError("mesh section died")
-
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-    monkeypatch.setattr(bench, "measure_mesh", boom)
-    assert bench.main(["--mesh"]) == 1
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert "mesh section died" in out["error"]
-    assert bench._emit({"metric": "m", "crush_error": "x"}) == 1
-    assert bench._emit({"metric": "m", "value": 1}) == 0

@@ -157,8 +157,8 @@ def test_kernel_stats_counter_shapes_in_perf_dump():
 
 
 def test_kernel_stats_snapshot_rollup():
-    """bench.py embeds kernel_stats().snapshot() in its JSON result
-    line: compile-cache hit ratio plus per-group call/byte totals."""
+    """kernel_stats().snapshot(): compile-cache hit ratio plus
+    per-group call/byte totals, embeddable in a JSON line as it is."""
     ks = kernel_stats()
     ks.record("ec_encode", bytes_in=1024, bytes_out=2048, seconds=0.01)
     ks.record_cache(3, 1)

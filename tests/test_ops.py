@@ -44,14 +44,23 @@ def test_bitmatrix_regions_matches_oracle(w, packetsize):
     np.testing.assert_array_equal(want, got)
 
 
-def test_matrix_stripes_batches_encode():
+def test_matrix_stripe_shards_batches_encode():
     k, m, w = 4, 2, 8
     matrix = gf.reed_sol_vandermonde_coding_matrix(k, m, w)
     stripes = rng.integers(0, 256, size=(5, k, 128), dtype=np.uint8)
-    got = np.asarray(get_backend("jax").matrix_stripes(matrix, stripes, w))
+    data, coding = get_backend("jax").matrix_stripe_shards(
+        matrix, stripes, w
+    )
+    # a shard is chunk i of every stripe, concatenated
+    got = np.stack(data + coding).reshape(k + m, 5, 128)
+    np.testing.assert_array_equal(got[:k], stripes.transpose(1, 0, 2))
+    np.testing.assert_array_equal(
+        got[k:].transpose(1, 0, 2),
+        get_backend("numpy").matrix_stripes(matrix, stripes, w),
+    )
     for b in range(5):
         want = get_backend("numpy").matrix_regions(matrix, stripes[b], w)
-        np.testing.assert_array_equal(want, got[b])
+        np.testing.assert_array_equal(want, got[k:, b])
 
 
 PROFILES = [

@@ -53,8 +53,8 @@ def test_decode_matrix_matches_oracle():
 
 def test_stripes_layout(monkeypatch):
     """The one stripes path of the packed kernel, the jax backend's
-    ``matrix_stripes`` (fold, word form, kernel, unfold), through the
-    kernel's interpreter."""
+    ``matrix_stripe_shards`` (fold, word form, kernel, rows out),
+    through the kernel's interpreter."""
     from ceph_tpu.ops import ec_backend
 
     k, m = 8, 3
@@ -70,9 +70,12 @@ def test_stripes_layout(monkeypatch):
         return built(bm, w, interpret=True)
 
     monkeypatch.setattr(packed_gf, "prebuilt_word_call", interpreted)
-    got = ec_backend.get_jax_backend().matrix_stripes(mat, stripes, 8)
+    _data, coding = ec_backend.get_jax_backend().matrix_stripe_shards(
+        mat, stripes, 8
+    )
     assert calls == [(m * 8, k * 8)]
-    assert got.shape == (5, m, 512)
+    # coding shard j is chunk j of every stripe, concatenated
+    got = np.stack(coding).reshape(m, 5, 512).transpose(1, 0, 2)
     for s in range(5):
         want = matrix_vector_mul_region(mat, stripes[s], 8)
         np.testing.assert_array_equal(got[s], want)

@@ -351,26 +351,3 @@ def test_three_process_cluster_boot_write_read(tmp_path):
         sup.stop()
     # teardown left nothing behind
     assert not (tmp_path / "supervisor.json").exists()
-
-
-@pytest.mark.slow
-def test_procs_scale_curve():
-    """The bench `procs` section end-to-end: 1/2/4/8-process curves
-    for both legs plus the in-process baseline.  The >1.4x speedup
-    acceptance only binds where >=4 cores exist — a 1-core CI box
-    cannot scale processes past one core, and the artifact says so."""
-    sys.path.insert(
-        0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    )
-    import bench
-
-    r = bench.measure_procs()
-    assert [row["procs"] for row in r["procs"]["msgr"]] == [1, 2, 4, 8]
-    assert [row["procs"] for row in r["procs"]["index"]] == [1, 2, 4, 8]
-    assert r["procs_msgr_msgs_per_s"] > 0
-    assert r["procs_index_ops_per_s"] > 0
-    assert r["procs"]["msgr_inproc_4t_msgs_per_s"] > 0
-    assert r["procs"]["index_inproc_4t_ops_per_s"] > 0
-    assert r["procs_cores"] >= 1
-    if r["procs_cores"] >= 4:
-        assert r["procs_msgr_speedup"] > 1.4

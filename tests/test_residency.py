@@ -27,8 +27,12 @@ import time
 import numpy as np
 import pytest
 
+from ceph_tpu import gf
+from ceph_tpu.ec.backend import get_backend
+from ceph_tpu.ec.interface import ErasureCodeError
 from ceph_tpu.native import ceph_crc32c
 from ceph_tpu.ops.kernel_stats import kernel_stats
+from ceph_tpu.ops.profiler import dispatch_profiler
 from ceph_tpu.ops.residency import (
     DeviceBuf,
     ResidencyCache,
@@ -117,6 +121,83 @@ def test_encode_batch_identity_k8m3():
     datas = _payloads((32767, 32768, 32769, 500000))
     for data, got in zip(datas, codec.encode_object_batch(datas)):
         assert got == codec.encode_object(data)
+
+
+# objects' stripe counts at group_stripes 8, by the edge they sit on
+PACKING_EDGES = {
+    "one_group": (3, 2, 1),
+    "lands_on_group_stripes": (4, 4),
+    "one_stripe_over": (4, 4, 1),
+    "ragged_pads_to_pow2": (5,),
+    "empty_object_in_the_middle": (3, 0, 2),
+    "an_object_larger_than_a_group": (2, 11, 2),
+}
+
+
+@pytest.mark.parametrize("entry", ["matrix_stripes_batch", "decode_stripes_batch"])
+@pytest.mark.parametrize("edge", sorted(PACKING_EDGES))
+def test_coalesced_pipeline_packing_edges(edge, entry):
+    """The one coalesced pipeline of the jax backend, through both of
+    its entries, against the numpy oracle: greedy groups of at most
+    ``group_stripes`` stripes (an object is never split), each group
+    one upload of its exact bytes and one program on a power-of-two
+    batch, every object its own slice back — and ONE recorder entry
+    for the lot."""
+    k, m, w, chunk, group = 4, 2, 8, 64, 8
+    sizes = PACKING_EDGES[edge]
+    matrix = gf.reed_sol_vandermonde_coding_matrix(k, m, w)
+    rng = np.random.default_rng(len(edge))
+    objects = [
+        rng.integers(0, 256, size=(b, k, chunk), dtype=np.uint8)
+        for b in sizes
+    ]
+    # greedy packing, by hand: the groups' stripe counts
+    groups, cur = [], []
+    for b in sizes:
+        if cur and sum(cur) + b > group:
+            groups.append(sum(cur))
+            cur = []
+        cur.append(b)
+    groups.append(sum(cur))
+    padded = sum(bucket_pow2(g) - g for g in groups) * k * chunk
+    kind = "ec_encode" if entry == "matrix_stripes_batch" else "ec_decode"
+    if entry == "matrix_stripes_batch":
+        args = (matrix, objects, w)
+    else:
+        # an object's survivors as stored: shard i is chunk i of every
+        # stripe, concatenated
+        args = (
+            matrix,
+            [[np.ascontiguousarray(o[:, i, :]).reshape(-1) for i in range(k)]
+             for o in objects],
+            w,
+            chunk,
+        )
+    want = getattr(get_backend("numpy"), entry)(*args)
+    seen = dispatch_profiler().history(kind)["entries"]
+    seen = seen[-1]["seq"] if seen else 0
+    got = getattr(get_backend("jax"), entry)(*args, group_stripes=group)
+    assert len(got) == len(want) == len(sizes)
+    for b, g, o in zip(sizes, got, want):
+        assert g.shape == (b, m, chunk)
+        assert np.array_equal(np.asarray(g), o)
+    (rec,) = [
+        e for e in dispatch_profiler().history(kind)["entries"]
+        if e["seq"] > seen and e["backend"] == "jax"
+    ]
+    assert (rec["ops"], rec["stripes"]) == (len(sizes), sum(sizes))
+    assert rec["bytes_in"] == rec["bytes_uploaded"] == sum(sizes) * k * chunk
+    assert rec["bytes_padded"] == padded
+    assert rec["transfer_s"] > 0 and rec["compute_s"] > 0
+
+
+def test_coalesced_pipeline_refuses_mixed_geometry():
+    """Objects of one dispatch share (rows, chunk): what used to fall
+    to a per-batch loop nobody could reach is an error."""
+    matrix = gf.reed_sol_vandermonde_coding_matrix(4, 2, 8)
+    objects = [np.zeros((2, 4, 64), np.uint8), np.zeros((2, 4, 128), np.uint8)]
+    with pytest.raises(ErasureCodeError, match="geometry"):
+        get_backend("jax").matrix_stripes_batch(matrix, objects, 8)
 
 
 def test_batch_crc32c_devicebuf_identity():

@@ -34,11 +34,13 @@ def test_on_tpu_and_available_devices_propagate(broken_jax):
         mesh.available_devices()
 
 
-def test_matrix_stripes_propagates_backend_error(broken_jax):
+def test_matrix_stripe_shards_propagates_backend_error(broken_jax):
     matrix = gf.reed_sol_vandermonde_coding_matrix(4, 2, 8)
     stripes = np.zeros((2, 4, 64), dtype=np.uint8)
     with pytest.raises(RuntimeError, match="Unable to initialize"):
-        ec_backend.get_jax_backend().matrix_stripes(matrix, stripes, 8)
+        ec_backend.get_jax_backend().matrix_stripe_shards(
+            matrix, stripes, 8
+        )
     with pytest.raises(RuntimeError, match="Unable to initialize"):
         ec_backend.get_jax_backend().matrix_regions(
             matrix, stripes[0], 8

@@ -13,7 +13,8 @@ import pytest
 import ceph_tpu.ops  # noqa: F401  registers the jax backend
 from ceph_tpu.common import tracing
 from ceph_tpu.ec import ErasureCodeProfile, registry_instance
-from ceph_tpu.ec.stripe import StripeInfo, decode, decode_concat, encode
+from ceph_tpu.ec.stripe import (
+    StripeInfo, decode, decode_batch, decode_concat, encode)
 from ceph_tpu.ops import ec_backend, packed_gf
 from ceph_tpu.ops.kernel_stats import kernel_stats
 from ceph_tpu.ops.profiler import dispatch_profiler
@@ -105,30 +106,66 @@ def test_a_chunk_that_is_not_whole_words_takes_the_loop():
     assert (rec["backend"], rec["stripes"], rec["transfer_s"]) == ("numpy", 3, 0.0)
 
 
-def test_the_plan_is_gated_on_the_entry_point_it_calls():
-    """A backend with the coalesced ``decode_stripes_batch`` but no
-    ``matrix_shards`` has no one-dispatch rebuild: the loop runs, one
-    record, the same bytes."""
+HOST_LOOP_CODES = {
+    # a layered code has no backend of its own: its loop is "cpu";
+    # losing 1 and 7 leaves no single layer that rebuilds both
+    "lrc": ("lrc", dict(k="4", m="2", l="3", backend="jax"), (1, 7), "cpu"),
+    "cauchy_good:jax": (
+        "jerasure",
+        dict(technique="cauchy_good", k="4", m="2", w="8", packetsize="8", backend="jax"),
+        (0, 4), "jax"),
+    "cauchy_good:numpy": (
+        "jerasure",
+        dict(technique="cauchy_good", k="4", m="2", w="8", packetsize="8"),
+        (0, 4), "numpy"),
+}
 
-    class BatchOnly:
-        name = "batch_only"
 
-        def __init__(self, inner):
-            self.matrix_regions = inner.matrix_regions
-            self.decode_stripes_batch = inner.decode_stripes_batch
-
-    ec = _code(technique="reed_sol_van", k="4", m="2", w="8")
-    sinfo = StripeInfo(4, 4 * 64)
-    data = np.random.default_rng(8).integers(0, 256, 3 * 4 * 64, dtype=np.uint8)
+@pytest.mark.parametrize("seam", ["encode", "decode", "decode_batch"])
+@pytest.mark.parametrize("code", sorted(HOST_LOOP_CODES))
+def test_the_per_stripe_fallbacks_share_one_bracket(code, seam):
+    """A layered code and a bitmatrix technique have no whole-word
+    matrix path: each seam runs the reference's loop inside ONE host
+    recorder entry for the whole call, under the codec's backend
+    name, whole wall booked as compute — and the bytes are the
+    per-stripe plugin's."""
+    plugin, profile, lost, name = HOST_LOOP_CODES[code]
+    ec = _code(plugin, **profile)
+    k, nstripes = ec.get_data_chunk_count(), 3
+    chunk = ec.get_chunk_size(k * 256)
+    sinfo = StripeInfo(k, k * chunk)
+    data = np.random.default_rng(8).integers(
+        0, 256, nstripes * k * chunk, dtype=np.uint8)
+    kind = "ec_encode" if seam == "encode" else "ec_decode"
     shards = encode(sinfo, ec, data)
-    have = {p: s for p, s in shards.items() if p not in (1, 4)}
-    want = decode(sinfo, ec, have, (1, 4))
-    ec.backend = BatchOnly(ec.backend)
-    seen = len(_records("ec_decode"))
-    got = decode(sinfo, ec, have, (1, 4))
-    (rec,) = _records("ec_decode")[seen:]
-    assert (rec["backend"], rec["stripes"]) == ("batch_only", 3)
-    assert all(np.array_equal(got[p], want[p]) for p in (1, 4))
+    have = {p: s for p, s in shards.items() if p not in lost}
+    have_bytes = sum(v.nbytes for v in have.values())
+    seen = len(_records(kind))
+    if seam == "encode":
+        got, positions = [encode(sinfo, ec, data)], sorted(shards)
+        expect = (name, 1, nstripes, data.nbytes)
+        per_stripe = [
+            ec.encode(set(shards), data[s * k * chunk:(s + 1) * k * chunk])
+            for s in range(nstripes)]
+    else:
+        positions = lost
+        per_stripe = [
+            ec.decode(set(lost), {q: v[s * chunk:(s + 1) * chunk] for q, v in have.items()})
+            for s in range(nstripes)]
+        if seam == "decode":
+            got = [decode(sinfo, ec, have, lost)]
+            expect = (name, 1, nstripes, have_bytes)
+        else:
+            got = decode_batch(sinfo, ec, [have, have], lost)
+            # the group's loop never counted stripes
+            expect = (name, 2, 0, 2 * have_bytes)
+    (rec,) = _records(kind)[seen:]
+    assert (rec["backend"], rec["ops"], rec["stripes"], rec["bytes_in"]) == expect
+    assert rec["bytes_uploaded"] == 0 and rec["compute_s"] == rec["wall_s"]
+    for one in got:
+        for p in positions:
+            assert np.array_equal(
+                np.asarray(one[p]), np.concatenate([c[p] for c in per_stripe])), p
 
 
 def test_decode_returns_what_is_at_hand_without_a_dispatch():
@@ -217,7 +254,7 @@ def test_the_stage_helper_counts_with_and_without_an_ambient_tracer():
 
 
 def test_the_packed_encode_path_brackets_its_stages(monkeypatch):
-    """The chip-only path of matrix_stripes, run here through the
+    """The chip-only path of matrix_stripe_shards, run here through the
     kernel's interpreter: the same bytes as the bitplane path, the
     recorder's upload / compute / sync each bracketing its own part,
     the host fold (a span a row, each row going up as it is folded)

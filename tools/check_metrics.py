@@ -105,8 +105,8 @@ RESIDENCY_COUNTERS = (
 )
 # device-dispatch flight-recorder family the kernel-stats schema must
 # declare (ops/profiler.py ensure_dispatch_counters — the
-# transfer/compute/sync attribution plane the bench breakdown and the
-# `dispatch history|summary` tell surface read), plus the pad-waste
+# transfer/compute/sync attribution plane the benchmark's harness and
+# the `dispatch history|summary` tell surface read), plus the pad-waste
 # counter kernel_stats registers at construction
 DISPATCH_COUNTERS = (
     "l_tpu_dispatch_count",
@@ -502,8 +502,7 @@ def check_proc_counters() -> list[str]:
 
 def check_recovery_counters() -> list[str]:
     """The recovery-storm plane: the OSD schema's l_osd_recovery_*
-    block (bench.py's recovery section and the LRC fan-in assertion
-    read exactly these)."""
+    block (`perf dump` and /metrics serve exactly these)."""
     from ceph_tpu.osd.daemon import build_osd_perf
 
     declared = set(build_osd_perf(0)._counters)
@@ -592,7 +591,7 @@ def check_dispatch_counters() -> list[str]:
     """The kernel-stats schema must keep declaring the
     flight-recorder family through the REAL registration helper
     (ops/profiler.ensure_dispatch_counters — the exact names the
-    bench dispatch breakdown and the prometheus exporter read), with
+    prometheus exporter reads), with
     the stage-latency histograms carrying real bucket bounds."""
     from ceph_tpu.ops.kernel_stats import KernelStats
     from ceph_tpu.ops.profiler import ensure_dispatch_counters
