@@ -24,6 +24,7 @@ from __future__ import annotations
 import base64
 import decimal
 import functools
+import threading
 
 import numpy as np
 
@@ -65,9 +66,22 @@ _LL_B85 = (
 )
 
 
+# base64.b85decode fills its own decode table on first use and
+# without a lock: of two first calls at once (ten OSDs booting in one
+# process) one can read the table half filled and raise "bad base85
+# character".  This is the process's one caller, so one lock round the
+# build is enough.
+_TABLES_LOCK = threading.Lock()
+
+
 @functools.lru_cache(maxsize=1)
 def _tables() -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """(RH, LH, LL) as int64 arrays (values < 2^49 fit comfortably)."""
+    with _TABLES_LOCK:
+        return _build_tables()
+
+
+def _build_tables() -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     rh = np.array(
         [-((-(1 << 55)) // (128 + k)) for k in range(129)], dtype=np.int64
     )

@@ -59,6 +59,14 @@ DISPATCH_QUEUE_HIGH_DEFAULT = 256
 # any legitimate message (multi-MB chunk writes) but far below the
 # 4 GiB the u32 prefix could otherwise demand
 MAX_FRAME_LEN = 1 << 28
+# asyncio's stream reader stops the transport whenever it holds more
+# than twice its limit and starts it again at the next read: at the
+# default 64 KiB a 4 MiB frame is some thirty stop/start pairs, each
+# two system calls that give up the interpreter.  A client's 4 MiB op
+# and a 1 MiB shard pass under this one without any; a connection
+# whose reads are stalled (_maybe_stall_reads) still pushes back on
+# its peer once 8 MiB are buffered.
+STREAM_LIMIT = 4 << 20
 
 
 class Dispatcher:
@@ -615,7 +623,7 @@ class Messenger:
 
         async def _serve():
             self._server = await asyncio.start_server(
-                self._accept, host, port
+                self._accept, host, port, limit=STREAM_LIMIT
             )
             return self._server.sockets[0].getsockname()[:2]
 
@@ -632,7 +640,9 @@ class Messenger:
         self.start()
 
         async def _dial():
-            reader, writer = await asyncio.open_connection(host, port)
+            reader, writer = await asyncio.open_connection(
+                host, port, limit=STREAM_LIMIT
+            )
             try:
                 return await _negotiate(reader, writer)
             except BaseException:

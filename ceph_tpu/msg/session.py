@@ -170,6 +170,49 @@ class _SessionState:
                     return
 
 
+class PendingReply:
+    """A request ``SessionConnection.submit`` has put on the wire and
+    whose reply nobody has waited for yet; ``wait`` is the second half
+    of ``call``."""
+
+    __slots__ = ("_session", "_tid", "_cf")
+
+    def __init__(
+        self,
+        session: "SessionConnection",
+        tid: int,
+        cf: concurrent.futures.Future,
+    ):
+        self._session, self._tid, self._cf = session, tid, cf
+
+    def wait(self, timeout: float = _CALL_TIMEOUT) -> Message:
+        """The reply, or MessageError once ``timeout`` seconds have
+        passed without one (a reply that is already here is returned
+        whatever the timeout: several waits may share one deadline)."""
+        sc = self._session
+        deadline = time.monotonic() + timeout
+        try:
+            while True:
+                remaining = deadline - time.monotonic()
+                try:
+                    return self._cf.result(max(0.0, min(0.1, remaining)))
+                except concurrent.futures.TimeoutError:
+                    if remaining <= 0:
+                        raise MessageError(
+                            f"session call tid={self._tid} timed out"
+                        ) from None
+                    # reconnect only when the socket actually died —
+                    # the handshake replays the request AND the reply
+                    conn = sc._conn
+                    if conn is None or conn.is_closed:
+                        try:
+                            sc._ensure()
+                        except (MessageError, OSError):
+                            time.sleep(0.05)
+        finally:
+            sc._forget(self._tid)
+
+
 class SessionConnection:
     """Dialer half: the Connection API (send/call) surviving TCP
     drops with replay.  One instance per (messenger, peer, name)."""
@@ -216,6 +259,14 @@ class SessionConnection:
     def call(
         self, msg: Message, timeout: float = _CALL_TIMEOUT
     ) -> Message:
+        return self.submit(msg).wait(timeout)
+
+    def submit(self, msg: Message) -> "PendingReply":
+        """The first half of ``call``: put ``msg`` on the session and
+        return at once.  Whoever submits must ``wait`` on what comes
+        back (that is what forgets the tid); several requests, to one
+        peer or to many, can then be on the wire before the first
+        wait."""
         if msg.tid == 0:
             msg.tid = self.msgr.new_tid()
         # fail fast when the peer is unreachable NOW and no session
@@ -231,29 +282,16 @@ class SessionConnection:
         cf: concurrent.futures.Future = concurrent.futures.Future()
         with self._plock:
             self._pending[msg.tid] = cf
-        deadline = time.monotonic() + timeout
         try:
             self.state.send_wrapped(msg, conn, self.msgr.new_tid)
-            while True:
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    raise MessageError(
-                        f"session call tid={msg.tid} timed out"
-                    )
-                try:
-                    return cf.result(min(0.1, remaining))
-                except concurrent.futures.TimeoutError:
-                    # reconnect only when the socket actually died —
-                    # the handshake replays the request AND the reply
-                    conn = self._conn
-                    if conn is None or conn.is_closed:
-                        try:
-                            self._ensure()
-                        except (MessageError, OSError):
-                            time.sleep(0.05)
-        finally:
-            with self._plock:
-                self._pending.pop(msg.tid, None)
+        except BaseException:
+            self._forget(msg.tid)
+            raise
+        return PendingReply(self, msg.tid, cf)
+
+    def _forget(self, tid: int) -> None:
+        with self._plock:
+            self._pending.pop(tid, None)
 
     # -- transport management ----------------------------------------------
     def _ensure(self) -> Connection:

@@ -73,12 +73,17 @@ def _lib():
                 )
             tmp_path.replace(so)
         lib = ctypes.CDLL(str(so))
-        lib.ceph_crc32c.restype = ctypes.c_uint32
-        lib.ceph_crc32c.argtypes = [
-            ctypes.c_uint32,
-            ctypes.c_char_p,
-            ctypes.c_size_t,
-        ]
+        # the same routine through a handle that keeps the interpreter
+        # lock across the call, for inputs too short to be worth
+        # giving it up (ceph_crc32c below)
+        lib.crc32c_held = ctypes.PyDLL(str(so)).ceph_crc32c
+        for crc in (lib.ceph_crc32c, lib.crc32c_held):
+            crc.restype = ctypes.c_uint32
+            crc.argtypes = [
+                ctypes.c_uint32,
+                ctypes.c_char_p,
+                ctypes.c_size_t,
+            ]
         try:
             u8p = ctypes.POINTER(ctypes.c_uint8)
             lib.gf8_region_mac.restype = None
@@ -155,6 +160,11 @@ def gf8_matrix_regions(matrix, regions):
     return out
 
 
+# below this many bytes (some 30 us of checksum at 2 GB/s) the crc is
+# computed holding the interpreter lock
+_CRC_RELEASE_BYTES = 1 << 16
+
+
 def ceph_crc32c(crc: int, data: bytes | memoryview) -> int:
     """ceph_crc32c(seed, data) — matches src/include/crc32c.h semantics
     (verified against the reference's test vectors in
@@ -162,6 +172,13 @@ def ceph_crc32c(crc: int, data: bytes | memoryview) -> int:
     data = bytes(data)
     lib = _lib()
     if lib is not None:
+        # a frame header, a heartbeat or an ack checksums in a
+        # microsecond or two: letting go of the interpreter for that
+        # costs a thread hand-over (a fraction of a millisecond when
+        # other threads want it) some three hundred times a served
+        # write.  Only a payload worth the hand-over releases it.
+        if len(data) < _CRC_RELEASE_BYTES:
+            return lib.crc32c_held(crc & 0xFFFFFFFF, data, len(data))
         return lib.ceph_crc32c(crc & 0xFFFFFFFF, data, len(data))
     table = _py_table()
     crc &= 0xFFFFFFFF

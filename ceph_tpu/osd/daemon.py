@@ -2606,34 +2606,51 @@ class OSD(Dispatcher):
             while len(pg.reqid_cache) > 4 * self.log_keep:
                 pg.reqid_cache.pop(next(iter(pg.reqid_cache)))
         failed: list[int] = []
-        # first MOSDRepOp sent -> last ack: the calls block one after
-        # another, and the per-peer events land on this span
+        # first MOSDRepOp sent -> last ack (issue_repop): every sub-op
+        # is on the wire before the first wait, every one of them is
+        # resolved before the span closes, and the per-peer events
+        # land on it
         with tracing.span(
             "sub_op_wait", tags={"peers": len(txn_by_osd) - 1}
         ) as wait:
+
+            def mark(event: str) -> None:
+                if self._cur_op is not None:
+                    self._cur_op.mark_event(event)
+                wait.mark_event(event)
+
+            deadline = time.monotonic() + self.repop_timeout
+            issued = []  # (osd, sent at, pending reply)
             for osd, txn in txn_by_osd.items():
                 if osd == self.whoami:
                     continue
-                if self._cur_op is not None:
-                    self._cur_op.mark_event(f"sub_op_sent osd.{osd}")
-                wait.mark_event(f"sub_op_sent osd.{osd}")
+                mark(f"sub_op_sent osd.{osd}")
+                sent_at = time.perf_counter()
                 try:
-                    ack = self._peer_conn(osd).call(
+                    pending = self._peer_conn(osd).submit(
                         MOSDRepOp(
                             pgid=pg.pgid, epoch=epoch, txn=txn,
                             entry_blob=entry_blob, trace=msg.reqid,
-                        ),
-                        timeout=self.repop_timeout,
+                        )
                     )
-                    if isinstance(ack, MOSDRepOpReply) and not ack.ok:
-                        failed.append(osd)
-                    else:
-                        if self._cur_op is not None:
-                            self._cur_op.mark_event(
-                                f"sub_op_commit_rec osd.{osd}"
-                            )
-                        wait.mark_event(f"sub_op_commit_rec osd.{osd}")
                 except (MessageError, OSError):
+                    failed.append(osd)
+                    continue
+                issued.append((osd, sent_at, pending))
+            for osd, sent_at, pending in issued:
+                try:
+                    ack = pending.wait(deadline - time.monotonic())
+                    ok = not isinstance(ack, MOSDRepOpReply) or ack.ok
+                except (MessageError, OSError):
+                    ok = False
+                self.tracer.record(
+                    "sub_op_rtt", msg.reqid, sent_at,
+                    role=tracing.ROLE_PRIMARY,
+                    tags={"osd": osd, "ok": ok},
+                )
+                if ok:
+                    mark(f"sub_op_commit_rec osd.{osd}")
+                else:
                     failed.append(osd)
         live_failures = [
             osd for osd in failed if self.monc.osdmap.is_up(osd)

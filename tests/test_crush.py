@@ -530,3 +530,48 @@ def test_choose_args_empty_weight_set_falls_back():
     for x in range(64):
         expect = m.do_rule(0, x, 3)
         assert got[x, : counts[x]].tolist() == expect, x
+
+
+def test_crush_ln_tables_survive_concurrent_first_calls():
+    """Ten OSDs booting in one process all ask for the tables at once;
+    ``base64.b85decode`` fills its decode table lazily and unlocked, so
+    without the lock round the build one of them can read it half
+    filled ("bad base85 character", seen once as a pool that never
+    went active).  Many first calls, a short switch interval."""
+    import base64
+    import sys
+    import threading
+
+    from ceph_tpu.crush import ln
+
+    want = int(crush_ln(0x7FFF))
+    errors: list[BaseException] = []
+
+    def first_call(go: threading.Event) -> None:
+        go.wait(5)
+        try:
+            assert int(ln.crush_ln(0x7FFF)) == want
+        except BaseException as e:  # noqa: BLE001 — collected, then raised
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for _ in range(6):
+            ln._tables.cache_clear()
+            base64._b85dec = None  # as in a process that never decoded
+            go = threading.Event()
+            pool = [
+                threading.Thread(target=first_call, args=(go,))
+                for _ in range(16)
+            ]
+            for t in pool:
+                t.start()
+            go.set()
+            for t in pool:
+                t.join(30)
+            assert not any(t.is_alive() for t in pool)
+    finally:
+        sys.setswitchinterval(interval)
+        ln._tables.cache_clear()
+    assert errors == []

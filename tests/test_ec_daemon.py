@@ -425,3 +425,76 @@ def test_ec_partial_overwrite_ships_only_stripe_range(cluster):
             assert 0 < nbytes <= 2 * 4096, (pos, nbytes)
     want += b"tailbytes" * 100 + b"more-tail" * 50
     assert io.read("big") == bytes(want)
+
+
+def test_ec_write_one_shard_naks_the_others_are_still_waited_for():
+    """k=2 m=2: three sub-ops go out together; the middle peer
+    refuses.  The two others are still waited for and acked, the
+    first try ends -EAGAIN (the objecter retries the reqid), and
+    every position then holds exactly its own shard, logged once."""
+    c = ECCluster(4)
+    try:
+        pool_id = c.create_ec_pool(
+            "nakpool", ["k=2", "m=2", "plugin=jerasure"], pg_num=1
+        )
+        io = c.rados.open_ioctx("nakpool")
+        io.write_full("warm", b"w" * 4096)
+        pgid = f"{pool_id}.0"
+        osdmap = c.rados.monc.osdmap
+        _u, _up, acting, primary_id = osdmap.pg_to_up_acting_osds(pool_id, 0)
+        primary = c.osds[primary_id]
+        peers = [o for o in acting if o != primary_id]
+        victim = peers[1]
+        refused = []
+        commit = primary._commit_and_replicate
+
+        def spy(pg, *args, **kw):
+            try:
+                return commit(pg, *args, **kw)
+            except Exception as e:
+                refused.append((str(e), pg.repop_clean))
+                raise
+
+        primary._commit_and_replicate = spy
+        c.osds[victim].pgs[pgid].activated_epoch = 0
+        data = b"nak-one-shard" * 777
+        io.write_full("nakobj", data)
+        assert io.read("nakobj") == data
+        ((error, clean),) = refused
+        assert f"[{victim}]" in error and "EAGAIN" in error and clean is False
+        (events,) = [
+            [e["event"] for e in s["events"]]
+            for s in primary.tracer.dump_traces()["spans"]
+            if s["name"] == "sub_op_wait" and len(s["events"]) < 6
+        ]
+        assert events == [f"sub_op_sent osd.{o}" for o in peers] + [
+            f"sub_op_commit_rec osd.{o}" for o in peers if o != victim
+        ]
+        shards, _meta = ECCodec(
+            osdmap.erasure_code_profiles[
+                osdmap.pools[pool_id].erasure_code_profile
+            ]
+        ).encode_object(data)
+        reqid = next(
+            e.reqid for e in primary.pgs[pgid].log.entries if e.oid == "nakobj"
+        )
+
+        def level():
+            for pos, osd_id in enumerate(acting):
+                pg = c.osds[osd_id].pgs[pgid]
+                logged = [e for e in pg.log.entries if e.reqid == reqid]
+                if len(logged) != 1:
+                    return False
+                store = c.stores[osd_id]
+                if not store.exists(pg.cid, OBJ_PREFIX + "nakobj"):
+                    return False
+                if store.read(pg.cid, OBJ_PREFIX + "nakobj") != shards[pos]:
+                    return False
+            return True
+
+        deadline = time.monotonic() + 20
+        while not level():
+            assert time.monotonic() < deadline, "the refused shard never landed"
+            time.sleep(0.1)
+    finally:
+        c.shutdown()

@@ -132,3 +132,87 @@ def test_unclaimed_message_drops_silently():
     finally:
         client.shutdown()
         server.shutdown()
+
+
+# -- fewer hand-overs of the interpreter on a frame's path (ISSUE 34) ---------
+
+
+def test_a_short_crc_keeps_the_interpreter_and_a_payload_gives_it_up(monkeypatch):
+    """Either side of ``_CRC_RELEASE_BYTES`` the checksum is the same
+    number (the table walk is the reference); which handle computed
+    it is what differs."""
+    import os
+
+    from ceph_tpu import native
+
+    lib = native._lib()
+    if lib is None:
+        pytest.skip("no C compiler here: the table walk is the only path")
+    calls = []
+    held, released = lib.crc32c_held, lib.ceph_crc32c
+    monkeypatch.setattr(
+        lib, "crc32c_held",
+        lambda c, d, n: calls.append(("held", n)) or held(c, d, n),
+    )
+    monkeypatch.setattr(
+        lib, "ceph_crc32c",
+        lambda c, d, n: calls.append(("released", n)) or released(c, d, n),
+    )
+    table = native._py_table()
+
+    def walk(crc: int, data: bytes) -> int:
+        for b in data:
+            crc = (crc >> 8) ^ table[(crc ^ b) & 0xFF]
+        return crc
+
+    edge = native._CRC_RELEASE_BYTES
+    data = os.urandom(edge + 1)
+    for n in (0, 20, edge - 1, edge, edge + 1):
+        assert native.ceph_crc32c(0xDEADBEEF, data[:n]) == walk(0xDEADBEEF, data[:n])
+    assert calls == [
+        ("held", 0), ("held", 20), ("held", edge - 1),
+        ("released", edge), ("released", edge + 1),
+    ]
+
+
+def test_a_4_mib_frame_is_read_without_stopping_the_transport(monkeypatch):
+    """Both ends open their streams with ``STREAM_LIMIT``: an op of
+    4 MiB passes under the reader's high-water mark (twice the limit),
+    so the transport is not paused and resumed every 128 KiB."""
+    import asyncio
+    import os
+
+    from ceph_tpu.msg import messenger as messenger_mod
+
+    paused = []
+    pause = asyncio.selector_events._SelectorSocketTransport.pause_reading
+    monkeypatch.setattr(
+        asyncio.selector_events._SelectorSocketTransport, "pause_reading",
+        lambda self: paused.append(1) or pause(self),
+    )
+    got = []
+
+    class Sink(Dispatcher):
+        def ms_dispatch(self, conn, msg) -> bool:
+            if isinstance(msg, MECSubWrite):
+                got.append(len(msg.txn.ops[0][4]))
+                conn.send(MECSubWriteReply(tid=msg.tid, ok=True))
+                return True
+            return False
+
+    server = Messenger("limit-srv")
+    server.add_dispatcher(Sink())
+    addr = server.bind()
+    client = Messenger("limit-cli")
+    try:
+        conn = client.connect(*addr)
+        assert conn._reader._limit == messenger_mod.STREAM_LIMIT
+        txn = Transaction().write("c", "o", 0, os.urandom(4 << 20))
+        for _ in range(3):
+            assert conn.call(MECSubWrite(txn=txn), timeout=20).ok
+        (accepted,) = list(server._conns)
+        assert accepted._reader._limit == messenger_mod.STREAM_LIMIT
+    finally:
+        client.shutdown()
+        server.shutdown()
+    assert got == [4 << 20] * 3 and paused == []

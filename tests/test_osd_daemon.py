@@ -352,3 +352,162 @@ def test_append_is_atomic_and_log_trims(cluster):
     logs = [o for o in osd.store.list_objects(pg.cid)
             if o.startswith("_log/")]
     assert len(logs) == len(pg.log.entries)
+
+
+# -- the repop fan-out's failure path (issue_repop: send all, wait once) -----
+
+
+def _try_once(c, pgid: str, oid: str, data: bytes, reqid: str):
+    """ONE write_full to the primary and its reply, ok or not."""
+    primary = c.osds[c.primary_of(pgid)]
+    reply = c.client_msgr.connect(*primary.addr).call(
+        MOSDOp(
+            pool=POOL, pgid=pgid, oid=oid, op=OSD_OP_WRITEFULL, data=data,
+            length=-1, reqid=reqid, epoch=c.monc.epoch,
+        ),
+        timeout=20.0,
+    )
+    assert isinstance(reply, MOSDOpReply)
+    return reply
+
+
+def _fan_out_spans(primary, reqid: str):
+    """(the events of the ``sub_op_wait`` span, {peer: ok} of the
+    ``sub_op_rtt`` spans) of the primary's first try under ``reqid``."""
+    spans = primary.tracer.dump_traces(reqid)["spans"]
+    wait = next(s for s in spans if s["name"] == "sub_op_wait")
+    rtts = {
+        s["tags"]["osd"]: s["tags"]["ok"]
+        for s in spans
+        if s["name"] == "sub_op_rtt" and s["end"] <= wait["end"] + 1e-6
+    }
+    return [e["event"] for e in wait["events"]], rtts
+
+
+def _name_link(primary, peer) -> str:
+    host, port = peer.addr
+    primary.messenger.faults.alias(f"osd.{peer.whoami}", f"{host}:{port}")
+    return f"osd.{peer.whoami}"
+
+
+@pytest.mark.parametrize("fault", ["nak", "link_drop", "no_address"])
+def test_a_failed_sub_op_fails_the_write_once_every_peer_is_resolved(
+    cluster, fault
+):
+    """One of the two peers refuses, loses the frame, or cannot be
+    dialed: the other's sub-op is still sent and still waited for
+    (its ack counts though it is read after the deadline), the op
+    ends -EAGAIN with the PG marked unclean and un-peered for the
+    queued re-peer, and the client's retry of the same reqid finds
+    the write committed exactly once on all three."""
+    from ceph_tpu.store.objectstore import StoreError
+
+    c = cluster
+    pgid = "1.0"
+    c.op(pgid, "pre", OSD_OP_WRITEFULL, b"pre")  # the sessions are dialed
+    primary = c.osds[c.primary_of(pgid)]
+    pg = primary.pgs[pgid]
+    # the victim is sent to, and waited for, FIRST
+    victim, good = [o for o in pg.acting if o != primary.whoami]
+    primary.repop_timeout = 1.0
+    addrs = primary.monc.osdmap.osd_addrs
+    if fault == "nak":
+        c.osds[victim].pgs[pgid].activated_epoch = 0
+        heal = lambda: None  # noqa: E731 — the re-peer activates it again
+    elif fault == "link_drop":
+        primary.messenger.faults.add_rule(
+            dst=_name_link(primary, c.osds[victim]), drop=1.0
+        )
+        heal = primary.messenger.faults.clear
+    else:
+        addr = addrs.pop(victim)
+        heal = lambda: addrs.setdefault(victim, addr)  # noqa: E731
+    at_failure = []
+    commit = primary._commit_and_replicate
+
+    def spy(pg_, *args, **kw):
+        try:
+            return commit(pg_, *args, **kw)
+        except StoreError as e:
+            at_failure.append((str(e), pg_.repop_clean, pg_.peered_interval))
+            heal()  # the re-peer this queued finds the peer again
+            raise
+
+    primary._commit_and_replicate = spy
+    reqid, data = f"fanout.{fault}", fault.encode() * 100
+    reply = _try_once(c, pgid, "fan", data, reqid)
+    assert not reply.ok and "EAGAIN" in reply.error, reply.error
+    ((error, clean, interval),) = at_failure
+    assert f"[{victim}]" in error and clean is False and interval is None
+    events, rtts = _fan_out_spans(primary, reqid)
+    assert events == [
+        f"sub_op_sent osd.{victim}", f"sub_op_sent osd.{good}",
+        f"sub_op_commit_rec osd.{good}",
+    ]
+    assert rtts == (
+        {good: True} if fault == "no_address" else {victim: False, good: True}
+    )
+    # the retry: the reqid cache answers, and the re-peer has (or
+    # will have) brought the victim level
+    deadline = time.monotonic() + 20
+    while not _try_once(c, pgid, "fan", data, reqid).ok:
+        assert time.monotonic() < deadline, "the retry never succeeded"
+        time.sleep(0.15)
+
+    def level():
+        for osd in c.osds.values():
+            mine = [e for e in osd.pgs[pgid].log.entries if e.reqid == reqid]
+            if len(mine) != 1:
+                return False
+            try:
+                if osd.store.read(osd.pgs[pgid].cid, OBJ_PREFIX + "fan") != data:
+                    return False
+            except StoreError:
+                return False
+        return pg.repop_clean and pg.peered_interval is not None
+
+    while not level():
+        assert time.monotonic() < deadline, {
+            o: [e.reqid for e in osd.pgs[pgid].log.entries]
+            for o, osd in c.osds.items()
+        }
+        time.sleep(0.1)
+    assert len(at_failure) == 1  # nothing was applied a second time
+
+
+def test_the_client_is_answered_after_the_slowest_peers_ack(cluster):
+    """The ack point did not move: with one link delayed the other
+    peer commits at once (the sub-ops overlap) and the client's reply
+    still waits for the delayed one."""
+    c = cluster
+    pgid = "1.0"
+    c.op(pgid, "pre", OSD_OP_WRITEFULL, b"pre")
+    primary = c.osds[c.primary_of(pgid)]
+    slow, fast = [o for o in primary.pgs[pgid].acting if o != primary.whoami]
+    committed: dict[int, float] = {}
+    for o in (slow, fast):
+        osd = c.osds[o]
+
+        def handle(conn, msg, osd=osd, inner=osd._handle_rep_op):
+            inner(conn, msg)
+            committed[osd.whoami] = time.monotonic()
+
+        osd._handle_rep_op = handle
+    primary.messenger.faults.add_rule(
+        dst=_name_link(primary, c.osds[slow]), delay=0.5
+    )
+    try:
+        t0 = time.monotonic()
+        reply = _try_once(c, pgid, "late", b"late" * 100, "fanout.delay")
+        acked = time.monotonic()
+    finally:
+        primary.messenger.faults.clear()
+    assert reply.ok, reply.error
+    assert committed[fast] - t0 < 0.5 <= committed[slow] - t0
+    assert committed[slow] <= acked
+    events, rtts = _fan_out_spans(primary, "fanout.delay")
+    assert events[:2] == [f"sub_op_sent osd.{slow}", f"sub_op_sent osd.{fast}"]
+    assert sorted(events[2:]) == sorted(
+        f"sub_op_commit_rec osd.{o}" for o in (slow, fast)
+    )
+    assert rtts == {slow: True, fast: True}

@@ -12,7 +12,7 @@ import time
 import pytest
 
 from ceph_tpu.msg import Messenger, MPing, Message
-from ceph_tpu.msg.message import MOSDOpReply
+from ceph_tpu.msg.message import MessageError, MOSDOpReply
 from ceph_tpu.msg.messenger import Dispatcher, wait_for
 from ceph_tpu.rados import Rados
 
@@ -87,6 +87,157 @@ def test_session_replays_unacked_after_drop_without_duplicates():
     finally:
         cli_msgr.shutdown()
         srv_msgr.shutdown()
+
+
+class HoldingServer(Dispatcher):
+    """Counts every (deduped) ping and answers it when told to:
+    ``release`` replies, in arrival order, to what has come so far;
+    stamps below zero are never answered."""
+
+    def __init__(self):
+        self.received: list[float] = []
+        self._held: list = []
+        self.hold = True
+
+    def ms_dispatch(self, conn, msg) -> bool:
+        if not isinstance(msg, MPing) or msg.is_reply:
+            return False
+        self.received.append(msg.stamp)
+        self._held.append((conn, msg))
+        if not self.hold:
+            self.release()
+        return True
+
+    def release(self) -> None:
+        held, self._held = self._held, []
+        for conn, msg in held:
+            if msg.stamp >= 0:
+                conn.send(
+                    MPing(
+                        tid=msg.tid, from_osd=99, stamp=msg.stamp,
+                        is_reply=True,
+                    )
+                )
+
+
+@pytest.fixture
+def held():
+    """(server dispatcher, server messenger, a dialed session)."""
+    srv_msgr = Messenger("sess-hold-srv")
+    srv = HoldingServer()
+    srv_msgr.add_dispatcher(srv)
+    host, port = srv_msgr.bind()
+    cli_msgr = Messenger("sess-hold-cli")
+    try:
+        yield srv, srv_msgr, cli_msgr.connect_session(host, port, "t3")
+    finally:
+        cli_msgr.shutdown()
+        srv_msgr.shutdown()
+
+
+def _call(sc, msg, timeout):
+    return sc.call(msg, timeout=timeout)
+
+
+def _submit_then_wait(sc, msg, timeout):
+    return sc.submit(msg).wait(timeout)
+
+
+@pytest.mark.parametrize("send", [_call, _submit_then_wait])
+@pytest.mark.parametrize("outcome", ["reply", "timeout", "reset"])
+def test_call_is_submit_then_wait(held, send, outcome):
+    """One implementation: the same reply, the same timeout error with
+    nothing left pending, the same fail-fast on a peer that is gone."""
+    srv, srv_msgr, sc = held
+    srv.hold = False
+    first = send(sc, MPing(from_osd=1, stamp=1.0), 5.0)
+    assert isinstance(first, MPing) and first.is_reply and first.stamp == 1.0
+    if outcome == "timeout":
+        t0 = time.monotonic()
+        with pytest.raises(MessageError, match="timed out"):
+            send(sc, MPing(from_osd=1, stamp=-1.0), 0.3)
+        assert 0.3 <= time.monotonic() - t0 < 3.0
+        assert srv.received == [1.0, -1.0]
+    elif outcome == "reset":
+        old_conn = sc._conn
+        srv_msgr.shutdown()
+        assert wait_for(lambda: old_conn.is_closed, 5.0)
+        with pytest.raises((MessageError, OSError)):
+            send(sc, MPing(from_osd=1, stamp=2.0), 5.0)
+    assert sc._pending == {}
+
+
+def test_two_submits_keep_fifo_order_and_resolve_apart(held):
+    """Both requests are on the wire before either wait; they arrive
+    in seq order and each wait gets its own reply, whichever is
+    waited for first."""
+    srv, _srv_msgr, sc = held
+    a = sc.submit(MPing(from_osd=1, stamp=1.0))
+    b = sc.submit(MPing(from_osd=1, stamp=2.0))
+    assert [seq for seq, _frame in sc.state.unacked] == [1, 2]
+    assert wait_for(lambda: srv.received == [1.0, 2.0], 5.0)
+    assert len(sc._pending) == 2
+    srv.release()
+    assert b.wait(5.0).stamp == 2.0
+    assert a.wait(5.0).stamp == 1.0
+    assert sc._pending == {}
+
+
+def test_concurrent_submitters_each_get_their_own_reply(held):
+    """More threads than cores submit and wait on one session under a
+    short switch interval: every request is delivered once, every wait
+    returns the reply to its own request, nothing is left pending."""
+    import sys
+
+    srv, _srv_msgr, sc = held
+    srv.hold = False
+    threads, each = 16, 12
+    wrong: list = []
+
+    def worker(n: int) -> None:
+        for i in range(each):
+            stamp = float(n * 1000 + i)
+            first = sc.submit(MPing(from_osd=1, stamp=stamp))
+            second = sc.submit(MPing(from_osd=1, stamp=stamp + 0.5))
+            got = (second.wait(30.0).stamp, first.wait(30.0).stamp)
+            if got != (stamp + 0.5, stamp):
+                wrong.append((stamp, got))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        pool = [threading.Thread(target=worker, args=(n,)) for n in range(threads)]
+        for t in pool:
+            t.start()
+        for t in pool:
+            t.join(60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in pool)
+    assert wrong == []
+    assert len(srv.received) == len(set(srv.received)) == threads * each * 2
+    assert sc._pending == {}
+
+
+def test_a_drop_between_submit_and_wait_replays_the_request_once(held):
+    """The transport dies with the request delivered and its reply
+    not yet sent: the session redials at once (a reply is pending),
+    the replayed request is deduplicated, and the reply finds the
+    pending wait over the new socket."""
+    srv, srv_msgr, sc = held
+    pending = sc.submit(MPing(from_osd=1, stamp=7.0))
+    assert wait_for(lambda: srv.received == [7.0], 5.0)
+    old_conn = sc._conn
+    for conn in list(srv_msgr._conns):
+        conn.close()
+    assert wait_for(lambda: old_conn.is_closed, 5.0)
+    assert wait_for(
+        lambda: sc._conn is not old_conn and not sc._conn.is_closed, 5.0
+    )
+    srv.release()
+    assert pending.wait(10.0).stamp == 7.0
+    assert srv.received == [7.0]
+    assert sc._pending == {}
 
 
 @pytest.fixture(scope="module")

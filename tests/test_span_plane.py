@@ -283,7 +283,7 @@ def test_a_profiler_session_holds_the_entered_spans(tmp_path):
 WRITE_SPANS = {
     "client_aio_wait", "client_op", "msgr_send", "msgr_recv",
     "osd_queue_wait", "osd_op", "ec_prepare", "ec_encode", "txn_build",
-    "store_commit", "sub_op_wait", "rep_op",
+    "store_commit", "sub_op_wait", "sub_op_rtt", "rep_op",
 }
 
 
@@ -412,6 +412,7 @@ def test_a_served_ec_write_leaves_every_span_under_one_trace(ec_cluster):
     events = [e["event"] for e in wait["events"]]
     assert sum(e.startswith("sub_op_sent") for e in events) == 2
     assert sum(e.startswith("sub_op_commit_rec") for e in events) == 2
+    _fan_out_then_wait(wait, by_name["sub_op_rtt"], osd)
     # the device stages are the encode's children, the encode the op's
     enc = by_name["ec_encode"][0]
     assert enc["parent_id"] == op["span_id"]
@@ -428,6 +429,92 @@ def test_a_served_ec_write_leaves_every_span_under_one_trace(ec_cluster):
     # one tree, the client's span its root beside the aio wait
     roots = tracing.assemble_tree(mine)
     assert sorted(n["name"] for n in roots) == ["client_aio_wait", "client_op"]
+
+
+def _fan_out_then_wait(wait: dict, rtts: list[dict], osd: str) -> None:
+    """Every ``sub_op_sent`` of a ``sub_op_wait`` span comes before its
+    first ``sub_op_commit_rec``, and each peer has one ``sub_op_rtt``
+    of the primary's that began at its send and ended inside the
+    wait."""
+    events = [e["event"] for e in wait["events"]]
+    peers = wait["tags"]["peers"]
+    assert all(e.startswith("sub_op_sent") for e in events[:peers]), events
+    assert all(e.startswith("sub_op_commit_rec") for e in events[peers:]), events
+    sent = {e.split()[1] for e in events[:peers]}
+    assert len(sent) == peers and sent == {e.split()[1] for e in events[peers:]}
+    assert {f"osd.{s['tags']['osd']}" for s in rtts} == sent
+    assert len(rtts) == peers
+    for s in rtts:
+        assert s["daemon"] == osd and s["tags"]["ok"] is True
+        assert wait["start"] - 1e-6 <= s["start"] and s["end"] <= wait["end"] + 1e-6
+    # the last reply closes the wait: the longest round trip is all
+    # but the whole span, and overlapped ones sum to more than it
+    assert max(s["duration"] for s in rtts) <= wait["duration"] + 1e-6
+    assert sum(s["duration"] for s in rtts) >= 0.9 * wait["duration"]
+
+
+@pytest.fixture(scope="module")
+def wide_cluster():
+    """Six OSDs: a k=4 m=2 pool (five peers a write) beside a
+    replicated size-3 one (two), one PG each."""
+    from test_ec_daemon import ECCluster
+
+    c = ECCluster(6)
+    try:
+        c.create_ec_pool(
+            "wide-ec", ["k=4", "m=2", "plugin=jerasure"], pg_num=1
+        )
+        c.rados.pool_create("wide-rep", pg_num=1, size=3)
+        for pool in ("wide-ec", "wide-rep"):
+            c.rados.open_ioctx(pool).write_full("warm", b"w" * 4096)
+        yield c
+    finally:
+        c.shutdown()
+
+
+@pytest.mark.parametrize(
+    "pool, peers", [("wide-ec", 5), ("wide-rep", 2)],
+    ids=["ec_k4m2", "replicated_size3"],
+)
+def test_every_sub_op_is_sent_before_the_first_wait(wide_cluster, pool, peers):
+    """``_commit_and_replicate`` issues every ``MOSDRepOp`` and then
+    waits (issue_repop): the order of the span's events, one
+    ``sub_op_rtt`` a peer under the op's trace id, and the stage
+    counter the benchmark's ``osd_subop_overlap`` reads."""
+    c = wide_cluster
+    io = c.rados.open_ioctx(pool)
+    _drain_all(c, c.rados)
+    before = _stage("sub_op_rtt"), _stage("sub_op_wait")
+    payload = bytes(range(256)) * 64
+    io.aio_write_full("fan-out", payload).result(30)
+    assert io.read("fan-out") == payload
+
+    def finished():
+        spans = []
+        for osd in c.osds.values():
+            spans += osd.tracer.dump_traces()["spans"]
+        ops = [
+            s for s in spans
+            if s["name"] == "osd_op" and s["tags"].get("oid") == "fan-out"
+            and s["tags"].get("created") is not None
+        ]
+        return ops and [s for s in spans if s["trace_id"] == ops[0]["trace_id"]]
+
+    deadline = time.monotonic() + 10
+    while not (mine := finished()):
+        assert time.monotonic() < deadline
+        time.sleep(0.02)
+    (wait,) = [s for s in mine if s["name"] == "sub_op_wait"]
+    assert wait["tags"]["peers"] == peers
+    rtts = [s for s in mine if s["name"] == "sub_op_rtt"]
+    _fan_out_then_wait(wait, rtts, wait["daemon"])
+    assert len([s for s in mine if s["name"] == "rep_op"]) == peers
+    rtt, waited = (
+        tuple(a - b for a, b in zip(_stage(name), was))
+        for name, was in zip(("sub_op_rtt", "sub_op_wait"), before)
+    )
+    assert rtt[0] == peers and waited[0] == 1
+    assert rtt[1] == pytest.approx(sum(s["duration"] for s in rtts) * 1e9, abs=20)
 
 
 def test_coalesced_writes_share_one_ec_encode_with_the_device_stages(ec_cluster):
