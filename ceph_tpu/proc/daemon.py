@@ -87,7 +87,9 @@ def _boot_osd(spec: ClusterSpec, idx: int):
     if not spec.data["memstore"]:
         from ..store import BlockStore
 
-        store = BlockStore(spec.dir / f"osd.{idx}", sync=False)
+        store = BlockStore(
+            spec.dir / f"osd.{idx}", sync=bool(spec.data.get("sync"))
+        )
     osd = OSD(
         idx,
         store=store,

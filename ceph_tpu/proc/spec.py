@@ -13,6 +13,7 @@ Grammar (all keys present after ``plan()``)::
       "rgw":       0,
       "memstore":  false,                # RAM stores (no persistence)
       "wal":       false,                # WAL-front each OSD store
+      "sync":      false,                # OSD BlockStores fsync each commit
       "mon_addrs": [["127.0.0.1", 6789], ...],   # one per mon rank
       "rgw_ports": [8000, ...],          # one per rgw instance
       "pool_size": 2,                    # replica count for pools
@@ -63,6 +64,7 @@ class ClusterSpec:
         rgw: int = 0,
         memstore: bool = False,
         wal: bool = False,
+        sync: bool = False,
         mon_port: int = 0,
         rgw_port: int = 0,
     ) -> "ClusterSpec":
@@ -93,6 +95,7 @@ class ClusterSpec:
                 "rgw": int(rgw),
                 "memstore": bool(memstore),
                 "wal": bool(wal),
+                "sync": bool(sync),
                 "mon_addrs": [["127.0.0.1", p] for p in mon_ports],
                 "rgw_ports": rgw_ports,
                 "pool_size": min(3, max(1, int(osds))),

@@ -53,6 +53,7 @@ from ..common.encoding import Decoder, DecodeError, Encoder
 from ..native import ceph_crc32c
 from .framed_log import (
     append_frame,
+    fsync,
     replay_frames,
     truncate_tail,
     write_checkpoint,
@@ -183,13 +184,13 @@ class _KVLog:
         self._wal.close()
         self._wal = open(self.path / _KV_WAL, "wb")
         if self.sync:
-            os.fsync(self._wal.fileno())
+            fsync(self._wal)
 
     def close(self) -> None:
         if not self._wal.closed:
             self._wal.flush()
             if self.sync:
-                os.fsync(self._wal.fileno())
+                fsync(self._wal)
             self._wal.close()
 
 
@@ -400,7 +401,7 @@ class BlockStore(ObjectStore):
                 if st.dev_writes:
                     self._dev.flush()
                     if self.sync:
-                        os.fsync(self._dev.fileno())
+                        fsync(self._dev)
                 # ... then metadata; a crash in between leaves only
                 # unreferenced bytes in free space
                 sets: dict[str, bytes] = {}
@@ -698,7 +699,7 @@ class BlockStore(ObjectStore):
             if not self._dev.closed:
                 self._dev.flush()
                 if self.sync:
-                    os.fsync(self._dev.fileno())
+                    fsync(self._dev)
                 self._dev.close()
 
     def fsck(self) -> list[str]:

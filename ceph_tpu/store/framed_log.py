@@ -8,8 +8,18 @@ from __future__ import annotations
 import os
 import pathlib
 
+from ..common import tracing
 from ..native import ceph_crc32c
 from .objectstore import StoreError
+
+
+def fsync(f) -> None:
+    """``os.fsync`` of a store's file under a ``store_fsync`` stage
+    span: every barrier a persistent store pays for a commit counts
+    into ``l_stage_store_fsync_{count,ns}``, whichever thread pays it
+    (the WAL's drain thread has no ambient span)."""
+    with tracing.stage("store_fsync"):
+        os.fsync(f.fileno())
 
 
 def frame(body: bytes) -> bytes:
@@ -31,7 +41,7 @@ def append_frame(f, body: bytes, sync: bool) -> None:
         f.write(frame(body))
         f.flush()
         if sync:
-            os.fsync(f.fileno())
+            fsync(f)
     except OSError as e:
         try:
             f.truncate(start)

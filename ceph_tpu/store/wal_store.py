@@ -42,6 +42,34 @@ Crash model: SIGKILL.  Completed file writes survive the process (the
 page cache outlives it); replay tolerates a torn tail (framed_log)
 and batch-verifies every record's payload crc on the device crc32c
 kernels (ops/scrub_kernels.py) before re-applying.
+
+The durability invariant, which a deployment that states "each commit
+fsynced before its ack" (``sync=True`` here AND on the inner store:
+the cluster spec's ``sync`` key) is held to:
+
+- **no ack before the record's WAL barrier has fsynced**
+  (``_commit_batch`` sets ``synced_ev`` only after ``os.fsync`` of the
+  log returned);
+- **no WAL truncation** (``_maybe_checkpoint``, and through it
+  ``compact``; ``close`` truncates nothing) **before every applied
+  record's bytes in the inner store's block file and KV log are
+  fsynced**.  The log is truncated on the stated assumption that the
+  inner store persists its own applies, and only an inner opened
+  ``sync=True`` does so past the page cache: BlockStore then fsyncs
+  the block file and the KV frame inside every ``queue_transaction``,
+  before ``_apply_one`` returns, so by the time ``_pending`` is empty
+  nothing is left to sync.  Over an inner opened ``sync=False`` (the
+  dev-cluster default) a checkpoint drops the only fsynced copy of a
+  shard: a process loss does not show it, a host loss would
+  (tests/test_durable_sync.py shows both sides).
+
+Four stage spans (``common/tracing.stage``: they count from the
+writer and drain threads, which have no ambient span) say where a
+commit's time goes: ``wal_barrier`` round a group's appends and its
+fsync, ``wal_apply`` round the inner ``queue_transaction``,
+``wal_checkpoint`` round a checkpoint that happens, and the inner
+BlockStore's ``store_fsync`` round each of its fsyncs
+(docs/OBSERVABILITY.md "Reading a slow commit").
 """
 
 from __future__ import annotations
@@ -52,6 +80,7 @@ import pathlib
 import threading
 import time
 
+from ..common import tracing
 from ..common.encoding import Decoder, DecodeError, Encoder
 from ..common.perf_counters import PerfCounters, PerfCountersBuilder
 from ..native import ceph_crc32c
@@ -391,23 +420,24 @@ class WALStore(ObjectStore):
 
     def _commit_batch(self, batch: list[_Pending]) -> None:
         ok: list[_Pending] = []
-        for rec in batch:
-            e = Encoder()
-            encode_wal_record(e, make_wal_record(rec.seq, rec.payload))
-            try:
-                # per-record append without fsync; one barrier below
-                append_frame(self._wal, e.getvalue(), sync=False)
-                self._wal_bytes += 8 + len(e.getvalue())
-                ok.append(rec)
-            except StoreError as err:
-                self._fail_record(rec, str(err))
-        if ok and self.sync:
-            try:
-                os.fsync(self._wal.fileno())
-            except OSError as err:
-                for rec in ok:
-                    self._fail_record(rec, f"wal fsync failed: {err}")
-                ok = []
+        with tracing.stage("wal_barrier"):
+            for rec in batch:
+                e = Encoder()
+                encode_wal_record(e, make_wal_record(rec.seq, rec.payload))
+                try:
+                    # per-record append without fsync; one barrier below
+                    append_frame(self._wal, e.getvalue(), sync=False)
+                    self._wal_bytes += 8 + len(e.getvalue())
+                    ok.append(rec)
+                except StoreError as err:
+                    self._fail_record(rec, str(err))
+            if ok and self.sync:
+                try:
+                    os.fsync(self._wal.fileno())
+                except OSError as err:
+                    for rec in ok:
+                        self._fail_record(rec, f"wal fsync failed: {err}")
+                    ok = []
         if not ok:
             return
         self.wal_perf.inc("l_os_wal_barriers")
@@ -486,7 +516,8 @@ class WALStore(ObjectStore):
             rec.seq.to_bytes(8, "little"),
         )
         try:
-            self.inner.queue_transaction(inner_txn)
+            with tracing.stage("wal_apply"):
+                self.inner.queue_transaction(inner_txn)
             self.wal_perf.inc("l_os_wal_applies")
         except StoreError as err:
             # validated at commit; an inner rejection here means the
@@ -518,23 +549,26 @@ class WALStore(ObjectStore):
             if self._pending or self._wal_bytes < self.checkpoint_bytes:
                 return
             # every record in the log is applied and the inner store
-            # persists its own applies: compact the inner (bounds ITS
-            # log too), checkpoint the replay base, start a fresh WAL
-            self.inner.compact()
-            base = self._next_seq - 1
-            e = Encoder()
-            encode_wal_checkpoint(e, WALCheckpoint(base))
-            body = e.getvalue()
-            write_checkpoint(
-                self.path / _CKPT,
-                body + ceph_crc32c(0, body).to_bytes(4, "little"),
-            )
-            self._wal.close()
-            self._wal = open(self.path / _WAL, "wb")
-            if self.sync:
-                os.fsync(self._wal.fileno())
-            self._wal_bytes = 0
-            self.wal_perf.inc("l_os_wal_checkpoints")
+            # persists its own applies (past the page cache only where
+            # it was opened sync=True: the header's invariant): compact
+            # the inner (bounds ITS log too), checkpoint the replay
+            # base, start a fresh WAL
+            with tracing.stage("wal_checkpoint"):
+                self.inner.compact()
+                base = self._next_seq - 1
+                e = Encoder()
+                encode_wal_checkpoint(e, WALCheckpoint(base))
+                body = e.getvalue()
+                write_checkpoint(
+                    self.path / _CKPT,
+                    body + ceph_crc32c(0, body).to_bytes(4, "little"),
+                )
+                self._wal.close()
+                self._wal = open(self.path / _WAL, "wb")
+                if self.sync:
+                    os.fsync(self._wal.fileno())
+                self._wal_bytes = 0
+                self.wal_perf.inc("l_os_wal_checkpoints")
 
     # -- mount / replay -----------------------------------------------------
     def _mount(self) -> int:

@@ -188,7 +188,14 @@ class Cluster:
             return None  # the OSD defaults to MemStore
         from ..store import BlockStore
 
-        return BlockStore(self.dir / f"osd.{i}", sync=False)
+        # ``sync``: the block file and its KV log fsynced inside every
+        # commit (a deployment whose acks mean stable storage; the
+        # WAL in front fsyncs its barriers either way, and truncates
+        # its log on the word that the store below persisted the
+        # applies).  Absent, the dev cluster's pace: the page cache.
+        return BlockStore(
+            self.dir / f"osd.{i}", sync=bool(self.spec.get("sync"))
+        )
 
     def wait_healthy(self, timeout: float = 30.0) -> bool:
         from ..rados import Rados

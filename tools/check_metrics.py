@@ -126,8 +126,10 @@ DISPATCH_COUNTERS = (
 # tracing-plane stage counters (common/tracing.py Tracer._complete →
 # ops/kernel_stats.py record_stage): every finished span of one of
 # these names — the served write from the client's aio queue to the
-# shard commit, the remap's stages, and the EC seam's host copies
-# round a plugin call — feeds
+# shard commit, the remap's stages, the EC seam's host copies round a
+# plugin call, and a durable store's commit below store_commit (the
+# WAL's barrier, apply and checkpoint, the block store's fsyncs) —
+# feeds
 # l_stage_<name>_{count,ns} (and _self_ns for a kernel_stats
 # SELF_TIME_STAGES name) that the benchmark's per-layer readers and
 # /metrics read
@@ -139,6 +141,7 @@ STAGE_SPANS = (
     "remap", "crush_inputs", "crush_fallback", "fixup_exists",
     "fixup_upmap", "fixup_up", "fixup_affinity", "fixup_temp",
     "ec_fold", "ec_unfold", "ec_assemble", "ec_plan",
+    "wal_barrier", "wal_apply", "wal_checkpoint", "store_fsync",
 )
 # sharded bucket-index + reshard families the RGW schema must
 # declare (rgw/index.py build_rgw_perf — the bench rgw_index section
