@@ -26,7 +26,9 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import math
+import time
 from dataclasses import dataclass
 
 import jax
@@ -1974,6 +1976,69 @@ def _batched_range(
     return fn
 
 
+def oracle_fallback(
+    cm: CompiledMap, ruleno: int, result_max: int, weights=None
+):
+    """``finish(xs, res, counts, ok) -> (results, counts)`` for one
+    remap's parts: re-maps the lanes whose speculative retry window
+    overflowed (ok == False) through the exact host oracle and returns
+    finalized numpy.  No-op (and no copy) on a part whose every lane is
+    ok — the common case for any realistically-sized map.  What is fixed
+    a remap (the mutation check, the weight list) is made once, at the
+    first part that holds a bad lane.  Accepts the packed int16 wire
+    form (see _batched_range) and unpacks it."""
+
+    @functools.cache
+    def weight_list():
+        if getattr(cm.source, "mutation", 0) != cm.source_mutation:
+            raise RuntimeError(
+                "CrushMap mutated since compile_map(): the oracle "
+                "fallback would mix old-snapshot kernel results with "
+                "new-map lanes — recompile the map first"
+            )
+        if weights is None:
+            return [0x10000] * max(cm.max_devices, 1)
+        return np.asarray(weights).tolist()
+
+    def finish(xs, res, counts, ok):
+        with tracing.span("crush_fallback") as span:
+            res = np.asarray(res)
+            counts = np.asarray(counts)
+            if res.dtype == np.int16:
+                res32 = res.astype(np.int32)
+                res32[res == -32768] = CRUSH_ITEM_NONE
+                res = res32
+                counts = counts.astype(np.int32)
+            ok = np.asarray(ok)
+            bad = () if ok.all() else np.nonzero(~ok)[0]
+            span.set_tag("lanes", len(bad))
+            if len(bad):
+                from ..ops.kernel_stats import kernel_stats
+
+                ks = kernel_stats()
+                ks.perf.inc(
+                    ks.counter(
+                        "crush", "fallback_lanes",
+                        desc="lanes re-mapped by the host oracle",
+                    ),
+                    len(bad),
+                )
+                wl = weight_list()
+                res = res.copy()
+                counts = counts.copy()
+                xs = np.asarray(xs)
+                for i in bad:
+                    row = cm.source.do_rule(
+                        ruleno, int(xs[i]), result_max, wl
+                    )
+                    res[i, :] = CRUSH_ITEM_NONE
+                    res[i, : len(row)] = row
+                    counts[i] = len(row)
+            return res, counts
+
+    return finish
+
+
 def apply_oracle_fallback(
     cm: CompiledMap,
     ruleno: int,
@@ -1984,54 +2049,11 @@ def apply_oracle_fallback(
     result_max: int,
     weights=None,
 ):
-    """Re-map the lanes whose speculative retry window overflowed
-    (ok == False) through the exact host oracle; returns finalized
-    numpy (results, counts).  No-op (and no copy) when every lane is
-    ok — the common case for any realistically-sized map.  Accepts
-    the packed int16 wire form (see _batched_range) and unpacks it."""
-    with tracing.span("crush_fallback") as span:
-        res = np.asarray(res)
-        counts = np.asarray(counts)
-        if res.dtype == np.int16:
-            res32 = res.astype(np.int32)
-            res32[res == -32768] = CRUSH_ITEM_NONE
-            res = res32
-            counts = counts.astype(np.int32)
-        bad = np.nonzero(~np.asarray(ok))[0]
-        span.set_tag("lanes", int(bad.size))
-        if bad.size:
-            from ..ops.kernel_stats import kernel_stats
-
-            ks = kernel_stats()
-            ks.perf.inc(
-                ks.counter(
-                    "crush", "fallback_lanes",
-                    desc="lanes re-mapped by the host oracle",
-                ),
-                int(bad.size),
-            )
-            if getattr(cm.source, "mutation", 0) != cm.source_mutation:
-                raise RuntimeError(
-                    "CrushMap mutated since compile_map(): the oracle "
-                    "fallback would mix old-snapshot kernel results with "
-                    "new-map lanes — recompile the map first"
-                )
-            if weights is None:
-                weights = np.full(
-                    max(cm.max_devices, 1), 0x10000, np.int32
-                )
-            wl = [int(w) for w in np.asarray(weights)]
-            res = res.copy()
-            counts = counts.copy()
-            xs = np.asarray(xs)
-            for i in bad:
-                row = cm.source.do_rule(
-                    ruleno, int(xs[i]), result_max, wl
-                )
-                res[i, :] = CRUSH_ITEM_NONE
-                res[i, : len(row)] = row
-                counts[i] = len(row)
-        return res, counts
+    """:func:`oracle_fallback` for a result that is finished in one
+    piece: returns finalized numpy (results, counts)."""
+    return oracle_fallback(cm, ruleno, result_max, weights)(
+        xs, res, counts, ok
+    )
 
 
 def _spec_boost_for(weights) -> int:
@@ -2065,62 +2087,164 @@ def batched_rule_call(cm: CompiledMap, ruleno: int, result_max: int,
 # lane count: compiled for a v5e on the BASELINE #5 map (10,000 OSDs,
 # rule 0, 3 replicas) memory_analysis() reports temp_size_in_bytes of
 # 1,653,150,720 at 2^16 lanes (~25 kB a lane; 3.4 GB at 2^17, 7.0 GB at
-# 2^18) next to <1 MB of tables and 1.4 MB of results.  With the two
-# chunks map_chunked keeps in flight that is 3.3 GB of a v5e's 16 GB.
+# 2^18) next to <1 MB of tables and 1.4 MB of results.
 CHUNK_LANES = 1 << 16
 
+# Parts issued beyond the one the host is working on (map_parts).  The
+# device's queue must not run empty while the host finishes its slowest
+# part.  On that map (PERF.md section 6, PR 37, one v5e) a part is 26 ms
+# of device and the host's share of one — its inputs, the fallback
+# scan, the widening, the fix-ups of osd/mapping.py — 19-23 ms, or some
+# 60 where the part holds a lane for the scalar oracle (38-40 ms a
+# lane): more than two parts of device, less than three.  Measured in
+# that cell: 1 ahead 2.05 M mappings/s and the device idle 19.6%, 2
+# ahead 2.19 M / 15.6%, 3 ahead 2.18 M / 15.2%, 4 ahead 2.22 M / 13.9%
+# (runs 2% apart of themselves).  Three, with the part in hand, is
+# four programs' scratch at most: 6.6 GB of a v5e's 16 GB.
+PARTS_AHEAD = 3
 
-def _no_stage(_name: str):
-    return contextlib.nullcontext()
 
-
-def map_chunked(dispatch, xs, chunk: int | None = None):
-    """Run ``dispatch(xs_part) -> (res, counts, ok)`` (non-blocking
-    device arrays, at least ``len(xs_part)`` rows each) over ``xs`` in
-    parts of at most ``chunk`` lanes (default CHUNK_LANES) and return
-    the three results as numpy, ``len(xs)`` rows each.  A batch larger
-    than ``chunk`` is cut into parts of exactly ``chunk`` lanes — the
-    tail repeats its last input and the extra rows are dropped — so one
-    compiled program is replayed; at most two parts are in flight (the
-    next one computes while the previous one is fetched)."""
-    chunk = chunk or CHUNK_LANES
-    n = len(xs)
-    # each part is issued and fetched under the flight recorder's
-    # compute / sync stage (and so its dev_compute / dev_sync span)
-    # where a dispatch record is open on this thread: the fetch
-    # (np.asarray) is where the host waits for the device
+def _stage(name: str):
+    """The flight recorder's ``compute`` / ``sync`` stage (and so its
+    dev_compute / dev_sync span) of the dispatch record open on this
+    thread now, where there is one."""
     from ..ops.profiler import current_dispatch
 
     record = current_dispatch()
-    stage = record.stage if record is not None else _no_stage
+    if record is None:
+        return contextlib.nullcontext()
+    return record.stage(name)
 
-    def issue(part):
-        with stage("compute"):
-            return dispatch(part)
 
-    def fetch(arrays):
-        with stage("sync"):
-            return [np.asarray(a) for a in arrays]
+def map_parts(dispatch, xs, chunk: int | None = None):
+    """Run ``dispatch(xs_part) -> (res, counts, ok)`` (non-blocking
+    device arrays, at least ``len(xs_part)`` rows each) over ``xs`` in
+    parts of at most ``chunk`` lanes (default CHUNK_LANES) and yield
+    each part ``(lo, res, counts, ok)`` as numpy, its own rows only, as
+    soon as it is fetched — with the parts after it, up to PARTS_AHEAD
+    of them, already issued, so what the caller does with a part runs
+    under their kernels.  A batch larger than ``chunk`` is cut into
+    parts of exactly ``chunk`` lanes (the tail repeats its last input
+    and the extra rows are dropped), so one compiled program is
+    replayed.  A batch of one part has nothing issued ahead.
 
-    if n <= chunk:
-        return tuple(a[:n] for a in fetch(issue(xs)))
-    parts = []
-    prev = None
-    for lo in range(0, n, chunk):
+    Each part is issued and fetched under the compute / sync stage of
+    the dispatch record open on the calling thread at that moment: the
+    fetch (np.asarray) is where the host waits for the device.  The
+    time between a part's hand-over and the caller's return for the
+    next is the host's work on it: ``l_tpu_crush_host_ns``, and
+    ``l_tpu_crush_host_overlapped_ns`` for the share spent with a later
+    part issued and not yet fetched."""
+    from ..ops.kernel_stats import kernel_stats
+
+    chunk = chunk or CHUNK_LANES
+    n = len(xs)
+    ks = kernel_stats()
+    host_ns = ks.counter(
+        "crush", "host_ns",
+        desc="host time on fetched parts of a batch (ns)",
+    )
+    overlapped_ns = ks.counter(
+        "crush", "host_overlapped_ns",
+        desc="host_ns spent with a later part on the device (ns)",
+    )
+    starts = iter(range(0, max(n, 1), chunk))
+    issued = collections.deque()
+
+    def issue_next():
+        lo = next(starts, None)
+        if lo is None:
+            return
         part = xs[lo : lo + chunk]
-        short = chunk - len(part)
+        short = chunk - len(part) if n > chunk else 0
         if short:
             part = np.concatenate(
                 [np.asarray(part), np.full(short, part[-1], part.dtype)]
             )
-        cur = issue(part)
-        if prev is not None:
-            parts.append(fetch(prev))
-        prev = cur
-    parts.append(fetch(prev))
+        with _stage("compute"):
+            issued.append((lo, dispatch(part)))
+
+    def fetch():
+        lo, arrays = issued.popleft()
+        rows = min(chunk, n - lo)
+        with _stage("sync"):
+            return (lo, *(np.asarray(a)[:rows] for a in arrays))
+
+    for _ in range(PARTS_AHEAD):
+        issue_next()
+    while True:
+        issue_next()
+        if not issued:
+            return
+        part = fetch()
+        t0 = time.perf_counter_ns()
+        try:
+            yield part
+        finally:
+            dt = time.perf_counter_ns() - t0
+            ks.perf.inc(host_ns, dt)
+            if issued:
+                ks.perf.inc(overlapped_ns, dt)
+
+
+def join_parts(parts) -> tuple:
+    """The arrays of ``(lo, array, ...)`` parts joined row-wise, in
+    order; a single part's own arrays, uncopied."""
+    parts = list(parts)
+    if len(parts) == 1:
+        return parts[0][1:]
     return tuple(
-        np.concatenate([p[i] for p in parts])[:n] for i in range(3)
+        np.concatenate([p[i] for p in parts])
+        for i in range(1, len(parts[0]))
     )
+
+
+def map_chunked(dispatch, xs, chunk: int | None = None):
+    """:func:`map_parts` joined: the three results as numpy,
+    ``len(xs)`` rows each."""
+    return join_parts(map_parts(dispatch, xs, chunk))
+
+
+def rule_parts(
+    cm: CompiledMap,
+    ruleno: int,
+    xs,
+    result_max: int,
+    weights=None,
+    place=None,
+    chunk: int | None = None,
+):
+    """``batch_do_rule`` a part at a time: sets the kernel up now (an
+    UnsupportedMap is raised here, before anything is issued) and
+    returns a generator of finished parts ``(lo, results, counts)`` —
+    :func:`map_parts` with each part's fallback lanes re-mapped as it
+    is handed out, under the kernels of the parts after it.  ``place``
+    puts a part's inputs on the device(s) (default ``jnp.asarray``;
+    the mesh path shards them) and ``chunk`` is the lanes a part.
+    ``xs`` is only ever sliced a part at a time (``xs[lo:hi]``, as the
+    part is issued and again as it is finished) and asked its length,
+    so it may work its values out as they are wanted."""
+    if weights is None:
+        weights = np.full(max(cm.max_devices, 1), 0x10000, np.int32)
+    # by way of numpy: jnp.asarray walks a list an element at a time
+    wv = jnp.asarray(np.asarray(weights, dtype=np.int32))
+    fn, tables = batched_rule_call(cm, ruleno, result_max, weights)
+    place = place or jnp.asarray
+    finish = oracle_fallback(cm, ruleno, result_max, weights)
+
+    def dispatch(part):
+        return fn(place(np.asarray(part, dtype=np.int32)), wv, *tables)
+
+    def parts():
+        raw = map_parts(dispatch, xs, chunk)
+        with contextlib.closing(raw):
+            for lo, res, counts, ok in raw:
+                yield (
+                    lo,
+                    *finish(xs[lo : lo + len(ok)], res, counts, ok),
+                )
+
+    return parts()
 
 
 def batch_do_rule(

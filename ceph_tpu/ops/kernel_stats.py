@@ -32,8 +32,12 @@ function), ``gf_matmul`` (ops/ec_backend.py: a batched dispatch feeds
 it from its flight-recorder entry's commit — ops/profiler.py
 ``dispatch(group=)`` — and the per-call region math through
 ``timed``), ``gf_bitmatrix`` (the same region math), ``crush``
-(osd/mapping.py batched PG mapping, where bytes_in counts PGs mapped
-via the extra ``l_tpu_crush_pgs`` counter).
+(osd/mapping.py batched PG mapping, one ``timed`` a part of a pool,
+with the extra counters ``l_tpu_crush_pgs`` (PGs mapped),
+``l_tpu_crush_fallback_lanes`` (lanes the host oracle re-mapped) and,
+from crush/jaxmap.py ``map_parts``, ``l_tpu_crush_host_ns`` /
+``l_tpu_crush_host_overlapped_ns``: the host's time on the parts it
+was handed, and the share of it spent with a later part on the device).
 
 The set is a normal PerfCounters: daemons register it on their admin
 socket collection (``perf dump``) and merge its dump into their

@@ -51,6 +51,56 @@ def _compact_rows(osds: np.ndarray, valid: np.ndarray) -> np.ndarray:
     return np.where(keep, packed, _NONE)
 
 
+class _PoolPps:
+    """``pool_pps_vec`` of a pool's PGs, worked out as far as it has
+    been asked for (``pps[lo:hi]``, nothing else).  The pipeline asks
+    for a part as it issues it (jaxmap.map_parts), so every part's
+    inputs but the first few are computed under the kernels of the
+    parts before — a ``crush_inputs`` span each time — and a part of
+    them stays in the cache."""
+
+    def __init__(self, pool: PgPool, ps: np.ndarray):
+        self._pool = pool
+        self._ps = ps
+        self._pps = np.empty(len(ps), dtype=np.int64)
+        self._done = 0
+
+    def __len__(self) -> int:
+        return len(self._ps)
+
+    def __getitem__(self, rows: slice) -> np.ndarray:
+        stop = min(rows.stop, len(self._ps))
+        if stop > self._done:
+            todo = slice(self._done, stop)
+            with tracing.span(
+                "crush_inputs", tags={"pool": self._pool.pool_id}
+            ):
+                self._pps[todo] = pool_pps_vec(self._pool, self._ps[todo])
+            self._done = stop
+        return self._pps[rows]
+
+
+class _OsdTables:
+    """What the fix-ups look up by OSD id, as arrays built once a pool:
+    ``exists``, ``up`` (exists and up) and ``affinity`` (None where the
+    map sets none), each with one slot past ``max_osd`` — where ids out
+    of range are clipped to — that reads False / 0."""
+
+    def __init__(self, osdmap: OSDMap):
+        self.exists = np.zeros(osdmap.max_osd + 1, dtype=bool)
+        self.up = np.zeros(osdmap.max_osd + 1, dtype=bool)
+        self.exists[:-1] = np.asarray(osdmap.osd_exists, dtype=bool)
+        self.up[:-1] = self.exists[:-1] & np.asarray(
+            osdmap.osd_up, dtype=bool
+        )
+        self.affinity = None
+        if osdmap.osd_primary_affinity is not None:
+            self.affinity = np.zeros(osdmap.max_osd + 1, dtype=np.int64)
+            self.affinity[:-1] = np.asarray(
+                osdmap.osd_primary_affinity, dtype=np.int64
+            )
+
+
 def _build_perf():
     from ..common import PerfCountersBuilder
 
@@ -82,6 +132,8 @@ class OSDMapMapping:
         self.epoch = 0
         self.perf = _build_perf()
         self.tracer = tracer or tracing.Tracer("mapping", buffered=False)
+        # the pool's parts off the device while ``_update_pool`` runs
+        self._parts = None
 
     # -- batch pipeline ----------------------------------------------------
     def update(self, osdmap: OSDMap, use_device: bool = True) -> None:
@@ -98,47 +150,74 @@ class OSDMapMapping:
     def _update_pool(
         self, osdmap: OSDMap, pool: PgPool, use_device: bool
     ) -> None:
+        """The pool's four tables, a part at a time: a part's raw rows
+        come from one ``_crush_stage`` call and are fixed up and written
+        into their rows while the device maps the parts after it
+        (``_device_parts``).  Every stage is row-local, so the parts in
+        order give the bytes of the whole; a pool of one part, or one
+        the host maps, is one call of each stage."""
         from ..ops.kernel_stats import kernel_stats
 
         n = pool.pg_num
-        size = pool.size
-        with tracing.span("crush_inputs", tags={"pool": pool.pool_id}):
-            ps = np.arange(n, dtype=np.int64)
-            pps = pool_pps_vec(pool, ps).astype(np.int64)
+        ps = np.arange(n, dtype=np.int64)
+        pps = _PoolPps(pool, ps)
+        up = np.empty((n, pool.size), dtype=np.int64)
+        acting = np.empty_like(up)
+        up_primary = np.empty(n, dtype=np.int64)
+        acting_primary = np.empty_like(up_primary)
 
         ks = kernel_stats()
         pgs_counter = ks.counter(
             "crush", "pgs", desc="PGs mapped through the CRUSH kernel"
         )
-        with self.perf.time_it("crush_stage"), ks.timed(
-            "crush", bytes_in=pps.nbytes
-        ) as kt:
-            raw = self._crush_stage(osdmap, pool, pps, use_device)
-            kt.bytes_out = raw.nbytes
-        ks.perf.inc(pgs_counter, n)
-
-        with self.perf.time_it("fixup_stages"):
-            up, up_primary, acting, acting_primary = self._fixup(
-                osdmap, pool, ps, pps, raw
-            )
+        osds = _OsdTables(osdmap)
+        # what ``_crush_stage`` draws from for the length of this call
+        self._parts, step = (
+            self._device_parts(osdmap, pool, pps) if use_device
+            else (None, n)
+        )
+        on_device = self._parts is not None
+        try:
+            for lo in range(0, n, step or 1):
+                rows = slice(lo, lo + step)
+                part_pps = pps[rows]
+                with self.perf.time_it("crush_stage"), ks.timed(
+                    "crush", bytes_in=part_pps.nbytes
+                ) as kt:
+                    raw = self._crush_stage(
+                        osdmap, pool, part_pps, on_device
+                    )
+                    kt.bytes_out = raw.nbytes
+                ks.perf.inc(pgs_counter, len(raw))
+                with self.perf.time_it("fixup_stages"):
+                    part_up, part_primary = self._fixup(
+                        osdmap, pool, osds, ps[rows], part_pps, raw
+                    )
+                    with tracing.span("fixup_temp"):
+                        up[rows] = acting[rows] = part_up
+                        up_primary[rows] = part_primary
+                        acting_primary[rows] = part_primary
+                        self._temp_stage(
+                            osdmap, pool, lo,
+                            acting[rows], acting_primary[rows],
+                        )
+        finally:
+            if on_device:
+                self._parts.close()
+            self._parts = None
         self.up[pool.pool_id] = up
         self.up_primary[pool.pool_id] = up_primary
         self.acting[pool.pool_id] = acting
         self.acting_primary[pool.pool_id] = acting_primary
 
-    def _fixup(self, osdmap, pool, ps, pps, raw):
+    def _fixup(self, osdmap, pool, osds, ps, pps, raw):
+        """(up, up_primary) of the rows ``raw`` holds."""
         with tracing.span("fixup_exists"):
             # _remove_nonexistent_osds + _raw_to_up_osds, fused: both
             # drop to NONE (EC) or compact (replicated)
-            exists = np.zeros(osdmap.max_osd + 1, dtype=bool)
-            up_ok = np.zeros(osdmap.max_osd + 1, dtype=bool)
-            exists[:-1] = np.asarray(osdmap.osd_exists, dtype=bool)
-            up_ok[:-1] = exists[:-1] & np.asarray(
-                osdmap.osd_up, dtype=bool
-            )
             idx = np.clip(raw, 0, osdmap.max_osd)
             in_range = (raw >= 0) & (raw < osdmap.max_osd)
-            raw_exists = in_range & exists[idx]
+            raw_exists = in_range & osds.exists[idx]
             if pool.can_shift_osds():
                 raw = _compact_rows(raw, raw_exists)
             else:
@@ -150,7 +229,7 @@ class OSDMapMapping:
         with tracing.span("fixup_up"):
             idx = np.clip(raw, 0, osdmap.max_osd)
             in_range = (raw >= 0) & (raw < osdmap.max_osd)
-            alive = in_range & up_ok[idx]
+            alive = in_range & osds.up[idx]
             if pool.can_shift_osds():
                 up = _compact_rows(raw, alive)
             else:
@@ -159,78 +238,91 @@ class OSDMapMapping:
         with tracing.span("fixup_affinity"):
             up_primary = self._primary_vec(up)
             up, up_primary = self._affinity_stage(
-                osdmap, pool, pps, up, up_primary
+                osdmap, pool, osds, pps, up, up_primary
             )
+        return up, up_primary
 
-        with tracing.span("fixup_temp"):
-            acting = up.copy()
-            acting_primary = up_primary.copy()
-            self._temp_stage(osdmap, pool, acting, acting_primary)
+    def _device_parts(self, osdmap: OSDMap, pool: PgPool, pps):
+        """(the pool's raw mappings off the device as a generator of
+        ``(lo, results, counts)`` parts, the rows a part) — or (None, all
+        the rows) where the map is outside the kernel's scope and the
+        host maps the pool.  Nothing is issued until the first part is
+        drawn; from then on the parts after the one in hand are on the
+        device (jaxmap.map_parts)."""
+        from ..crush import jaxmap
+        from ..ops import mesh as meshmod
+        from ..ops.profiler import record_pad
+        from ..ops.residency import bucket_pow2, note_shape
+        from .sharded_mapping import mesh_rule_parts, part_lanes
 
-        return up, up_primary, acting, acting_primary
+        n = len(pps)
+        ruleno = osdmap.crush.find_rule(pool.crush_rule, pool.type, pool.size)
+        if ruleno < 0:
+            return None, n
+        # shards across the device mesh when >1 device exists
+        # (ParallelPGMapper role); single-device unchanged
+        dmesh = meshmod.default_mesh()
+        step = part_lanes(dmesh)
+        # a pool of one part is bucketed to a power of two (padded with
+        # a repeat of lane 0 — a valid input — and the rows sliced
+        # back), a larger one cut into parts of exactly ``step`` lanes,
+        # so pools with ragged pg_num and remap sweeps replay ONE
+        # compiled program per bucket; reuse lands in
+        # l_tpu_compile_cache_{hit,miss}
+        lanes = min(bucket_pow2(n), step)
+        pad = max(lanes - n, 0)
+        pad_bytes = 0
+        if pad:
+            pps = pps[0:n]
+            pps = np.concatenate([pps, np.full(pad, pps[0], dtype=pps.dtype)])
+            pad_bytes = pad * pps.itemsize
+        try:
+            parts = mesh_rule_parts(
+                _compiled(osdmap.crush), ruleno, pps, pool.size,
+                osdmap.osd_weight, dmesh,
+            )
+        except jaxmap.UnsupportedMap:
+            return None, n
+
+        def drawn():
+            # under the first part's dispatch record
+            record_pad(pad_bytes)
+            note_shape("crush_batch", lanes, pool.size)
+            yield from parts
+
+        return drawn(), step
 
     def _crush_stage(
         self, osdmap: OSDMap, pool: PgPool, pps: np.ndarray, use_device: bool
     ) -> np.ndarray:
-        """(npgs, size) raw mappings via the device kernel, oracle
-        fallback outside its scope."""
+        """(len(pps), size) raw mappings of the part of the pool that
+        ``_update_pool`` is at: the next part off the device kernel, or
+        the oracle's where the map is outside its scope."""
         ruleno = osdmap.crush.find_rule(pool.crush_rule, pool.type, pool.size)
         n = len(pps)
         if ruleno < 0:
             return np.full((n, pool.size), _NONE, dtype=np.int64)
-        if use_device:
-            try:
-                from ..crush import jaxmap
-                from ..ops.profiler import dispatch_profiler
-                from ..ops.residency import bucket_pow2, note_shape
-                from .sharded_mapping import mesh_batch_do_rule
-
-                cm = _compiled(osdmap.crush)
-                # an UnsupportedMap raised anywhere in here discards
-                # the flight-recorder entry (no commit on exception —
-                # the oracle loop below records its own)
-                with dispatch_profiler().dispatch(
-                    "crush", backend="jax"
-                ) as dp:
-                    dp.set_ops(1)
-                    dp.set_stripes(n)
-                    dp.add_bytes_in(pps.nbytes)
-                    dp.add_upload(pps.nbytes)
-                    # bucket the PG batch to a power of two (pad with
-                    # a repeat of lane 0 — a valid input — and slice
-                    # the rows back) so pools with ragged pg_num and
-                    # remap sweeps replay ONE compiled program per
-                    # bucket; reuse lands in
-                    # l_tpu_compile_cache_{hit,miss}
-                    nb = bucket_pow2(n)
-                    pps_in = pps
-                    if nb != n:
-                        pps_in = np.concatenate(
-                            [pps, np.full(nb - n, pps[0], dtype=pps.dtype)]
-                        )
-                        dp.add_pad((nb - n) * pps.itemsize)
-                    note_shape("crush_batch", nb, pool.size)
-                    # shards across the device mesh when >1 device
-                    # exists (ParallelPGMapper role); single-device
-                    # unchanged.  The record's compute / sync stages
-                    # are bracketed where the chunks are issued and
-                    # fetched (jaxmap.map_chunked): what comes back
-                    # here is numpy already
-                    res, counts = mesh_batch_do_rule(
-                        cm, ruleno, pps_in, pool.size,
-                        osdmap.osd_weight,
-                    )
-                    raw = np.asarray(res, dtype=np.int64)[:n]
-                    counts = np.asarray(counts)[:n]
-                    # positions beyond the returned count are absent,
-                    # not NONE
-                    cols = np.arange(pool.size)
-                    return np.where(
-                        cols[None, :] < counts[:, None], raw, _NONE
-                    )
-            except jaxmap.UnsupportedMap:
-                pass
         from ..ops.profiler import dispatch_profiler
+
+        if use_device:
+            # one flight-recorder entry a part: its compute / sync
+            # stages are bracketed where a part is issued and fetched
+            # (jaxmap.map_parts) — the issue is of a part further on,
+            # the fetch of this one — and what comes back is numpy
+            with dispatch_profiler().dispatch(
+                "crush", backend="jax"
+            ) as dp:
+                dp.set_ops(1)
+                dp.set_stripes(n)
+                dp.add_bytes_in(pps.nbytes)
+                dp.add_upload(pps.nbytes)
+                _lo, res, counts = next(self._parts)
+                raw = res[:n].astype(np.int64)
+                # positions beyond the returned count are absent,
+                # not NONE
+                cols = np.arange(pool.size)
+                raw[cols[None, :] >= counts[:n, None]] = _NONE
+                return raw
 
         with dispatch_profiler().dispatch(
             "crush", backend="cpu"
@@ -284,13 +376,11 @@ class OSDMapMapping:
         has = valid.any(axis=1)
         return np.where(has, up[np.arange(len(up)), first], -1)
 
-    def _affinity_stage(self, osdmap, pool, pps, up, up_primary):
+    def _affinity_stage(self, osdmap, pool, osds, pps, up, up_primary):
         """Vectorized _apply_primary_affinity (OSDMap.cc:2540-2590)."""
-        aff = osdmap.osd_primary_affinity
-        if aff is None:
+        affv = osds.affinity
+        if affv is None:
             return up, up_primary
-        affv = np.zeros(osdmap.max_osd + 1, dtype=np.int64)
-        affv[:-1] = np.asarray(aff, dtype=np.int64)
         idx = np.clip(up, 0, osdmap.max_osd)
         valid = (up != _NONE) & (up >= 0) & (up < osdmap.max_osd)
         a = np.where(valid, affv[idx], CEPH_OSD_DEFAULT_PRIMARY_AFFINITY)
@@ -329,21 +419,23 @@ class OSDMapMapping:
                 up[row, 0] = new_primary[row]
         return up, new_primary
 
-    def _temp_stage(self, osdmap, pool, acting, acting_primary):
-        """pg_temp / primary_temp sparse overrides (scalar per entry)."""
+    def _temp_stage(self, osdmap, pool, lo, acting, acting_primary):
+        """pg_temp / primary_temp sparse overrides (scalar per entry)
+        on the rows of PGs ``lo`` onwards that the two arrays hold."""
+        hi = lo + len(acting)
         for (pid, seed), temps in osdmap.pg_temp.items():
-            if pid != pool.pool_id or seed >= pool.pg_num:
+            if pid != pool.pool_id or not lo <= seed < hi:
                 continue
             t, tp = osdmap._get_temp_osds(pool, seed)
             if t:
                 row = np.full(acting.shape[1], _NONE, dtype=np.int64)
                 row[: len(t)] = t
-                acting[seed] = row
-                acting_primary[seed] = tp
+                acting[seed - lo] = row
+                acting_primary[seed - lo] = tp
         for (pid, seed), tp in osdmap.primary_temp.items():
-            if pid != pool.pool_id or seed >= pool.pg_num:
+            if pid != pool.pool_id or not lo <= seed < hi:
                 continue
-            acting_primary[seed] = tp
+            acting_primary[seed - lo] = tp
 
     # -- queries (OSDMapMapping consumer API) ------------------------------
     def get(self, pool_id: int, ps: int):

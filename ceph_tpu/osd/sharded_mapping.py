@@ -11,20 +11,66 @@ re-assembles host-side from the gathered shards (ragged PG counts pad
 to a device multiple and slice back), and the same exact-oracle
 fallback sweeps any speculation-overflow lanes afterwards.
 
-``mesh_batch_do_rule`` is the product entry point: OSDMap full remaps
+``mesh_rule_parts`` is the product entry point: OSDMap full remaps
 (osd/mapping.py, so the balancer's dry-runs and osdmaptool inherit it)
-route through it and shard automatically whenever more than one device
-exists; single-device hosts keep the exact existing dispatch.
+draw their parts from it, sharded whenever more than one device exists
+and on the one device otherwise — one pipeline (jaxmap.map_parts)
+either way.  ``mesh_batch_do_rule`` is the same choice for a caller
+that wants the whole table at once.
 """
 
 from __future__ import annotations
 
 import time
 
-import numpy as np
-
 from ..crush import jaxmap
 from ..ops import mesh as meshmod
+
+
+def part_lanes(dmesh: meshmod.DeviceMesh | None) -> int:
+    """Lanes one issue of the CRUSH program maps: every device sees at
+    most CHUNK_LANES lanes per program."""
+    return jaxmap.CHUNK_LANES * (dmesh.n if dmesh is not None else 1)
+
+
+def mesh_rule_parts(
+    cm,
+    ruleno: int,
+    xs,
+    result_max: int,
+    weights=None,
+    dmesh: meshmod.DeviceMesh | None = None,
+):
+    """``jaxmap.rule_parts`` with each part's lanes sharded across
+    ``dmesh`` (None: the one device): set-up now, then a generator of
+    finished parts ``(lo, results, counts)`` of ``part_lanes(dmesh)``
+    rows — THE pipeline of a whole-map remap (osd/mapping.py), on one
+    device or many."""
+    if dmesh is None:
+        return jaxmap.rule_parts(cm, ruleno, xs, result_max, weights)
+    import jax
+
+    spec = dmesh.batch_spec(1)
+
+    def place(part):
+        padded, _ = meshmod.pad_to_devices(part, dmesh.n)
+        return jax.device_put(padded, spec)
+
+    parts = jaxmap.rule_parts(
+        cm, ruleno, xs, result_max, weights, place, part_lanes(dmesh)
+    )
+
+    def counted():
+        # the mesh counters' latency runs from the first issue to the
+        # last part handed back, the caller's work on the parts between
+        t0 = time.perf_counter()
+        yield from parts
+        meshmod.record_shard_dispatch(
+            dmesh, "crush", 4 * len(xs),  # the lanes go up as int32
+            time.perf_counter() - t0,
+        )
+
+    return counted()
 
 
 def sharded_batch_do_rule(
@@ -42,45 +88,16 @@ def sharded_batch_do_rule(
         dmesh = meshmod.default_mesh()
     if dmesh is None:
         return jaxmap.batch_do_rule(cm, ruleno, xs, result_max, weights)
-    import jax
-    import jax.numpy as jnp
-
-    if weights is None:
-        weights = np.full(max(cm.max_devices, 1), 0x10000, np.int32)
-    xs_np = np.asarray(xs, dtype=np.int32)
-    t0 = time.perf_counter()
-    wv = jnp.asarray(weights, dtype=jnp.int32)
-    fn, tables = jaxmap.batched_rule_call(
-        cm, ruleno, result_max, weights
-    )
-    spec = dmesh.batch_spec(1)
-
-    def dispatch(part):
-        padded, _ = meshmod.pad_to_devices(part, dmesh.n)
-        return fn(jax.device_put(padded, spec), wv, *tables)
-
-    # host-side re-assembly: gather every shard, drop the pad lanes;
-    # every device sees at most CHUNK_LANES lanes per program
-    res, counts, ok = jaxmap.map_chunked(
-        dispatch, xs_np, jaxmap.CHUNK_LANES * dmesh.n
-    )
-    meshmod.record_shard_dispatch(
-        dmesh, "crush", xs_np.nbytes, time.perf_counter() - t0
-    )
-    return jaxmap.apply_oracle_fallback(
-        cm, ruleno, xs_np, res, counts, ok, result_max, weights
+    # host-side re-assembly: every shard gathered, the pad lanes dropped
+    return jaxmap.join_parts(
+        mesh_rule_parts(cm, ruleno, xs, result_max, weights, dmesh)
     )
 
 
 def mesh_batch_do_rule(cm, ruleno, xs, result_max, weights=None):
     """Product dispatch: shard across the default mesh when more than
     one device exists, else the single-device path unchanged."""
-    dmesh = meshmod.default_mesh()
-    if dmesh is None:
-        return jaxmap.batch_do_rule(cm, ruleno, xs, result_max, weights)
-    return sharded_batch_do_rule(
-        cm, ruleno, xs, result_max, weights, dmesh
-    )
+    return sharded_batch_do_rule(cm, ruleno, xs, result_max, weights)
 
 
 class ShardedPGMapper:

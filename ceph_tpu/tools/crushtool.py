@@ -156,7 +156,7 @@ def _map_range(m: CrushMap, args, weights, timed: bool = True):
 
         def device_pass():
             # one flight-recorder entry a pass, as OSDMapMapping
-            # records a remap's; map_chunked brackets its stages
+            # records a part of a remap; map_parts brackets its stages
             with dispatch_profiler().dispatch(
                 "crush", backend="jax"
             ) as dp:

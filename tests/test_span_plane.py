@@ -590,7 +590,8 @@ def test_a_remap_leaves_its_stage_spans_and_a_crush_record(monkeypatch):
     from ceph_tpu.ops.profiler import dispatch_profiler
     from ceph_tpu.osd.mapping import OSDMapMapping
 
-    monkeypatch.setattr(jaxmap, "CHUNK_LANES", 1 << 9)  # 4 chunks
+    monkeypatch.setattr(jaxmap, "CHUNK_LANES", 1 << 8)  # 8 parts
+    ahead = jaxmap.PARTS_AHEAD
     osdmap = _small_map()
     tracer = tracing.Tracer("remap-host")
     mapping = OSDMapMapping(tracer=tracer)
@@ -607,43 +608,52 @@ def test_a_remap_leaves_its_stage_spans_and_a_crush_record(monkeypatch):
     assert root["name"] == "remap" and root["tags"]["epoch"] == osdmap.epoch
     names = [c["name"] for c in root["children"]]
     assert list(dict.fromkeys(names)) == REMAP_STAGES
-    # one issue and one fetch a chunk, two chunks in flight: the second
-    # is issued before the first is fetched
-    assert names.count("dev_compute") == names.count("dev_sync") == 4
-    assert names[1:4] == ["dev_compute", "dev_compute", "dev_sync"]
+    # a part's inputs are worked out as it is issued; the part in hand
+    # has ``ahead`` parts issued behind it when it is fetched, and the
+    # host's stages on it come before the next part's fetch
+    issue, host = ["crush_inputs", "dev_compute"], REMAP_STAGES[3:]
+    want = issue * ahead
+    for part in range(8):
+        want += (issue if part + ahead < 8 else []) + ["dev_sync"] + host
+    assert names == want
     by_name = {c["name"]: c for c in root["children"]}
     assert by_name["dev_sync"]["tags"]["kind"] == "crush"
     assert by_name["crush_fallback"]["tags"]["lanes"] == 0
     _children_fit(spans)
     covered = sum(c["duration"] for c in root["children"])
     assert covered >= 0.85 * root["duration"]
-    # the flight recorder's stages are bracketed round each chunk: the
-    # fetches are the record's sync time, the issues its compute time
-    (entry,) = [
+    # the flight recorder's stages are bracketed round each part, one
+    # record a part: the fetches are the records' sync time, the issues
+    # their compute time
+    entries = [
         e for e in dispatch_profiler().history("crush")["entries"]
         if e["seq"] > seq
     ]
+    assert len(entries) == 8
+    assert all(e["backend"] == "jax" and e["stripes"] == 256 for e in entries)
     fetch = sum(
         c["duration"] for c in root["children"] if c["name"] == "dev_sync"
     )
     issue = sum(
         c["duration"] for c in root["children"] if c["name"] == "dev_compute"
     )
-    assert entry["backend"] == "jax" and entry["sync_s"] > entry["compute_s"] > 0
-    assert entry["sync_s"] == pytest.approx(fetch, rel=0.2)
-    assert entry["compute_s"] <= issue  # a span wraps its stage's bracket
-    assert entry["sync_s"] + entry["compute_s"] <= entry["wall_s"]
+    sync_s = sum(e["sync_s"] for e in entries)
+    compute_s = sum(e["compute_s"] for e in entries)
+    assert sync_s > compute_s > 0
+    assert sync_s == pytest.approx(fetch, rel=0.2)
+    assert compute_s <= issue  # a span wraps its stage's bracket
+    assert sync_s + compute_s <= sum(e["wall_s"] for e in entries)
     count, ns, _none = (
         a - b for a, b in zip(_stage("dev_sync"), before["dev_sync"])
     )
-    assert count == 4 and ns == pytest.approx(fetch * 1e9, rel=0.01)
+    assert count == 8 and ns == pytest.approx(fetch * 1e9, rel=0.01)
     assert _stage("remap")[0] - before["remap"][0] == 1
     # a mapping no host serves keeps nothing and counts all the same
     bare = OSDMapMapping()
     bare.update(osdmap)
     assert bare.tracer.dump_traces()["num_spans"] == 0
     assert _stage("remap")[0] - before["remap"][0] == 2
-    assert _stage("fixup_up")[0] - before["fixup_up"][0] == 2
+    assert _stage("fixup_up")[0] - before["fixup_up"][0] == 16
 
 
 def test_osdmaptool_prints_the_stages_of_its_timed_remap(capsys):

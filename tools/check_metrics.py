@@ -1036,7 +1036,8 @@ def product_counter_sets():
     for group in ("ec_encode", "ec_decode", "gf_matmul",
                   "gf_bitmatrix", "crush"):
         ks.record(group)
-    ks.counter("crush", "pgs")
+    for suffix in ("pgs", "fallback_lanes", "host_ns", "host_overlapped_ns"):
+        ks.counter("crush", suffix)
     # residency + coalesced-encode families (ops/residency.py) join
     # the schema walk and the cross-set collision lint
     ensure_counters(ks)
