@@ -149,17 +149,39 @@ class NumpyBackend:
             return outs
 
     def matrix_shards(
-        self, matrix: np.ndarray, shards, w: int, stripes: int
+        self,
+        matrix: np.ndarray,
+        shards,
+        w: int,
+        stripes: int,
+        sub_rows: tuple[int, int] = (1, 1),
     ) -> list[np.ndarray]:
         """One object's reconstruction in shard form (the jax backend
         makes it one device dispatch): the s survivor shards are the
         regions as they are stored, the (r, s) reconstruction rows
-        give the r rebuilt shards."""
-        regions = np.stack([_host_row(s) for s in shards])
+        give the r rebuilt shards.  ``sub_rows`` = (rows in, rows
+        out): a stripe of a payload holds so many rows of the matrix
+        (a fractional repair's sub-chunks), folded to regions here and
+        the result laid back as stored."""
+        rows_in, rows_out = sub_rows
+        payloads = [_host_row(s) for s in shards]
         with self._host_entry(
-            "ec_decode", ops=1, stripes=stripes, bytes_in=regions.nbytes
+            "ec_decode",
+            ops=1,
+            stripes=stripes,
+            bytes_in=sum(p.nbytes for p in payloads),
         ):
-            return list(self.matrix_regions(matrix, regions, w))
+            regions = np.concatenate(
+                [
+                    fold_stripes(p.reshape(stripes, rows_in, -1))
+                    for p in payloads
+                ]
+            )
+            out = self.matrix_regions(matrix, regions, w)
+            return [
+                unfold_stripes(rows, stripes, -1).reshape(-1)
+                for rows in out.reshape(-1, rows_out, out.shape[1])
+            ]
 
     def bitmatrix_regions(
         self,

@@ -16,6 +16,11 @@ through them into the real chunk buffers.
 
 nu pads k+m to a multiple of q with zeroed virtual data nodes; node
 ids in grid space shift parity ids by nu.
+
+The repair is linear a byte position, so it is also ONE matrix over
+the helpers' sub-chunks (``repair_matrix``): the hook the stripe seam
+(``ec/stripe.repair``) takes to rebuild every stripe of a shard in a
+single backend dispatch.  Encode and the full decode stay per stripe.
 """
 
 from __future__ import annotations
@@ -50,6 +55,12 @@ class ErasureCodeClay(ErasureCode):
         self.sub_chunk_no = 1
         self.mds: ErasureCode | None = None
         self.pft: ErasureCode | None = None
+        # the inner codecs' region-math backend: what the stripe seam
+        # dispatches a repair on and names its host loops after
+        self.backend = None
+        self._host_twin: ErasureCodeClay | None = None
+        # (lost, helpers) -> repair matrix, or None where declined
+        self._repair_matrices: dict[tuple, np.ndarray | None] = {}
 
     # -- profile -----------------------------------------------------------
     def init(self, profile: ErasureCodeProfile) -> None:
@@ -59,6 +70,7 @@ class ErasureCodeClay(ErasureCode):
         super().init(profile)
         self.mds = instance().factory(mds_profile["plugin"], mds_profile)
         self.pft = instance().factory(pft_profile["plugin"], pft_profile)
+        self.backend = self.mds.backend
 
     def parse(self, profile: ErasureCodeProfile):
         super().parse(profile)
@@ -247,6 +259,75 @@ class ErasureCodeClay(ErasureCode):
         assert len(minimum) == self.d
         return minimum
 
+    def repair_matrix(self, lost: int, helpers):
+        """The whole repair as ONE matrix (the stripe seam's hook,
+        ``ec/stripe.repair``): every step of :meth:`repair` is
+        GF(2^8)-linear in each byte position, so chunk ``lost`` is a
+        fixed (sub_chunk_no x d*sub_chunk_no/q) matrix times the
+        helpers' sub-chunks.  Returns (matrix, order, w, backend):
+        column ``j * sub_chunk_no/q + i`` is sub-chunk i of helper
+        ``order[j]``'s fragment as ``minimum_to_decode`` has it read
+        (its runs concatenated), row z sub-chunk z of the lost chunk.
+        Built once a (lost, helpers) by :meth:`repair` itself on an
+        identity laid along one sub-chunk, on the host, and kept.
+        Virtual nodes (nu > 0) and aloof nodes (d < k+m-1) are the
+        plane traversal's own, so every d holds.  Declines (None, and
+        the caller keeps the per-stripe loop) where ``helpers`` are
+        not a repair set of d, under a chunk mapping, and for inner
+        codes whose math is not byte-wise over GF(2^8) (bitmatrix
+        techniques work on packets)."""
+        helpers = frozenset(helpers)
+        key = (lost, helpers)
+        if key not in self._repair_matrices:
+            self._repair_matrices[key] = self._probe_repair(lost, helpers)
+        matrix = self._repair_matrices[key]
+        if matrix is None:
+            return None
+        return matrix, sorted(helpers), 8, self.backend
+
+    def _probe_repair(self, lost: int, helpers: frozenset):
+        byte_wise = all(
+            getattr(c, "matrix", None) is not None
+            and getattr(c, "bitmatrix", None) is None
+            for c in (self.mds, self.pft)
+        )
+        if (
+            not byte_wise
+            or self.chunk_mapping
+            or len(helpers) != self.d
+            or not self.is_repair({lost}, helpers)
+        ):
+            return None
+        if self.backend.name == "numpy":
+            host = self
+        else:
+            # the probe is a few hundred region calls of odd lengths:
+            # host work, whichever backend the codec's own math is on
+            if self._host_twin is None:
+                from .registry import instance
+
+                profile = ErasureCodeProfile(
+                    {
+                        k: v
+                        for k, v in self._profile.items()
+                        if k != "backend"
+                    }
+                )
+                self._host_twin = instance().factory("clay", profile)
+            host = self._host_twin
+        per_helper = self.sub_chunk_no // self.q
+        cols = self.d * per_helper
+        fragments = {}
+        for j, helper in enumerate(sorted(helpers)):
+            frag = np.zeros((per_helper, cols), dtype=np.uint8)
+            frag[
+                np.arange(per_helper),
+                j * per_helper + np.arange(per_helper),
+            ] = 1
+            fragments[helper] = frag.reshape(-1)
+        out = host.repair({lost}, fragments, self.sub_chunk_no * cols)
+        return out[lost].reshape(self.sub_chunk_no, cols).astype(np.int64)
+
     def repair(self, want_to_read, chunks, chunk_size):
         """Minimum-bandwidth repair of one chunk from d partial helper
         reads (ErasureCodeClay.cc:395-460)."""
@@ -339,8 +420,9 @@ class ErasureCodeClay(ErasureCode):
             i = plane_to_ind[z]
             return helper[node][i * sub : (i + 1) * sub]
 
-        order = 1
-        while order in ordered_planes:
+        # lowest intersection score first; a y-group that is aloof
+        # whole (q nodes of one row) leaves no plane of score 1
+        for order in sorted(ordered_planes):
             for z in sorted(ordered_planes[order]):
                 z_vec = self._plane_vector(z)
                 for y in range(t):
@@ -419,7 +501,6 @@ class ErasureCodeClay(ErasureCode):
                             i3: scratch,
                         }
                         self.pft.decode_chunks({i1}, known, dec)
-            order += 1
 
     # -- layered decode (full decode and encode) ---------------------------
     def _decode_layered(self, erased_chunks: set, chunks: dict) -> None:

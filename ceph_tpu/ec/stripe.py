@@ -17,7 +17,9 @@ coding shards as they arrive (``ec_unfold`` brackets their views as
 bytes, ``ec_assemble`` the ``{position: shard}`` dict here: no payload
 byte moves in either).  The coalesced ``encode_batch`` gets stripe-form
 results and lays them out with ``_assemble_shards``; ``decode`` takes
-and returns shards as stored and copies nothing but the fetch.
+and returns shards as stored and copies nothing but the fetch;
+``repair`` is its fractional twin (CLAY's minimum-bandwidth repair:
+helpers' sub-chunk fragments in, the lost shard out, one dispatch).
 """
 
 from __future__ import annotations
@@ -530,6 +532,103 @@ def decode(
         kt.bytes_out = sum(v.nbytes for v in rebuilt.values())
     out.update(rebuilt)
     return out
+
+
+def repair_reconstruction(ec, lost: int, helpers):
+    """The fractional-repair analog of :func:`decode_reconstruction`,
+    and the ONE gate of :func:`repair`'s device path: a (matrix,
+    order, w, backend) plan that rebuilds chunk ``lost`` from the
+    sub-chunks ``minimum_to_decode`` has ``helpers`` read, or None for
+    a code without a ``repair_matrix`` hook (every family but CLAY) or
+    a profile whose hook declines.  The span ``ec_repair_plan`` is
+    round the hook: the matrix's build at first sight of a (lost,
+    helpers), a dictionary hit after."""
+    hook = getattr(ec, "repair_matrix", None)
+    if hook is None:
+        return None
+    with tracing.stage("ec_repair_plan"):
+        return hook(lost, set(helpers))
+
+
+def repair(
+    sinfo: StripeInfo, ec, fragments: dict, lost: int
+) -> np.ndarray:
+    """The B-stripe form of ``ec.decode({lost}, partial, chunk_size)``
+    (ECUtil::decode's sub-chunk loop, src/osd/ECUtil.cc:82-116,
+    hoisted; the twin of :func:`decode`): shard ``lost`` of one
+    object, as stored, from ``fragments`` — ``{helper: payload}``, a
+    stripe after another, each stripe the sub-chunk runs that
+    ``minimum_to_decode({lost}, helpers)`` names, concatenated.  A
+    code whose repair is one matrix (:func:`repair_reconstruction`)
+    takes ONE ``ec_decode`` dispatch on its backend, the fragments
+    going up as they are stored (``matrix_shards`` with sub-chunk
+    rows); any other runs the per-stripe loop inside one
+    flight-recorder entry, as :func:`_rebuild`'s fallback does.
+    Byte-identical either way.  Counted in
+    ``l_tpu_ec_repair_{calls,helper_bytes,rebuilt_bytes}``."""
+    views = {h: _host_row(v) for h, v in fragments.items()}
+    sub = ec.get_sub_chunk_count()
+    cs = sinfo.chunk_size
+    if cs % sub:
+        raise ErasureCodeError(
+            f"chunk size {cs} is not {sub} whole sub-chunks"
+        )
+    sc = cs // sub
+    minimum = ec.minimum_to_decode({lost}, set(views))
+    if set(minimum) != set(views):
+        raise ErasureCodeError(
+            f"fragments of {sorted(views)} given, the repair of "
+            f"{lost} reads {sorted(minimum)}"
+        )
+    per_stripe = {
+        h: sc * sum(count for _off, count in runs)
+        for h, runs in minimum.items()
+    }
+    counts = {
+        divmod(len(views[h]), per_stripe[h]) for h in views
+    }
+    if len(counts) != 1 or next(iter(counts))[1]:
+        raise ErasureCodeError(
+            "fragments must hold the same whole number of stripes"
+        )
+    ((nstripes, _),) = counts
+    if nstripes == 0:
+        return np.zeros(0, dtype=np.uint8)
+    ks = _kstats()
+    nbytes = sum(v.nbytes for v in views.values())
+    plan = repair_reconstruction(ec, lost, views)
+    # fragments travel to the device as 32-bit words
+    if plan is not None and sc % 4 == 0:
+        matrix, order, w, backend = plan
+        (rebuilt,) = backend.matrix_shards(
+            matrix,
+            [views[h] for h in order],
+            w,
+            nstripes,
+            sub_rows=(matrix.shape[1] // len(order), sub),
+        )
+    else:
+        with _host_loop("ec_decode", ec, 1, nstripes, nbytes):
+            rebuilt = np.concatenate(
+                [
+                    ec.decode(
+                        {lost},
+                        {
+                            h: v[s * per_stripe[h] : (s + 1) * per_stripe[h]]
+                            for h, v in views.items()
+                        },
+                        cs,
+                    )[lost]
+                    for s in range(nstripes)
+                ]
+            )
+    for suffix, amount, desc in (
+        ("calls", 1, "fractional repairs through ec/stripe.repair"),
+        ("helper_bytes", nbytes, "fragment bytes the repairs were handed"),
+        ("rebuilt_bytes", rebuilt.nbytes, "shard bytes the repairs rebuilt"),
+    ):
+        ks.perf.inc(ks.counter("ec_repair", suffix, desc=desc), amount)
+    return rebuilt
 
 
 def decode_concat(

@@ -239,7 +239,12 @@ class JaxBackend:
         return list(folded), coding
 
     def matrix_shards(
-        self, matrix: np.ndarray, shards, w: int, stripes: int
+        self,
+        matrix: np.ndarray,
+        shards,
+        w: int,
+        stripes: int,
+        sub_rows: tuple[int, int] = (1, 1),
     ) -> list[np.ndarray]:
         """One object's reconstruction in ONE device dispatch, in shard
         form: ``shards`` are the s survivor shards named by the plan
@@ -256,14 +261,26 @@ class JaxBackend:
         its matrix at trace time — a compile a pattern, inside a
         client's degraded read (PERF.md section 6, PR 28).  The length
         buckets to a power of two of stripes, as
-        ``_bitplane_dispatch`` buckets batches."""
+        ``_bitplane_dispatch`` buckets batches.
+
+        ``sub_rows`` = (rows in, rows out) is the row shape of a
+        fractional repair (``stripe.repair``): a stripe of a payload
+        holds so many rows of the matrix going in (a CLAY helper's 16
+        sub-chunks) and so many coming out (the lost chunk's 64), each
+        row a sub-chunk of every stripe, strided in what is stored.
+        The fragments cross the link as they are stored and the same
+        program folds them to rows and lays the result back as a
+        shard (``gf_matrix_words``' ``tile``), so the fetch is the
+        shard."""
         import jax
 
         from .gf_matmul import gf_matrix_words
         from .residency import bucket_pow2, note_shape
 
+        rows_in, rows_out = sub_rows
         rows = [_row_u8(s).view(np.uint32) for s in shards]
         n4 = len(rows[0])
+        out4 = n4 // rows_in * rows_out
         total = 4 * n4 * len(rows)
         with self._dispatch(
             "ec_decode", ops=1, stripes=stripes, bytes_in=total
@@ -273,16 +290,24 @@ class JaxBackend:
             with dp.stage("upload"):
                 dev = [jax.device_put(r) for r in rows]
             with dp.stage("compute"):
-                pad = (bucket_pow2(stripes) - stripes) * (n4 // stripes)
+                bucket = bucket_pow2(stripes)
+                pad = (bucket - stripes) * (n4 // stripes)
                 if pad:
                     dev = [jnp.pad(d, (0, pad)) for d in dev]
                     record_pad(4 * pad * len(dev))
-                note_shape("ec_shards", n4 + pad, len(dev), len(matrix), w)
-                odev = gf_matrix_words(bm, tuple(dev), w=w)
+                note_shape(
+                    "ec_shards", n4 + pad, len(dev), len(matrix), w, sub_rows
+                )
+                tile = (
+                    None
+                    if sub_rows == (1, 1)
+                    else (bucket, rows_in, rows_out)
+                )
+                odev = gf_matrix_words(bm, tuple(dev), w=w, tile=tile)
             with dp.stage("sync"):
                 out = np.asarray(odev)
-            dp.set_bytes_out(4 * n4 * len(matrix))
-        return [out[j, :n4].view(np.uint8) for j in range(out.shape[0])]
+            dp.set_bytes_out(4 * out4 * len(out))
+        return [row[:out4].view(np.uint8) for row in out]
 
     def matrix_stripes_batch(
         self,

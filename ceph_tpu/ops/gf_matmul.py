@@ -106,8 +106,10 @@ def gf_matrix_stripes(
         return unfold_stripes(out, b, chunk)
 
 
-@functools.partial(jax.jit, static_argnames=("w",))
-def gf_matrix_words(bm: jnp.ndarray, words, *, w: int) -> jnp.ndarray:
+@functools.partial(jax.jit, static_argnames=("w", "tile"))
+def gf_matrix_words(
+    bm: jnp.ndarray, words, *, w: int, tile: tuple | None = None
+) -> jnp.ndarray:
     """Decode-from-survivors in shard form.  ``words`` is a tuple of
     s equal-length 1-D uint32 arrays — survivor shards as they are
     stored (chunk i of every stripe concatenated, which IS the folded
@@ -118,13 +120,29 @@ def gf_matrix_words(bm: jnp.ndarray, words, *, w: int) -> jnp.ndarray:
     program per (rows out, rows in, length) serves every erasure
     pattern.  → (r, nwords) uint32, row j the j-th rebuilt shard.
 
+    ``tile`` = (stripes, rows in, rows out) is the sub-chunk form (a
+    CLAY repair): a payload then holds ``rows in`` rows of the matrix
+    a stripe — sub-chunk i of every stripe is one row, strided in what
+    is stored — and a result ``rows out`` of them.  The fold to rows
+    and the unfold of the result back to stored form happen here, on
+    the device.
+
     A uint32 holds 32 // w code words; sub-word q's bit x is bit
     ``w*q + x``.  Each q is one mod-2 matmul over its bit planes, and
     the planes of the result are shifted back where they came from —
     no uint8 array exists on the device."""
     per = 32 // w
     with jax.named_scope("ec_bitplane_decode"):
-        x = jnp.stack(words)
+        if tile is None:
+            x = jnp.stack(words)
+        else:
+            stripes, rows_in, rows_out = tile
+            x = jnp.concatenate(
+                [
+                    fold_stripes(p.reshape(stripes, rows_in, -1))
+                    for p in words
+                ]
+            )
         s, n = x.shape
         r = bm.shape[0] // w
         acc = jnp.zeros((r, n), dtype=jnp.uint32)
@@ -136,6 +154,13 @@ def gf_matrix_words(bm: jnp.ndarray, words, *, w: int) -> jnp.ndarray:
             # the w planes of a sub-word touch disjoint bits: sum is OR
             acc = acc | (planes << shift[None, :, None]).sum(
                 axis=1, dtype=jnp.uint32
+            )
+        if tile is not None:
+            acc = jnp.stack(
+                [
+                    unfold_stripes(rows, stripes, -1).reshape(-1)
+                    for rows in acc.reshape(-1, rows_out, n)
+                ]
             )
         return acc
 

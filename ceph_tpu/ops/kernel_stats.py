@@ -28,7 +28,9 @@ reader (``SELF_TIME_STAGES``):
 
 Groups registered by the instrumented modules: ``ec_encode`` /
 ``ec_decode`` (ec/stripe.py batched seam: one ``timed`` a seam
-function), ``gf_matmul`` (ops/ec_backend.py: a batched dispatch feeds
+function), ``ec_repair`` (ec/stripe.py ``repair``: the counters
+``l_tpu_ec_repair_{calls,helper_bytes,rebuilt_bytes}`` alone),
+``gf_matmul`` (ops/ec_backend.py: a batched dispatch feeds
 it from its flight-recorder entry's commit — ops/profiler.py
 ``dispatch(group=)`` — and the per-call region math through
 ``timed``), ``gf_bitmatrix`` (the same region math), ``crush``

@@ -41,6 +41,7 @@ from ..ec.stripe import (
     StripeInfo,
     decode_concat,
     encode as stripe_encode,
+    repair as stripe_repair,
     rmw_encode,
 )
 from ..native import ceph_crc32c
@@ -661,29 +662,33 @@ class ECStore:
         if sub_count > 1 and any(
             runs != [(0, sub_count)] for runs in minimum.values()
         ):
-            # fractional repair, stripe by stripe (the ECUtil::decode
-            # subchunk loop, src/osd/ECUtil.cc:82-116)
+            # fractional repair: each helper's sub-chunk runs of every
+            # stripe (the ranged reads of ECUtil::decode's subchunk
+            # loop, src/osd/ECUtil.cc:82-116), one stripe.repair an
+            # object
             nstripes = shard_len // chunk_len
             sc = chunk_len // sub_count
-            parts = []
-            for s in range(nstripes):
-                base = s * chunk_len
-                partial = {}
-                for helper, runs in minimum.items():
-                    segs = [
+            fragments = {
+                helper: np.frombuffer(
+                    b"".join(
                         self.stores[helper].read(
-                            self.cid, name, base + off * sc, cnt * sc
+                            self.cid,
+                            name,
+                            s * chunk_len + off * sc,
+                            cnt * sc,
                         )
+                        for s in range(nstripes)
                         for off, cnt in runs
-                    ]
-                    buf = np.frombuffer(
-                        b"".join(segs), dtype=np.uint8
-                    )
-                    read_bytes += len(buf)
-                    partial[helper] = buf
-                decoded = self.ec.decode({shard}, partial, chunk_len)
-                parts.append(decoded[shard])
-            return np.concatenate(parts), read_bytes
+                    ),
+                    dtype=np.uint8,
+                )
+                for helper, runs in minimum.items()
+            }
+            read_bytes = sum(len(f) for f in fragments.values())
+            return (
+                stripe_repair(self.sinfo, self.ec, fragments, shard),
+                read_bytes,
+            )
         chunks = {}
         for helper in minimum:
             raw = self.stores[helper].read(self.cid, name)

@@ -133,6 +133,24 @@ def test_shard_form_decode_program(one_chip, rebuilt, survivors, nbytes):
     _fits(compiled)
 
 
+def test_sub_chunk_repair_program(one_chip):
+    """What matrix_shards runs for ec/stripe.repair at the repair
+    cell's size: CLAY k=8 m=4 d=11, 64 stripes, each of the 11 helpers'
+    fragments (16 of 64 sub-chunks of 2 KiB a stripe, 2 MiB) as
+    uint32 words as stored, the 64 x 176 repair matrix's bitmatrix an
+    operand, the fold to sub-chunk rows and the unfold of the shard
+    in the program."""
+    compiled = gf_matmul.gf_matrix_words.lower(
+        _sds((64 * 8, 176 * 8), jnp.int8, one_chip),
+        tuple(_sds((64 * 16 * 512,), jnp.uint32, one_chip) for _ in range(11)),
+        w=8,
+        tile=(64, 16, 64),
+    ).compile()
+    assert "u8[" not in compiled.as_text()  # no byte array to re-tile
+    assert compiled.out_info.shape == (1, 64 * 131072 // 4)  # the 8 MiB shard
+    assert _fits(compiled) < 1 << 30
+
+
 @pytest.mark.parametrize(
     "b,k,m,chunk",
     [
