@@ -9,13 +9,15 @@ loop for layered codes.  ``HashInfo`` keeps the cumulative per-shard
 crc32c persisted as the hinfo xattr (ECUtil.cc:164-248).
 
 What an encode copies: its input ONCE.  A shard is chunk i of every
-stripe, concatenated — the folded region layout the kernels read — so
-the backend's fold (``matrix_stripe_shards``; span ``ec_fold`` round
-the row copies on the packed device path) is at once the upload's
-source and the k data shards, and the m rows that come back are the
-coding shards as they arrive (``ec_unfold`` brackets their views as
-bytes, ``ec_assemble`` the ``{position: shard}`` dict here: no payload
-byte moves in either).  The coalesced ``encode_batch`` gets stripe-form
+stripe, concatenated — the folded region layout — so the backend's
+fold (``matrix_stripe_shards``; span ``ec_fold`` round the row copies
+on the packed device path) is the k data shards, and the m rows that
+come back are the coding shards as they arrive (``ec_unfold`` brackets
+their views as bytes, ``ec_assemble`` the ``{position: shard}`` dict
+here: no payload byte moves in either).  On the packed path the fold
+runs under the link wherever the chunk is whole 4096-byte tiles: the
+upload's source is then the caller's buffer itself, which the kernel
+reads in stripe form.  The coalesced ``encode_batch`` gets stripe-form
 results and lays them out with ``_assemble_shards``; ``decode`` takes
 and returns shards as stored and copies nothing but the fetch;
 ``repair`` is its fractional twin (CLAY's minimum-bandwidth repair:

@@ -27,7 +27,7 @@ from .gf_matmul import (
     gf_matrix_stripes,
     matrix_to_device_bitmatrix,
 )
-from .kernel_stats import kernel_stats
+from .kernel_stats import FOLD_OVERLAPPED_NS, kernel_stats
 from .profiler import dispatch_profiler, record_pad
 
 
@@ -198,17 +198,28 @@ class JaxBackend:
     def _packed_stripes(dp, bm_np: np.ndarray, stripes: np.ndarray):
         """The packed-lane path of :meth:`matrix_stripe_shards`, the
         one stripes form of the packed kernel.  An encode makes ONE
-        host copy of its input, the fold (a device-side transpose is
-        the relayout this kernel exists to avoid): row i of the fold
-        is data shard i, the upload's source and part of the answer,
-        and fetched row j is coding shard k+j as it arrives — nothing
-        is stacked or laid out again.  Each row goes up the link as
-        soon as it is folded, so the transfer drains under the rest
-        of the fold.  Each stage is bracketed where it happens, the
-        repeated ones accumulating: the row copies (span ``ec_fold``),
-        the row ``device_put``s (``upload``), the kernel's issue
-        (``compute``), the wait for and copy of the m result rows
-        (``sync``), their views as bytes (span ``ec_unfold``)."""
+        host copy of its input, the fold: row i of the fold is data
+        shard i, and fetched row j is coding shard k+j as it arrives —
+        nothing is stacked or laid out again.
+
+        The upload's source is the caller's own buffer wherever the
+        chunk is whole (8, 128) u32 tiles (``packed_gf.
+        STRIPE_FORM_BYTES``: every ``stripe_unit`` a pool can have):
+        ONE ``device_put`` of its stripe-form view, the kernel — which
+        reads stripe form through its ``BlockSpec``s, so nothing is
+        laid out again on the device either — and the fetch are issued
+        first, and the fold runs under the link, for the answer alone.
+        Any other chunk is folded first and goes up a row at a time as
+        it is folded (shard form), the transfer draining under the
+        rest of the fold.  ``l_tpu_ec_fold_overlapped_ns`` counts the
+        fold's nanoseconds spent with this call's whole upload issued.
+
+        Each stage is bracketed where it happens, the repeated ones
+        accumulating: the ``device_put``s (``upload``), the kernel's
+        issue (``compute``), the row copies (span ``ec_fold``, k a
+        call), the fetch's start, the wait for and copy of the m
+        result rows (``sync``), their views as bytes (span
+        ``ec_unfold``)."""
         import jax
 
         b, k, chunk = stripes.shape
@@ -220,19 +231,38 @@ class JaxBackend:
             else _fold_buffer(stripes, k, b * chunk)
         )
         dp.add_upload(stripes.nbytes)
-        dev = []
-        for i, row in enumerate(folded):
-            with tracing.stage("ec_fold"):
+
+        def put(words):
+            with dp.stage("upload"):
+                return jax.device_put(words)
+
+        def issue(dev):
+            with dp.stage("compute"):
+                outs = packed_gf.prebuilt_word_call(bm_np)(*dev)
+            with dp.stage("sync"):
+                for o in outs:
+                    o.copy_to_host_async()
+            return outs
+
+        def fold_row(i, row) -> int:
+            with tracing.stage("ec_fold") as span:
                 if b > 1:
                     np.copyto(row.reshape(b, chunk), stripes[:, i, :])
-                words = row.view(np.uint32).reshape(1, -1)
-            with dp.stage("upload"):
-                dev.append(jax.device_put(words))
-        with dp.stage("compute"):
-            outs = packed_gf.prebuilt_word_call(bm_np)(*dev)
+            return int(span.duration * 1e9)  # as l_stage_ec_fold_ns counts it
+
+        if chunk % packed_gf.STRIPE_FORM_BYTES == 0:
+            outs = issue([put(packed_gf.stripe_words(stripes))])
+            kernel_stats().perf.inc(
+                FOLD_OVERLAPPED_NS,
+                sum(fold_row(i, row) for i, row in enumerate(folded)),
+            )
+        else:
+            dev = []
+            for i, row in enumerate(folded):
+                fold_row(i, row)
+                dev.append(put(row.view(np.uint32).reshape(1, -1)))
+            outs = issue(dev)
         with dp.stage("sync"):
-            for o in outs:
-                o.copy_to_host_async()
             host = [np.asarray(o) for o in outs]
         with tracing.stage("ec_unfold"):
             coding = [h.reshape(-1).view(np.uint8) for h in host]

@@ -26,6 +26,14 @@ reader (``SELF_TIME_STAGES``):
     l_stage_<name>_ns        u64   their durations, summed (ns)
     l_stage_<name>_self_ns   u64   durations less same-thread children
 
+and, beside ``l_stage_ec_fold_ns``, the share of it that a packed
+encode spent with its whole upload issued (ops/ec_backend.py
+``_packed_stripes``: all of it where the caller's buffer goes up in
+stripe form, none where the fold comes first), declared with the set
+and not by the calls that count into it:
+
+    l_tpu_ec_fold_overlapped_ns   u64
+
 Groups registered by the instrumented modules: ``ec_encode`` /
 ``ec_decode`` (ec/stripe.py batched seam: one ``timed`` a seam
 function), ``ec_repair`` (ec/stripe.py ``repair``: the counters
@@ -75,6 +83,8 @@ SELF_TIME_STAGES = frozenset({"osd_op"})
 
 _LAT_HIST_BOUNDS = log2_bounds(LATENCY_MIN_S, LATENCY_BUCKETS)
 
+FOLD_OVERLAPPED_NS = "l_tpu_ec_fold_overlapped_ns"
+
 
 class KernelStats:
     def __init__(self, name: str = "tpu_kernels"):
@@ -95,6 +105,10 @@ class KernelStats:
         self._ensure_counter(
             "l_tpu_pad_bytes_wasted", PERFCOUNTER_U64,
             "device bytes padded in by pow2 shape bucketing"
+        )
+        self._ensure_counter(
+            FOLD_OVERLAPPED_NS, PERFCOUNTER_U64,
+            "ec_fold time spent with the call's upload issued (ns)"
         )
 
     def _ensure_counter(

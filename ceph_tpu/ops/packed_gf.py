@@ -36,6 +36,18 @@ both (measured >60x difference).  Host callers get the conversion for
 free via numpy views (``to_words``/``from_words``); device-resident
 pipelines should carry word form between calls.
 
+"Stripe form" is the other operand the same kernel body takes: ONE
+u32 ``(B, k, chunk/512, 128)`` array, a free view of (B, k, chunk)
+stripe bytes (``stripe_words``), where region j is chunk j of every
+stripe.  The body is elementwise over its blocks, so input j's
+``BlockSpec`` alone — ``(TB, None, TR, 128)`` at ``(bi, j, ri, 0)`` —
+reads the region where it lies and no fold precedes the upload; with
+a minor dimension of exactly 128 words the array's (8, 128) tiling is
+row-major, so it needs no padding and neither direction of the
+transfer re-tiles anything (the compiled program is the one custom
+call: tests/test_chip_compile.py).  On a v5e the 64 MiB k=8,m=3
+encode takes 0.272 ms so (PERF.md section 5, PR 40).
+
 w=8 only (the jerasure/isa default and the BASELINE.md configs);
 other word sizes use the bitplane path.
 """
@@ -50,6 +62,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 TILE_WORDS = 8192  # u32 lanes per grid step (measured best 4096-8192)
+STRIPE_FORM_BYTES = 4096  # a chunk of whole (8, 128) u32 tiles
 _LSB = 0x01010101
 
 
@@ -160,6 +173,25 @@ def _make_kernel(rows: tuple[tuple[int, ...], ...], n_in: int, m_out: int):
     return kernel
 
 
+def stripe_words(stripes: np.ndarray) -> np.ndarray:
+    """(B, k, chunk) u8 stripes → their ``(B, k, chunk/512, 128)`` u32
+    stripe form — a free view of the caller's buffer.  A chunk of
+    whole (8, 128) u32 tiles (4096 bytes) only: with a minor dimension
+    of exactly 128 words the device's tiling of the array is
+    row-major, so the link carries the bytes as they lie."""
+    b, k, chunk = stripes.shape
+    assert chunk % STRIPE_FORM_BYTES == 0, stripes.shape
+    return stripes.view(np.uint32).reshape(b, k, chunk // 512, 128)
+
+
+def _stripe_block(b: int, r: int) -> tuple[int, int]:
+    """(stripes, rows of 128 words) a grid step takes of a stripe-form
+    operand of ``b`` stripes of ``r`` rows a chunk: some TILE_WORDS
+    words, whole chunks of several stripes where a chunk is short."""
+    tr = min(r, TILE_WORDS // 128)
+    return min(b, max(1, TILE_WORDS // (tr * 128))), tr
+
+
 @functools.lru_cache(maxsize=512)
 def _packed_call(
     rows: tuple[tuple[int, ...], ...],
@@ -169,14 +201,13 @@ def _packed_call(
 ):
     kernel = _make_kernel(rows, n_in, m_out)
 
+    # index maps return int32 on purpose: crush/jaxmap.py turns
+    # jax_enable_x64 on for the whole process, a bare ``0`` then traces
+    # as i64 and Mosaic refuses the index map ('func.return' (i64, i32))
     def block_index(i):
-        # int32 on purpose: crush/jaxmap.py turns jax_enable_x64 on for
-        # the whole process, a bare ``0`` then traces as i64 and Mosaic
-        # refuses the index map ('func.return' (i64, i32))
         return jnp.int32(0), i
 
-    @jax.jit
-    def run(*xs):  # n_in arrays of (1, nwords) u32
+    def shard_form(xs):  # n_in arrays of (1, nwords) u32
         n4 = xs[0].shape[1]
         tile = min(TILE_WORDS, n4)
         pad = (-n4) % tile
@@ -206,6 +237,49 @@ def _packed_call(
         if pad:
             outs = [o[:, : n4 - pad] for o in outs]
         return outs
+
+    def stripe_form(x):  # ONE (B, n_in, R, 128) u32 array
+        b, k, r, lanes = x.shape
+        assert (k, lanes) == (n_in, 128), x.shape
+        tb, tr = _stripe_block(b, r)
+
+        def chunk_of(j):
+            # input j is chunk j of every stripe, read where it lies:
+            # the body is elementwise over its blocks, so the specs
+            # alone do the fold (an edge block's padding is harmless)
+            return lambda bi, ri: (bi, jnp.int32(j), ri, jnp.int32(0))
+
+        def out_index(bi, ri):
+            return bi, ri, jnp.int32(0)
+
+        with jax.named_scope("ec_packed_encode"):
+            return pl.pallas_call(
+                kernel,
+                grid=(pl.cdiv(b, tb), pl.cdiv(r, tr)),
+                in_specs=[
+                    pl.BlockSpec((tb, None, tr, 128), chunk_of(j))
+                    for j in range(n_in)
+                ],
+                out_specs=[
+                    pl.BlockSpec((tb, tr, 128), out_index)
+                    for _ in range(m_out)
+                ],
+                out_shape=[
+                    jax.ShapeDtypeStruct((b, r, 128), jnp.uint32)
+                    for _ in range(m_out)
+                ],
+                interpret=interpret,
+            )(*[x] * n_in)
+
+    @jax.jit
+    def run(*xs):
+        """The operand's form picks the specs: shard form, n_in
+        regions that already are separate arrays, or stripe form, the
+        ONE array of :func:`stripe_words` — coding output i then comes
+        back ``(B, R, 128)``, which flattened IS the folded shard."""
+        if len(xs) == 1 and xs[0].ndim == 4:
+            return stripe_form(xs[0])
+        return shard_form(xs)
 
     return run
 

@@ -160,7 +160,7 @@ def phase_ec(
     import jax.numpy as jnp
 
     from ceph_tpu import gf
-    from ceph_tpu.ec import ErasureCodeProfile, registry_instance
+    from ceph_tpu.ec import ErasureCodeProfile, registry_instance, stripe
     from ceph_tpu.ops import packed_gf
     from ceph_tpu.ops.gf_matmul import (
         gf_matrix_stripes,
@@ -214,6 +214,24 @@ def phase_ec(
                     f"{plugin} k={k} m={m}: shard {i} differs jax vs numpy"
                 )
             compared += got_j[i].nbytes
+        # the stripe seam at a pool's 4 KiB chunk, where the packed
+        # kernel reads the caller's buffer in stripe form: 12 stripes,
+        # so a whole block of them and an edge block
+        sinfo = stripe.StripeInfo(k, k * 4096)
+        stripes = rng.integers(0, 256, 12 * k * 4096, dtype=np.uint8)
+        seam_j = stripe.encode(sinfo, ec_jax, stripes)
+        per_stripe = [
+            ec_np.encode(want, one.tobytes())
+            for one in stripes.reshape(12, -1)
+        ]
+        for i in sorted(want):
+            shard = np.concatenate([chunks[i] for chunks in per_stripe])
+            if not np.array_equal(seam_j[i], shard):
+                raise RuntimeError(
+                    f"{plugin} k={k} m={m}: stripe.encode's shard {i} "
+                    "differs jax vs numpy"
+                )
+            compared += seam_j[i].nbytes
         for lost in erasure_sets:
             avail = {i: c for i, c in got_j.items() if i not in lost}
             rec_j = ec_jax.decode(want, avail)

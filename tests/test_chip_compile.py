@@ -98,6 +98,37 @@ def test_packed_encode_kernel(one_chip, plugin, k, m):
     _fits(compiled)
 
 
+@pytest.mark.parametrize(
+    "k,m,stripes,chunk",
+    [
+        (8, 3, 64, 131072),  # ec_plugin_k8m3.encode_1m: 64 x 1 MiB
+        (4, 2, 256, 4096),  # a 4 MiB write_full of the served k=4 m=2 pool
+    ],
+)
+def test_packed_encode_kernel_reads_stripe_form(one_chip, k, m, stripes, chunk):
+    """_packed_stripes' upload at a chunk of whole tiles: the caller's
+    buffer as ONE u32 (B, k, chunk/512, 128) operand, folded by the
+    kernel's BlockSpecs — the program is the kernel and nothing else:
+    no copy or transpose of the operand, no temporaries, no padding."""
+    ec = _code("jerasure", k, m)
+    bm = gf.jerasure_bitmatrix(np.asarray(ec.matrix, dtype=np.int64), 8)
+    shape = (stripes, k, chunk // 512, 128)
+    compiled = packed_gf.prebuilt_word_call(bm).lower(
+        _sds(shape, jnp.uint32, one_chip)
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"\b(copy|transpose)(\.\d+)? = ", text)
+    assert not re.search(r" (copy|transpose)\(", text)
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes == 0
+    assert ma.argument_size_in_bytes == stripes * k * chunk
+    assert [o.shape for o in compiled.out_info] == [
+        (stripes, chunk // 512, 128)
+    ] * m
+    _fits(compiled)
+
+
 def test_packed_decode_kernel_two_erasures(one_chip):
     """The per-op decode of a 1 MiB k=8,m=3 object with data chunks 2
     and 5 lost (matrix_decode -> matrix_regions)."""

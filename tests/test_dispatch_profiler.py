@@ -497,13 +497,15 @@ _SEAM_CLOCKS = sorted(
      for stage in ("compute", "sync", "transfer", "wall")]
     + [f"l_stage_{span}_ns"
        for span in ("ec_assemble", "ec_fold", "ec_plan", "ec_unfold")]
+    # ISSUE 40: the fold's share spent with the call's upload issued
+    + ["l_tpu_ec_fold_overlapped_ns"]
 )
 
 
 def test_the_harness_reads_the_counters_it_read_before():
     """``benchmark.harness.flat_counters`` after the four seam calls:
-    no key gone, none new, every count what the parent counted; the
-    recorder's entries one a call, ``<kind>:jax``, in sequence; and the same calls again
+    no key gone, none new but ISSUE 40's one, every count what the
+    parent counted; the recorder's entries one a call, ``<kind>:jax``, in sequence; and the same calls again
     compile nothing (``CompileClock.programs`` stands)."""
     repo = pathlib.Path(__file__).resolve().parent.parent
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}

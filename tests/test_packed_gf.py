@@ -17,6 +17,7 @@ from ceph_tpu.gf.matrix import (
     reed_sol_vandermonde_coding_matrix,
 )
 from ceph_tpu.gf import matrix_vector_mul_region
+from ceph_tpu.layout import fold_stripes
 from ceph_tpu.ops.gf_matmul import matrix_to_device_bitmatrix
 from ceph_tpu.ops import packed_gf
 
@@ -49,6 +50,53 @@ def test_decode_matrix_matches_oracle():
     enc = reed_sol_vandermonde_coding_matrix(k, m, 8)
     dec, survivors = make_decoding_matrix(enc, [1, 6], k, 8)
     _check(np.asarray(dec), k, 2048)
+
+
+@pytest.mark.parametrize(
+    "b,chunk,block",
+    [
+        (2, 65536, (1, 64)),  # the encode cell's block: 1 x 64 rows of a 128 KiB chunk's 256
+        (16, 4096, (8, 8)),  # the served pool's: 8 whole 4 KiB chunks
+        (12, 4096, (8, 8)),  # an edge block of 4 stripes
+        (3, 8192, (3, 16)),  # fewer stripes than a block would take
+        (2, 512 * 72, (1, 64)),  # an edge block of 8 rows
+        (1, 4096, (1, 8)),
+    ],
+)
+@pytest.mark.parametrize("k,m", [(4, 2), (8, 3), (10, 4)])
+def test_stripe_form_is_shard_form_byte_for_byte(k, m, b, chunk, block):
+    """ONE (B, k, R, 128) operand read through the BlockSpecs against
+    the k folded rows: the same kernel body, so the same bytes, and
+    an output flattened IS the folded coding shard."""
+    matrix = reed_sol_vandermonde_coding_matrix(k, m, 8)
+    bm = np.asarray(matrix_to_device_bitmatrix(matrix, 8))
+    stripes = rng.integers(0, 256, (b, k, chunk), dtype=np.uint8)
+    folded = np.ascontiguousarray(fold_stripes(stripes))
+    assert packed_gf._stripe_block(b, chunk // 512) == block
+    words = packed_gf.stripe_words(stripes)
+    assert words.shape == (b, k, chunk // 512, 128)
+    assert np.shares_memory(words, stripes)
+    call = packed_gf.prebuilt_word_call(bm, interpret=True)
+    outs = call(words)
+    assert [o.shape for o in outs] == [(b, chunk // 512, 128)] * m
+    got = np.stack([np.asarray(o).reshape(-1).view(np.uint8) for o in outs])
+    want = packed_gf.packed_bitmatrix_regions(bm, folded, interpret=True)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        got, matrix_vector_mul_region(matrix, folded, 8)
+    )
+
+
+def test_the_cells_stripe_blocks_are_tile_words_a_step():
+    """64 MiB at k=8 (chunk 128 KiB): 1 stripe x 64 rows, 256 steps a
+    chunk position — the shard form's count; the served pool's 4 MiB
+    at k=4 (chunk 4 KiB): 8 whole chunks a step."""
+    assert packed_gf._stripe_block(64, 256) == (1, 64)
+    assert packed_gf._stripe_block(256, 8) == (8, 8)
+    for b, r in [(64, 256), (256, 8), (5, 16), (1, 8)]:
+        tb, tr = packed_gf._stripe_block(b, r)
+        assert tb * tr * 128 <= packed_gf.TILE_WORDS
+        assert tr % 8 == 0 or tr == r  # whole (8, 128) tiles
 
 
 def test_stripes_layout(monkeypatch):

@@ -4,7 +4,10 @@ backend, the jax backend on the CPU (bitplane, and the virtual mesh at
 64 stripes) and the packed path through the kernel's interpreter —
 byte for byte the plain reference's, one ``ec_encode`` record a call,
 one host copy of the input on the packed path, and the spans that
-feed ``ec_plugin_host_ms_per_call`` still there."""
+feed ``ec_plugin_host_ms_per_call`` still there.  Where the chunk is
+whole (8, 128) u32 tiles the packed path's upload is the caller's own
+buffer, put before the fold (ISSUE 40); any other chunk is folded
+first."""
 
 from __future__ import annotations
 
@@ -30,8 +33,15 @@ from benchmark.references import reed_sol_van_codec  # noqa: E402
 
 PATHS = ("numpy", "jax", "packed")
 SHAPES = [(4, 2), (8, 3)]
-# stripes a call, and the chunk each is cut to (64 at a small one)
-BATCHES = {1: 512, 2: 512, 3: 512, 64: 64}
+# (stripes a call, the chunk each is cut to): below 4096 the packed
+# path folds first; at whole tiles it puts the caller's buffer — one
+# stripe, fewer stripes than a block takes, whole blocks, and an edge
+# block in the stripes (12 and 6 by blocks of 8 and 4)
+BATCHES = [
+    (1, 512), (2, 512), (3, 512), (64, 64),
+    (1, 4096), (3, 4096), (8, 4096), (12, 4096), (64, 4096),
+    (1, 8192), (3, 8192), (6, 8192), (8, 8192),
+]
 WANTS = {
     "all": lambda k, m: set(range(k + m)),
     "data": lambda k, m: set(range(k)),
@@ -73,11 +83,10 @@ def _payload(k, b, chunk, seed=30):
 
 
 @pytest.mark.parametrize("want", WANTS)
-@pytest.mark.parametrize("b", BATCHES)
+@pytest.mark.parametrize("b,chunk", BATCHES)
 @pytest.mark.parametrize("k,m", SHAPES)
 @pytest.mark.parametrize("path", PATHS)
-def test_the_backends_rows_are_the_shards(on_path, path, k, m, b, want):
-    chunk = BATCHES[b]
+def test_the_backends_rows_are_the_shards(on_path, path, k, m, b, chunk, want):
     ec = _code(k, m, path)
     sinfo = StripeInfo(k, k * chunk)
     data = _payload(k, b, chunk)
@@ -145,6 +154,86 @@ def test_one_stripe_folds_to_views_of_the_input(on_path):
         assert np.array_equal(got[p], data[p * chunk:(p + 1) * chunk])
 
 
+@pytest.fixture
+def order(monkeypatch):
+    """What a packed encode does in what order: every ``device_put``
+    (with its array) and every span the backend opens, as they
+    happen."""
+    import jax
+
+    events = []
+    put, stage = jax.device_put, ec_backend.tracing.stage
+
+    def spied_put(x, *args, **kwargs):
+        events.append(("put", x))
+        return put(x, *args, **kwargs)
+
+    def spied_stage(name, *args, **kwargs):
+        events.append((name, None))
+        return stage(name, *args, **kwargs)
+
+    monkeypatch.setattr(jax, "device_put", spied_put)
+    monkeypatch.setattr(ec_backend.tracing, "stage", spied_stage)
+    return events
+
+
+def _fold_counters():
+    dump = ec_backend.kernel_stats().dump()
+    return (dump.get("l_stage_ec_fold_ns", 0), dump.get("l_stage_ec_fold_count", 0),
+            dump.get("l_tpu_ec_fold_overlapped_ns", 0))
+
+
+@pytest.mark.parametrize("b,chunk", [(1, 4096), (3, 4096), (12, 4096), (8, 8192)])
+@pytest.mark.parametrize("k,m", SHAPES)
+def test_a_whole_tile_chunk_goes_up_as_the_callers_buffer_before_the_fold(
+        on_path, order, k, m, b, chunk):
+    """ONE put a call, of a view of the caller's data and no copy of
+    it, issued before the first ``ec_fold`` span opens; the fold then
+    runs with the upload issued, all of it (ISSUE 40)."""
+    ec = _code(k, m, "packed")
+    data = _payload(k, b, chunk)
+    on_path("packed")
+    fold_ns, fold_count, overlapped_ns = _fold_counters()
+    seen = len(_records())
+    got = encode(StripeInfo(k, k * chunk), ec, data)
+    (rec,) = _records()[seen:]
+    names = [name for name, _ in order]
+    assert names == ["put"] + ["ec_fold"] * k + ["ec_unfold", "ec_assemble"]
+    (put,) = [x for name, x in order if name == "put"]
+    assert put.dtype == np.uint32 and put.shape == (b, k, chunk // 512, 128)
+    assert np.shares_memory(put, data) and put.nbytes == data.nbytes
+    assert rec["bytes_uploaded"] == data.nbytes
+    after = _fold_counters()
+    assert after[1] - fold_count == k
+    assert after[2] - overlapped_ns == after[0] - fold_ns > 0
+    reference = reed_sol_van_codec.encode_shards(data.tobytes(), k, m, chunk)
+    assert all(np.array_equal(got[p], reference[p]) for p in range(k + m))
+
+
+@pytest.mark.parametrize("b,chunk", [(1, 512), (3, 512), (64, 64), (2, 4096 + 512)])
+def test_any_other_chunk_is_folded_first_a_row_at_a_time(on_path, order, b, chunk):
+    """Fold-first, as it was: each row's put follows its own
+    ``ec_fold`` span, k puts of folded rows that share nothing with
+    the caller's data (but for one stripe, its own fold), and no
+    nanosecond of the fold counts as overlapped."""
+    k, m = 4, 2
+    ec = _code(k, m, "packed")
+    data = _payload(k, b, chunk)
+    on_path("packed")
+    fold_ns, _count, overlapped_ns = _fold_counters()
+    got = encode(StripeInfo(k, k * chunk), ec, data)
+    names = [name for name, _ in order]
+    assert names == ["ec_fold", "put"] * k + ["ec_unfold", "ec_assemble"]
+    for _name, row in (e for e in order if e[0] == "put"):
+        assert row.shape == (1, b * chunk // 4)
+        assert np.shares_memory(row, data) == (b == 1)
+    after = _fold_counters()
+    assert after[0] > fold_ns and after[2] == overlapped_ns
+    assert "l_tpu_ec_fold_overlapped_ns" in ec_backend.kernel_stats().dump()
+    reference = reed_sol_van_codec.encode_shards(data.tobytes(), k, m, chunk)
+    assert all(np.array_equal(got[p], reference[p]) for p in range(k + m))
+
+
 def test_the_spans_of_a_packed_encode_still_feed_the_host_metric(on_path):
     """``ec_fold`` round the row copies (a span a row), ``ec_unfold``
     and ``ec_assemble`` round what is left at those places: the
@@ -184,3 +273,78 @@ def test_the_spans_of_a_packed_encode_still_feed_the_host_metric(on_path):
         assert counters[f"l_tpu_{group}_calls"] == 1
         assert counters[f"l_tpu_{group}_bytes_in"] == data.nbytes
         assert counters[f"l_tpu_{group}_bytes_out"] == bytes_out
+
+
+def test_ten_threads_at_the_served_shape_each_get_their_own_shards(
+        on_path, monkeypatch):
+    """A served pool's 4 MiB ``write_full`` (256 stripes of k=4 m=2,
+    chunk 4096) from ten threads at once, as ten OSDs of one process
+    meet ``_packed_stripes``, each under a daemon's own buffered
+    tracer: every call's k + m shards are the reference's of ITS
+    payload — the put's source is the caller's buffer until the coding
+    rows are back, nothing is shared between calls — and the overlap
+    counter is the one the set was built with (ISSUE 40, step 0 c)."""
+    import threading
+
+    from ceph_tpu.common import tracing
+    from ceph_tpu.ops.kernel_stats import FOLD_OVERLAPPED_NS
+
+    b, k, m, chunk, threads, calls = 256, 4, 2, 4096, 10, 20
+    on_path("packed")
+    backend = ec_backend.get_jax_backend()
+    matrix = np.asarray(_code(k, m, "packed").matrix, dtype=np.int64)
+    ks = ec_backend.kernel_stats()
+    counter = ks.perf._counters[FOLD_OVERLAPPED_NS]
+    declared = []
+    ensure = ks._ensure_counter
+    monkeypatch.setattr(
+        ks, "_ensure_counter",
+        lambda name, *a, **kw: (declared.append(name), ensure(name, *a, **kw)))
+    before = _fold_counters()
+    seen = len(_records())
+    payloads = [_payload(k, b, chunk, seed=4000 + t) for t in range(threads)]
+    wanted = [
+        reed_sol_van_codec.encode_shards(p.tobytes(), k, m, chunk)
+        for p in payloads
+    ]
+    wrong = []
+    start = threading.Barrier(threads)
+
+    def osd(t):
+        tracer = tracing.Tracer(f"osd.{t}")
+        stripes = payloads[t].reshape(b, k, chunk)
+        start.wait(timeout=60)
+        for call in range(calls):
+            try:
+                with tracer.start_span("osd_op", role="primary"):
+                    data, coding = backend.matrix_stripe_shards(
+                        matrix, stripes, 8)
+            except Exception as e:  # noqa: BLE001 — the thread's failure is the test's
+                wrong.append((t, call, repr(e)))
+                return
+            for p, shard in enumerate(data + coding):
+                if not (
+                    shard.ndim == 1 and shard.dtype == np.uint8
+                    and shard.flags.c_contiguous
+                    and np.array_equal(shard, wanted[t][p])
+                ):
+                    wrong.append((t, call, p))
+
+    pool = [threading.Thread(target=osd, args=(t,)) for t in range(threads)]
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)  # hand the interpreter over fifty times as often
+    try:
+        for th in pool:
+            th.start()
+        for th in pool:
+            th.join(timeout=300)
+    finally:
+        sys.setswitchinterval(switch)
+    assert not any(th.is_alive() for th in pool)
+    assert wrong == []
+    assert len(_records()) - seen == threads * calls
+    assert FOLD_OVERLAPPED_NS not in declared
+    assert ks.perf._counters[FOLD_OVERLAPPED_NS] is counter
+    after = _fold_counters()
+    assert after[1] - before[1] == threads * calls * k
+    assert after[2] - before[2] == after[0] - before[0] > 0

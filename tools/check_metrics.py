@@ -1038,6 +1038,8 @@ def product_counter_sets():
         ks.record(group)
     for suffix in ("pgs", "fallback_lanes", "host_ns", "host_overlapped_ns"):
         ks.counter("crush", suffix)
+    # the packed encode's l_tpu_ec_fold_overlapped_ns is declared by
+    # KernelStats itself, beside the compile-cache pair
     # residency + coalesced-encode families (ops/residency.py) join
     # the schema walk and the cross-set collision lint
     ensure_counters(ks)
