@@ -49,7 +49,15 @@ ride every span whether it is buffered or not:
   duration less the child spans opened on its thread while it was
   ambient) to the process-wide ``l_stage_<name>_*`` counters, where
   the device plane has attached its counter set
-  (``ops/kernel_stats.py``).
+  (``ops/kernel_stats.py``).  A span of a name that set declares
+  (``RUSAGE_STAGES``), entered as a context manager and finished on
+  the thread that entered it, also hands over what its thread used
+  between the two, from one ``getrusage(RUSAGE_THREAD)`` at each end:
+  its CPU time (``ru_utime + ru_stime``, which the kernel moves a
+  scheduler tick at a time, so a window's sum is right and one short
+  span's is not) and its voluntary context switches (``ru_nvcsw``).
+  The call keeps the interpreter's lock: reading the count is no
+  hand-over.
 """
 
 from __future__ import annotations
@@ -57,6 +65,7 @@ from __future__ import annotations
 import contextlib
 import itertools
 import os
+import resource
 import sys
 import threading
 import time
@@ -89,6 +98,12 @@ def _new_id() -> str:
     return f"{_ID_PREFIX}{next(_id_seq):06x}"
 
 
+def _thread_usage() -> tuple[float, int]:
+    """This thread's CPU seconds and voluntary context switches so far."""
+    ru = resource.getrusage(resource.RUSAGE_THREAD)
+    return ru.ru_utime + ru.ru_stime, ru.ru_nvcsw
+
+
 class Span:
     """One timed stage; finished spans become plain dicts in the
     tracer's buffer (the wire/admin-socket shape)."""
@@ -96,7 +111,7 @@ class Span:
     __slots__ = (
         "_tracer", "trace_id", "span_id", "parent_id", "daemon",
         "name", "role", "t0", "t1", "tags", "events", "_done",
-        "_parent", "_child_s", "_mirror",
+        "_parent", "_child_s", "_mirror", "_usage0",
     )
 
     def __init__(
@@ -127,6 +142,9 @@ class Span:
         self._parent = parent
         self._child_s = 0.0
         self._mirror = None
+        # (thread id, its CPU seconds, its voluntary switches) at
+        # enter, for a RUSAGE_STAGES name
+        self._usage0 = None
 
     # wall-clock views of the monotonic stamps
     @property
@@ -152,9 +170,16 @@ class Span:
             return
         self._done = True
         self.t1 = _now() if end is None else end
+        usage = None
+        if self._usage0 is not None:
+            thread, cpu_s, switches = self._usage0
+            # another thread's usage is not this span's
+            if thread == threading.get_ident():
+                cpu_now, switches_now = _thread_usage()
+                usage = (int((cpu_now - cpu_s) * 1e9), switches_now - switches)
         if self._parent is not None:
             self._parent._child_s += self.t1 - self.t0
-        self._tracer._complete(self)
+        self._tracer._complete(self, usage)
 
     def __enter__(self) -> "Span":
         _push(self._tracer, self)
@@ -162,6 +187,8 @@ class Span:
         if annotation is not None:
             self._mirror = annotation("ceph:" + self.name)
             self._mirror.__enter__()
+        if self.name in _rusage_stages:
+            self._usage0 = (threading.get_ident(), *_thread_usage())
         return self
 
     def __exit__(self, exc_type, *exc) -> bool:
@@ -282,7 +309,7 @@ class Tracer:
             self.spans_started += 1
         Span(self, name, trace_id, "", role, tags, start).finish(end)
 
-    def _complete(self, span: Span) -> None:
+    def _complete(self, span: Span, usage: tuple | None) -> None:
         if self.buffered:
             entry = span.dump()
             with self._lock:
@@ -296,6 +323,7 @@ class Tracer:
                 span.name,
                 int(seconds * 1e9),
                 int(max(seconds - span._child_s, 0.0) * 1e9),
+                usage,
             )
 
     # -- consumers ---------------------------------------------------------
@@ -331,16 +359,20 @@ class Tracer:
 
 # -- stage counters and the profiler mirror --------------------------------
 
-# ``sink(name, ns, self_ns)``: ops/kernel_stats.py attaches its
-# process-wide counter set here when it is first built.  A process
-# that never loads the device plane has no such set, and no surface
-# that would show one, and pays one ``is None`` a span.
+# ``sink(name, ns, self_ns, usage)``: ops/kernel_stats.py attaches its
+# process-wide counter set here when it is first built, with the span
+# names whose thread usage it counts (``usage`` is then (cpu_ns,
+# voluntary switches) or None, else always None).  A process that
+# never loads the device plane has no such set, and no surface that
+# would show one, and pays one ``is None`` a span.
 _stage_sink = None
+_rusage_stages: frozenset = frozenset()
 
 
-def set_stage_sink(sink) -> None:
-    global _stage_sink
+def set_stage_sink(sink, rusage_stages: frozenset) -> None:
+    global _stage_sink, _rusage_stages
     _stage_sink = sink
+    _rusage_stages = rusage_stages
 
 
 _annotation = None  # jax.profiler.TraceAnnotation, once jax is loaded

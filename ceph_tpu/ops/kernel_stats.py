@@ -18,13 +18,24 @@ plus the compile-cache counters:
 
 and, because this set is the one every co-hosted daemon already
 shares and reports, the stage counters that every finished tracing
-span feeds (common/tracing.py ``Tracer._complete``), one triple per
-span name, and a third counter for the names whose self time has a
-reader (``SELF_TIME_STAGES``):
+span feeds (common/tracing.py ``Tracer._complete``), one pair per
+span name, a third counter for the names whose self time has a reader
+(``SELF_TIME_STAGES``), a fourth for the names whose thread CPU time
+has one (``RUSAGE_STAGES``: a span entered as a context manager and
+finished on its own thread; like ``_ns``, inclusive of its children)
+and a fifth for those of them whose thread's switches have one
+(``HANDOVER_STAGES``):
 
-    l_stage_<name>_count     u64   spans of that name finished
-    l_stage_<name>_ns        u64   their durations, summed (ns)
-    l_stage_<name>_self_ns   u64   durations less same-thread children
+    l_stage_<name>_count      u64   spans of that name finished
+    l_stage_<name>_ns         u64   their durations, summed (ns)
+    l_stage_<name>_self_ns    u64   durations less same-thread children
+    l_stage_<name>_cpu_ns     u64   the thread's CPU time (user + system)
+    l_stage_<name>_handovers  u64   the thread's voluntary context
+                                    switches: the times it gave up its
+                                    core to wait (the interpreter's lock,
+                                    a lock, a socket) and was woken again
+                                    (0 under a kernel that counts none,
+                                    as gVisor's)
 
 and, beside ``l_stage_ec_fold_ns``, the share of it that a packed
 encode spent with its whole upload issued (ops/ec_backend.py
@@ -33,6 +44,14 @@ stripe form, none where the fold comes first), declared with the set
 and not by the calls that count into it:
 
     l_tpu_ec_fold_overlapped_ns   u64
+
+and the whole process's usage, ``getrusage(RUSAGE_SELF)`` read at every
+dump of the set and never on a hot path:
+
+    l_process_cpu_ns          u64   CPU time of every thread (ns)
+    l_process_handovers       u64   voluntary context switches
+    l_process_preemptions     u64   involuntary ones: the scheduler took
+                                    a runnable thread off its core
 
 Groups registered by the instrumented modules: ``ec_encode`` /
 ``ec_decode`` (ec/stripe.py batched seam: one ``timed`` a seam
@@ -61,6 +80,7 @@ same way they share the one JAX runtime.
 from __future__ import annotations
 
 import re
+import resource
 import threading
 import time
 
@@ -80,19 +100,52 @@ from ..common.perf_counters import (
 # span names whose self time is counted: a name goes here with its
 # reader (benchmark/layer_metrics/osd_op_self_ms_per_op.py)
 SELF_TIME_STAGES = frozenset({"osd_op"})
+# span names whose thread CPU time is counted, on the same rule: the op
+# path's (benchmark/layer_metrics/osd_op_cpu_pct.py,
+# ec_seam_cpu_ms_per_op.py) and a remap's host fix-ups
+# (crush_fixup_cpu_pct.py)
+RUSAGE_STAGES = frozenset({
+    "osd_op", "ec_prepare", "ec_encode", "txn_build",
+    "fixup_exists", "fixup_upmap", "fixup_up", "fixup_affinity",
+    "fixup_temp",
+})
+# those whose thread's voluntary switches are counted too: an op's
+# (perf dump; docs/OBSERVABILITY.md "Reading a slow write")
+HANDOVER_STAGES = frozenset({"osd_op"})
 
 _LAT_HIST_BOUNDS = log2_bounds(LATENCY_MIN_S, LATENCY_BUCKETS)
 
 FOLD_OVERLAPPED_NS = "l_tpu_ec_fold_overlapped_ns"
+PROCESS_COUNTERS = (
+    ("l_process_cpu_ns", "CPU time of the process's threads (ns)"),
+    ("l_process_handovers", "voluntary context switches of the process"),
+    ("l_process_preemptions", "involuntary context switches of the process"),
+)
+
+
+class _KernelCounters(PerfCounters):
+    """The set, with the process's usage read in at each dump (perf
+    dump, the MMgrReport, the benchmark's window)."""
+
+    def dump(self) -> dict:
+        ru = resource.getrusage(resource.RUSAGE_SELF)
+        usage = (
+            int((ru.ru_utime + ru.ru_stime) * 1e9), ru.ru_nvcsw, ru.ru_nivcsw,
+        )
+        with self._lock:
+            for (name, _what), value in zip(PROCESS_COUNTERS, usage):
+                self._counters[name].value = value
+        return super().dump()
 
 
 class KernelStats:
     def __init__(self, name: str = "tpu_kernels"):
-        self.perf = PerfCounters(name)
+        self.perf = _KernelCounters(name)
         self._lock = threading.Lock()
         self._cache_call_lock = threading.Lock()
         self._groups: set[str] = set()
-        # span name -> its l_stage_* counters (count, ns, self_ns|None)
+        # span name -> its l_stage_* counters (count, ns, self_ns|None,
+        # cpu_ns|None, handovers|None)
         self._stages: dict[str, tuple] = {}
         self._ensure_counter("l_tpu_compile_cache_hit", PERFCOUNTER_U64,
                              "device bitmatrix/table cache hits")
@@ -110,6 +163,8 @@ class KernelStats:
             FOLD_OVERLAPPED_NS, PERFCOUNTER_U64,
             "ec_fold time spent with the call's upload issued (ns)"
         )
+        for counter, what in PROCESS_COUNTERS:
+            self._ensure_counter(counter, PERFCOUNTER_U64, what)
 
     def _ensure_counter(
         self, name: str, kind: str, desc: str, bounds: tuple = ()
@@ -188,41 +243,61 @@ class KernelStats:
             )
         return out
 
-    def record_stage(self, name: str, ns: int, self_ns: int) -> None:
+    def record_stage(
+        self, name: str, ns: int, self_ns: int, usage: tuple | None = None
+    ) -> None:
         """One finished tracing span of ``name`` (the sink
         common/tracing.py calls, on every span of the process): count
         it and add its duration (and, for a ``SELF_TIME_STAGES``
-        name, its self time) to the ``l_stage_<name>_*`` counters,
-        registered on first sight — one lock acquisition for all."""
+        name, its self time; for a ``RUSAGE_STAGES`` one, the CPU time
+        its thread used, where it read it — ``usage`` is ``(cpu_ns,
+        handovers)`` — and for a ``HANDOVER_STAGES`` one its switches) to
+        the ``l_stage_<name>_*`` counters, registered on first sight —
+        one lock acquisition for all."""
         stage = self._stages.get(name)
         if stage is None:
             stage = self._ensure_stage(name)
-        count, total, own = stage
+        count, total, own, cpu, handovers = stage
         with self.perf._lock:
             count.value += 1
             total.value += ns
             if own is not None:
                 own.value += self_ns
+            if usage is not None and cpu is not None:
+                cpu.value += usage[0]
+                if handovers is not None:
+                    handovers.value += usage[1]
 
     def _ensure_stage(self, name: str) -> tuple:
         base = "l_stage_" + re.sub(r"\W", "_", name)
         wanted = [
-            (f"{base}_count", "spans finished"),
-            (f"{base}_ns", "span durations, summed (ns)"),
+            (f"{base}_count", "spans finished", True),
+            (f"{base}_ns", "span durations, summed (ns)", True),
+            (
+                f"{base}_self_ns",
+                "span durations less same-thread child spans (ns)",
+                name in SELF_TIME_STAGES,
+            ),
+            (
+                f"{base}_cpu_ns",
+                "CPU time of the span's thread over the span (ns)",
+                name in RUSAGE_STAGES,
+            ),
+            (
+                f"{base}_handovers",
+                "voluntary context switches of the span's thread",
+                name in HANDOVER_STAGES,
+            ),
         ]
-        if name in SELF_TIME_STAGES:
-            wanted.append(
-                (
-                    f"{base}_self_ns",
-                    "span durations less same-thread child spans (ns)",
+        for counter, what, kept in wanted:
+            if kept:
+                self._ensure_counter(
+                    counter, PERFCOUNTER_U64, f"{name} {what}"
                 )
-            )
-        for counter, what in wanted:
-            self._ensure_counter(
-                counter, PERFCOUNTER_U64, f"{name} {what}"
-            )
-        stage = tuple(self.perf._counters[n] for n, _ in wanted)
-        stage += (None,) * (3 - len(stage))
+        stage = tuple(
+            self.perf._counters[n] if kept else None
+            for n, _what, kept in wanted
+        )
         with self._lock:
             self._stages[name] = stage
         return stage
@@ -249,38 +324,6 @@ class KernelStats:
 
     def dump(self) -> dict:
         return self.perf.dump()
-
-    def snapshot(self) -> dict:
-        """Compact rollup for result artifacts (JSON-embeddable as it
-        is): compile-cache hit ratio plus per-group call/byte totals —
-        kernel behavior, not just GB/s.  No program reads it today
-        (ROADMAP D4)."""
-        dump = self.dump()
-        hits = int(dump.get("l_tpu_compile_cache_hit", 0))
-        misses = int(dump.get("l_tpu_compile_cache_miss", 0))
-        lookups = hits + misses
-        groups = {}
-        with self._lock:
-            known = sorted(self._groups)
-        for group in known:
-            base = f"l_tpu_{group}"
-            lat = dump.get(f"{base}_lat") or {}
-            groups[group] = {
-                "calls": int(dump.get(f"{base}_calls", 0)),
-                "bytes_in": int(dump.get(f"{base}_bytes_in", 0)),
-                "bytes_out": int(dump.get(f"{base}_bytes_out", 0)),
-                "lat_sum_s": round(float(lat.get("sum", 0.0)), 6),
-            }
-        return {
-            "compile_cache": {
-                "hits": hits,
-                "misses": misses,
-                "hit_ratio": (
-                    round(hits / lookups, 4) if lookups else None
-                ),
-            },
-            "groups": groups,
-        }
 
 
 class _KernelTimer:
@@ -322,10 +365,12 @@ def kernel_stats() -> KernelStats:
     return _instance
 
 
-def _record_stage(name: str, ns: int, self_ns: int) -> None:
-    kernel_stats().record_stage(name, ns, self_ns)
+def _record_stage(
+    name: str, ns: int, self_ns: int, usage: tuple | None
+) -> None:
+    kernel_stats().record_stage(name, ns, self_ns, usage)
 
 
 # from the moment the device plane is loaded, every finished span of
 # the process counts into the shared set
-tracing.set_stage_sink(_record_stage)
+tracing.set_stage_sink(_record_stage, RUSAGE_STAGES)

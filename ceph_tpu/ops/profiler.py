@@ -34,7 +34,7 @@ and the admin socket, plus unbounded per-kind totals behind
 ``summary()`` and ``totals()`` (the benchmark's harness diffs them
 over its window: ``dispatch.<kind>.<field>``).
 
-Three surfaces ride one instrumentation:
+Two more surfaces ride the same instrumentation:
 
 - tracing — every stage opens a ``dev_upload``/``dev_compute``/
   ``dev_sync`` child span of the ambient op span (a no-op off the
@@ -45,10 +45,7 @@ Three surfaces ride one instrumentation:
   /metrics with no new plumbing; a dispatch opened with a ``group``
   feeds that group's ``l_tpu_<group>_*`` kernel counters from the same
   commit, so an EC dispatch site has this one instrument and no
-  stopwatch of its own;
-- rollups — :func:`breakdown` diffs two ``totals()`` snapshots into
-  ``transfer_ms``/``compute_ms``/``sync_ms``/``occupancy``/
-  ``pad_waste_ratio``/``resident_byte_ratio``.
+  stopwatch of its own.
 """
 
 from __future__ import annotations
@@ -463,39 +460,6 @@ def _derive(t: dict) -> dict:
             if bytes_in
             else 0.0
         ),
-    }
-
-
-def breakdown(
-    before: dict, after: dict, backend: str = "jax"
-) -> dict:
-    """Diff two :meth:`DispatchProfiler.totals` snapshots into one
-    window's rollup.  ALWAYS carries the six
-    contract keys (``transfer_ms``/``compute_ms``/``sync_ms``/
-    ``occupancy``/``pad_waste_ratio``/``resident_byte_ratio``) plus
-    the ``backend`` marker — on a JAX_PLATFORMS=cpu run the values are
-    the host-entry walls (or zero), never missing keys."""
-    agg = {f: 0 for f in _TOTAL_FIELDS}
-    kinds: dict[str, dict] = {}
-    for kind, a in sorted(after.items()):
-        b = before.get(kind, {})
-        d = {f: a.get(f, 0) - b.get(f, 0) for f in _TOTAL_FIELDS}
-        if d["dispatches"] <= 0:
-            continue
-        kinds[kind] = _derive(d)
-        for f in _TOTAL_FIELDS:
-            agg[f] += d[f]
-    rolled = _derive(agg)
-    return {
-        "backend": backend,
-        "dispatches": rolled["dispatches"],
-        "transfer_ms": rolled["transfer_ms"],
-        "compute_ms": rolled["compute_ms"],
-        "sync_ms": rolled["sync_ms"],
-        "occupancy": rolled["occupancy"],
-        "pad_waste_ratio": rolled["pad_waste_ratio"],
-        "resident_byte_ratio": rolled["resident_byte_ratio"],
-        "kinds": kinds,
     }
 
 

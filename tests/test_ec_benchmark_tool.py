@@ -16,8 +16,16 @@ COMMON = ["-p", "jerasure", "-P", "technique=reed_sol_van", "-P", "k=4",
           "-P", "m=2", "-P", "backend=jax", "-s", "16384"]
 
 
-def _records(kind):
-    return dispatch_profiler().history(kind)["entries"]
+def _last_seq():
+    entries = dispatch_profiler().history()["entries"]
+    return max((e["seq"] for e in entries), default=0)
+
+
+def _records(kind, since):
+    """The ring's records of ``kind`` after ``since``: the ring drops
+    its oldest, so its length is no count of dispatches."""
+    entries = dispatch_profiler().history(kind)["entries"]
+    return [e for e in entries if e["seq"] > since]
 
 
 def _run(capsys, argv):
@@ -38,29 +46,29 @@ def test_batch_is_one_dispatch_of_the_stripe_seam_an_iteration(
     seam = getattr(stripe, workload)
     monkeypatch.setattr(
         stripe, workload, lambda *a, **kw: calls.append(1) or seam(*a, **kw))
-    seen = len(_records(kind))
+    seen = _last_seq()
     seconds, kb = _run(capsys, ["-w", workload, "-i", "3", "--batch", "4"] + extra)
     assert seconds > 0 and kb == 3 * 4 * 16
     # three timed iterations and the untimed one before the clock
     assert len(calls) == 4
-    new = _records(kind)[seen:]
+    new = _records(kind, seen)
     assert len(new) == 4
     assert all((r["backend"], r["ops"], r["stripes"]) == ("jax", 1, 4) for r in new)
 
 
 def test_exhaustive_decode_verifies_every_pair_through_the_seam(capsys):
-    seen = len(_records("ec_decode"))
+    seen = _last_seq()
     _seconds, kb = _run(capsys, ["-w", "decode", "-i", "1", "--batch", "2",
                                  "-e", "2", "-E", "exhaustive"])
     assert kb == 2 * 16
-    assert len(_records("ec_decode")) - seen == 2 * 15  # untimed + timed, 15 pairs
+    assert len(_records("ec_decode", seen)) == 2 * 15  # untimed + timed, 15 pairs
 
 
 def test_without_batch_the_plugin_is_called_as_upstream_calls_it(capsys):
-    seen = len(_records("ec_encode"))
+    seen = _last_seq()
     _seconds, kb = _run(capsys, ["-w", "encode", "-i", "2"])
     assert kb == 2 * 16
-    assert len(_records("ec_encode")) == seen  # ec.encode, not the seam
+    assert _records("ec_encode", seen) == []  # ec.encode, not the seam
     _seconds, kb = _run(capsys, ["-w", "decode", "-i", "2", "-e", "1"])
     assert kb == 2 * 16
 

@@ -156,26 +156,6 @@ def test_kernel_stats_counter_shapes_in_perf_dump():
     assert dump["l_tpu_compile_cache_miss"] >= 1
 
 
-def test_kernel_stats_snapshot_rollup():
-    """kernel_stats().snapshot(): compile-cache hit ratio plus
-    per-group call/byte totals, embeddable in a JSON line as it is."""
-    ks = kernel_stats()
-    ks.record("ec_encode", bytes_in=1024, bytes_out=2048, seconds=0.01)
-    ks.record_cache(3, 1)
-    snap = ks.snapshot()
-    cache = snap["compile_cache"]
-    assert cache["hits"] >= 3 and cache["misses"] >= 1
-    assert cache["hit_ratio"] is not None
-    assert 0.0 <= cache["hit_ratio"] <= 1.0
-    enc = snap["groups"]["ec_encode"]
-    assert enc["calls"] >= 1
-    assert enc["bytes_in"] >= 1024 and enc["bytes_out"] >= 2048
-    assert enc["lat_sum_s"] > 0
-    import json as _json
-
-    _json.dumps(snap)  # must be JSON-line embeddable as-is
-
-
 def test_crush_mapping_kernel_counters():
     from ceph_tpu.osd.mapping import OSDMapMapping
 

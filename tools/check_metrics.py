@@ -131,7 +131,8 @@ DISPATCH_COUNTERS = (
 # WAL's barrier, apply and checkpoint, the block store's fsyncs) —
 # feeds
 # l_stage_<name>_{count,ns} (and _self_ns for a kernel_stats
-# SELF_TIME_STAGES name) that the benchmark's per-layer readers and
+# SELF_TIME_STAGES name, _cpu_ns for a RUSAGE_STAGES one, _handovers
+# for a HANDOVER_STAGES one) that the benchmark's per-layer readers and
 # /metrics read
 STAGE_SPANS = (
     "client_aio_wait", "client_op", "msgr_send", "msgr_recv",
@@ -142,6 +143,15 @@ STAGE_SPANS = (
     "fixup_upmap", "fixup_up", "fixup_affinity", "fixup_temp",
     "ec_fold", "ec_unfold", "ec_assemble", "ec_plan",
     "wal_barrier", "wal_apply", "wal_checkpoint", "store_fsync",
+)
+# the process's own usage the kernel-stats schema declares at
+# construction and refreshes at every dump (ops/kernel_stats.py
+# PROCESS_COUNTERS — the benchmark's host_cores_busy reads the first,
+# perf dump and /metrics show all three)
+PROCESS_COUNTERS = (
+    "l_process_cpu_ns",
+    "l_process_handovers",
+    "l_process_preemptions",
 )
 # sharded bucket-index + reshard families the RGW schema must
 # declare (rgw/index.py build_rgw_perf — the bench rgw_index section
@@ -622,20 +632,37 @@ def check_stage_counters() -> list[str]:
     """The tracing plane's stage family, through the REAL sink: a
     finished span of every product span name must register its
     ``l_stage_<name>_*`` counters on the kernel set as plain u64s —
-    ``_self_ns`` where the name's self time has a reader and nowhere
-    else (the set itself is linted by check_perf_counters in the
-    schema walk)."""
+    ``_self_ns`` where the name's self time has a reader, ``_cpu_ns``
+    where its thread's CPU time has one, ``_handovers`` where its
+    thread's switches have one, and nowhere else (the set itself is
+    linted by check_perf_counters in the schema walk)."""
     from ceph_tpu.common.perf_counters import PERFCOUNTER_U64
-    from ceph_tpu.ops.kernel_stats import SELF_TIME_STAGES, KernelStats
+    from ceph_tpu.ops.kernel_stats import (
+        HANDOVER_STAGES,
+        RUSAGE_STAGES,
+        SELF_TIME_STAGES,
+        KernelStats,
+    )
 
     ks = KernelStats()
     for name in STAGE_SPANS:
-        ks.record_stage(name, 0, 0)
-    errors = []
+        ks.record_stage(name, 0, 0, (0, 0))
+    errors = [
+        f"kernel schema: RUSAGE_STAGES name {name!r} is no product span"
+        for name in sorted(RUSAGE_STAGES - set(STAGE_SPANS))
+    ] + [
+        f"kernel schema: HANDOVER_STAGES name {name!r} reads no usage"
+        for name in sorted(HANDOVER_STAGES - RUSAGE_STAGES)
+    ]
+    only = {
+        "self_ns": SELF_TIME_STAGES,
+        "cpu_ns": RUSAGE_STAGES,
+        "handovers": HANDOVER_STAGES,
+    }
     for name in STAGE_SPANS:
-        for suffix in ("count", "ns", "self_ns"):
+        for suffix in ("count", "ns", "self_ns", "cpu_ns", "handovers"):
             counter = ks.perf._counters.get(f"l_stage_{name}_{suffix}")
-            wanted = suffix != "self_ns" or name in SELF_TIME_STAGES
+            wanted = suffix not in only or name in only[suffix]
             if (counter is not None) != wanted:
                 errors.append(
                     f"kernel schema: stage counter "
@@ -647,6 +674,26 @@ def check_stage_counters() -> list[str]:
                     f"kernel schema: l_stage_{name}_{suffix} is "
                     f"{counter.kind}, not u64"
                 )
+    return errors
+
+
+def check_process_counters() -> list[str]:
+    """The kernel-stats schema declares the process's usage from
+    construction, as u64s its dump fills."""
+    from ceph_tpu.common.perf_counters import PERFCOUNTER_U64
+    from ceph_tpu.ops.kernel_stats import KernelStats
+
+    ks = KernelStats()
+    dump = ks.dump()
+    errors = []
+    for name in PROCESS_COUNTERS:
+        counter = ks.perf._counters.get(name)
+        if counter is None:
+            errors.append(f"kernel schema: process counter {name!r} missing")
+        elif counter.kind != PERFCOUNTER_U64:
+            errors.append(f"kernel schema: {name} is {counter.kind}, not u64")
+    if not dump.get("l_process_cpu_ns"):
+        errors.append("kernel schema: l_process_cpu_ns reads 0 after a dump")
     return errors
 
 
@@ -1089,6 +1136,7 @@ def check_all(sets=None) -> list[str]:
         errors.extend(check_residency_counters())
         errors.extend(check_dispatch_counters())
         errors.extend(check_stage_counters())
+        errors.extend(check_process_counters())
         errors.extend(check_proc_counters())
         errors.extend(check_thrash_counters())
         errors.extend(check_objecter_counters())
