@@ -27,8 +27,13 @@ from .gf_matmul import (
     gf_matrix_stripes,
     matrix_to_device_bitmatrix,
 )
-from .kernel_stats import FOLD_OVERLAPPED_NS, kernel_stats
+from .kernel_stats import DECODE_PACKED_CALLS, FOLD_OVERLAPPED_NS, kernel_stats
 from .profiler import dispatch_profiler, record_pad
+
+# the largest r·s the packed decode kernel takes: its work is r·s·8
+# masked XORs a word on the VPU, where a larger matrix (CLAY's 64 x 176
+# repair) is MXU work for the bitplane program
+PACKED_DECODE_TERMS = 64
 
 
 def _on_tpu() -> bool:
@@ -285,13 +290,21 @@ class JaxBackend:
         host arrays.  Recorded as kind ``ec_decode`` (ops 1,
         ``stripes``), upload / issue / fetch bracketed as their stages.
 
-        The matrix is an OPERAND of the bitplane program
-        (``gf_matrix_words``): a reconstruction matrix is one of
-        C(k+m, e) a pool may meet, and the packed-lane kernel unrolls
-        its matrix at trace time — a compile a pattern, inside a
-        client's degraded read (PERF.md section 6, PR 28).  The length
-        buckets to a power of two of stripes, as
-        ``_bitplane_dispatch`` buckets batches.
+        The matrix is an OPERAND of either program, never unrolled: a
+        reconstruction matrix is one of C(k+m, e) a pool may meet, so
+        one program a shape serves every pattern.  Which program runs
+        is read from the operands.  A whole-shard rebuild (``sub_rows``
+        (1, 1)) on the TPU at w=8, of whole 128-word rows after the
+        bucket's pad and a small matrix (r·s at most
+        ``PACKED_DECODE_TERMS``: VPU work), takes the packed-lane
+        kernel (``packed_gf.prebuilt_decode_call``): each survivor
+        goes up as a free ``(nwords/128, 128)`` view, the coefficients
+        are a cached device array, and the call counts in
+        ``l_tpu_ec_decode_packed_calls``.  Everything else — another
+        word size, a ragged row, a large matrix (CLAY's 64 x 176: MXU
+        work), the CPU — takes the bitplane program
+        (``gf_matrix_words``).  The length buckets to a power of two
+        of stripes, as ``_bitplane_dispatch`` buckets batches.
 
         ``sub_rows`` = (rows in, rows out) is the row shape of a
         fractional repair (``stripe.repair``): a stripe of a payload
@@ -312,32 +325,52 @@ class JaxBackend:
         n4 = len(rows[0])
         out4 = n4 // rows_in * rows_out
         total = 4 * n4 * len(rows)
+        bucket = bucket_pow2(stripes)
+        pad = (bucket - stripes) * (n4 // stripes)
+        lanes = packed_gf.LANES
+        packed = (
+            w == 8
+            and sub_rows == (1, 1)
+            and n4 % lanes == 0
+            and pad % lanes == 0
+            and matrix.size <= PACKED_DECODE_TERMS
+            and _on_tpu()
+        )
         with self._dispatch(
             "ec_decode", ops=1, stripes=stripes, bytes_in=total
         ) as dp:
-            bm = matrix_to_device_bitmatrix(matrix, w)
+            if packed:
+                coef = packed_gf.device_coefficients(matrix)
+                rows = [r.reshape(-1, lanes) for r in rows]
+            else:
+                bm = matrix_to_device_bitmatrix(matrix, w)
             dp.add_upload(total)
             with dp.stage("upload"):
                 dev = [jax.device_put(r) for r in rows]
             with dp.stage("compute"):
-                bucket = bucket_pow2(stripes)
-                pad = (bucket - stripes) * (n4 // stripes)
                 if pad:
-                    dev = [jnp.pad(d, (0, pad)) for d in dev]
+                    edge = ((0, pad // lanes), (0, 0)) if packed else (0, pad)
+                    dev = [jnp.pad(d, edge) for d in dev]
                     record_pad(4 * pad * len(dev))
                 note_shape(
                     "ec_shards", n4 + pad, len(dev), len(matrix), w, sub_rows
                 )
-                tile = (
-                    None
-                    if sub_rows == (1, 1)
-                    else (bucket, rows_in, rows_out)
-                )
-                odev = gf_matrix_words(bm, tuple(dev), w=w, tile=tile)
+                if packed:
+                    odev = packed_gf.prebuilt_decode_call(
+                        len(matrix), len(dev)
+                    )(coef, *dev)
+                    kernel_stats().perf.inc(DECODE_PACKED_CALLS)
+                else:
+                    tile = (
+                        None
+                        if sub_rows == (1, 1)
+                        else (bucket, rows_in, rows_out)
+                    )
+                    odev = gf_matrix_words(bm, tuple(dev), w=w, tile=tile)
             with dp.stage("sync"):
                 out = np.asarray(odev)
             dp.set_bytes_out(4 * out4 * len(out))
-        return [row[:out4].view(np.uint8) for row in out]
+        return [row.reshape(-1)[:out4].view(np.uint8) for row in out]
 
     def matrix_stripes_batch(
         self,

@@ -45,6 +45,12 @@ and not by the calls that count into it:
 
     l_tpu_ec_fold_overlapped_ns   u64
 
+and the whole-shard rebuilds that took the packed decode kernel
+(ops/ec_backend.py ``matrix_shards``; the others take the bitplane
+program), declared the same way:
+
+    l_tpu_ec_decode_packed_calls  u64
+
 and the whole process's usage, ``getrusage(RUSAGE_SELF)`` read at every
 dump of the set and never on a hot path:
 
@@ -116,6 +122,7 @@ HANDOVER_STAGES = frozenset({"osd_op"})
 _LAT_HIST_BOUNDS = log2_bounds(LATENCY_MIN_S, LATENCY_BUCKETS)
 
 FOLD_OVERLAPPED_NS = "l_tpu_ec_fold_overlapped_ns"
+DECODE_PACKED_CALLS = "l_tpu_ec_decode_packed_calls"
 PROCESS_COUNTERS = (
     ("l_process_cpu_ns", "CPU time of the process's threads (ns)"),
     ("l_process_handovers", "voluntary context switches of the process"),
@@ -162,6 +169,10 @@ class KernelStats:
         self._ensure_counter(
             FOLD_OVERLAPPED_NS, PERFCOUNTER_U64,
             "ec_fold time spent with the call's upload issued (ns)"
+        )
+        self._ensure_counter(
+            DECODE_PACKED_CALLS, PERFCOUNTER_U64,
+            "whole-shard rebuilds run by the packed decode kernel"
         )
         for counter, what in PROCESS_COUNTERS:
             self._ensure_counter(counter, PERFCOUNTER_U64, what)

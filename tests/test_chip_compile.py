@@ -153,14 +153,45 @@ def test_packed_decode_kernel_two_erasures(one_chip):
     ],
 )
 def test_shard_form_decode_program(one_chip, rebuilt, survivors, nbytes):
-    """What matrix_shards runs for ec/stripe.decode: the survivors as
-    uint32 words, the reconstruction bitmatrix an operand."""
+    """What matrix_shards runs for ec/stripe.decode off the packed
+    kernel's shapes (another word size, rows that are not whole 128
+    words): the survivors as uint32 words, the reconstruction
+    bitmatrix an operand."""
     compiled = gf_matmul.gf_matrix_words.lower(
         _sds((rebuilt * 8, survivors * 8), jnp.int8, one_chip),
         tuple(_sds((nbytes // 4,), jnp.uint32, one_chip) for _ in range(survivors)),
         w=8,
     ).compile()
     assert "u8[" not in compiled.as_text()  # no byte array to re-tile
+    _fits(compiled)
+
+
+@pytest.mark.parametrize(
+    "rebuilt,survivors,nbytes",
+    [
+        (2, 8, 64 * 131072),  # the decode cell: 64 x 1 MiB, two lost of k=8 m=3
+        (1, 4, 256 * 4096),  # a degraded read of one 4 MiB object of the k=4 m=2 pool
+    ],
+)
+def test_packed_decode_program(one_chip, rebuilt, survivors, nbytes):
+    """What matrix_shards runs for a whole-shard rebuild on the chip:
+    the coefficients an operand (a shape, never values), each survivor
+    a (nwords/128, 128) u32 view — the program is the one kernel, with
+    no byte array, no copy or transpose of an operand, no temporaries."""
+    rows = nbytes // 512
+    compiled = packed_gf.prebuilt_decode_call(rebuilt, survivors).lower(
+        _sds((rebuilt * survivors,), jnp.int32, one_chip),
+        *[_sds((rows, 128), jnp.uint32, one_chip)] * survivors,
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("custom-call(") == 1
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "u8[" not in text
+    assert not re.search(r"\b(copy|transpose)(\.\d+)? = ", text)
+    assert not re.search(r" (copy|transpose)\(", text)
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes == 0
+    assert compiled.out_info.shape == (rebuilt, rows, 128)
     _fits(compiled)
 
 

@@ -8,6 +8,8 @@ oracle for encode AND decode matrices, including the padding path.
 
 from __future__ import annotations
 
+import itertools
+
 import numpy as np
 import pytest
 
@@ -127,6 +129,87 @@ def test_stripes_layout(monkeypatch):
     for s in range(5):
         want = matrix_vector_mul_region(mat, stripes[s], 8)
         np.testing.assert_array_equal(got[s], want)
+
+
+def _rebuild_rows(k, m, lost, matrix=None):
+    """The (len(lost), k) rows ``stripe.decode`` hands the backend."""
+    from ceph_tpu.ec.stripe import reconstruction_rows
+
+    if matrix is None:
+        matrix = reed_sol_vandermonde_coding_matrix(k, m, 8)
+    avail = [p for p in range(k + m) if p not in lost]
+    rows, _survivors = reconstruction_rows(
+        np.asarray(matrix), list(lost), avail, k, 8)
+    return rows
+
+
+def _check_decode(rows, nbytes):
+    r, s = rows.shape
+    regions = rng.integers(0, 256, (s, nbytes), dtype=np.uint8)
+    call = packed_gf.prebuilt_decode_call(r, s, interpret=True)
+    out = call(
+        packed_gf.device_coefficients(rows),
+        *[x.view(np.uint32).reshape(-1, packed_gf.LANES) for x in regions],
+    )
+    assert out.shape == (r, nbytes // 512, packed_gf.LANES)
+    got = np.asarray(out).reshape(r, -1).view(np.uint8)
+    np.testing.assert_array_equal(
+        got, matrix_vector_mul_region(rows, regions, 8))
+
+
+@pytest.mark.parametrize(
+    "lost", [pytest.param(p, id=f"{p[0]}-{p[1]}")
+             for p in itertools.combinations(range(11), 2)])
+def test_decode_kernel_rebuilds_every_pair_of_k8m3(lost):
+    """The rebuild's kernel, its coefficients an operand, against the
+    field oracle for each of the 55 two-erasure patterns of jerasure
+    k=8 m=3 (the ``decode_2e_1m`` cell's): 9 rows of 128 words, an
+    edge block past the last."""
+    _check_decode(_rebuild_rows(8, 3, lost), 512 * 9)
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["k4m2_one_lost", "isa_k10m4_four_lost", "zero_and_one", "tall_block"],
+)
+def test_decode_kernel_other_shapes(case):
+    if case == "k4m2_one_lost":
+        rows, nbytes = _rebuild_rows(4, 2, (1,)), 512 * 8
+    elif case == "isa_k10m4_four_lost":
+        rows = _rebuild_rows(10, 4, (0, 3, 10, 13), isa_cauchy_matrix(10, 4))
+        nbytes = 512 * 3
+    elif case == "zero_and_one":
+        rows, nbytes = np.array([[0, 1, 0, 1], [1, 1, 0, 0]]), 512 * 2
+    else:  # more rows than a block takes: two grid steps and an edge
+        rows = _rebuild_rows(8, 3, (0, 9))
+        nbytes = 4 * (packed_gf.TILE_WORDS + 3 * packed_gf.LANES)
+    assert rows.shape[0] == {"k4m2_one_lost": 1, "isa_k10m4_four_lost": 4}.get(
+        case, 2)
+    _check_decode(np.asarray(rows), nbytes)
+
+
+def test_one_decode_program_serves_every_pattern_of_a_shape():
+    """The coefficients are data: two patterns of one shape trace one
+    program, and each call is its own pattern's rebuild."""
+    call = packed_gf.prebuilt_decode_call(2, 8, interpret=True)
+    regions = rng.integers(0, 256, (8, 512 * 5), dtype=np.uint8)
+    words = [x.view(np.uint32).reshape(-1, packed_gf.LANES) for x in regions]
+    before = call._cache_size()
+    for lost in [(2, 9), (0, 1)]:
+        rows = _rebuild_rows(8, 3, lost)
+        got = np.asarray(call(packed_gf.device_coefficients(rows), *words))
+        np.testing.assert_array_equal(
+            got.reshape(2, -1).view(np.uint8),
+            matrix_vector_mul_region(rows, regions, 8))
+    assert call._cache_size() == before + 1
+
+
+def test_device_coefficients_are_cached_by_value():
+    rows = _rebuild_rows(8, 3, (4, 7))
+    a = packed_gf.device_coefficients(rows)
+    assert packed_gf.device_coefficients(rows.copy()) is a
+    assert a.dtype == np.int32 and a.shape == (16,)
+    np.testing.assert_array_equal(np.asarray(a), rows.reshape(-1))
 
 
 def test_supports_guard():

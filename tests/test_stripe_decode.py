@@ -1,7 +1,8 @@
 """The B-stripe decode of the stripe seam (ec/stripe.decode, and
 decode_concat on it): one recorded dispatch an object for matrix
-codes, the per-stripe loop — recorded too — for the others, and the
-packed encode path's stage brackets."""
+codes, the per-stripe loop — recorded too — for the others, which
+program a rebuild takes on the chip, and the packed encode path's
+stage brackets."""
 
 from __future__ import annotations
 
@@ -251,6 +252,114 @@ def test_the_stage_helper_counts_with_and_without_an_ambient_tracer():
         assert child.parent_id and child.daemon == "osd.test"
     assert _stage_counts("probe_stage") == [before[0] + 2]
     assert [s["name"] for s in tracer.dump_traces()["spans"]] == ["probe_stage", "osd_op"]
+
+
+def _route_through_the_interpreter(monkeypatch):
+    """The chip-only choice of ``matrix_shards``, made here: the
+    backend thinks it is on the TPU and the packed decode kernel runs
+    in its interpreter.  Returns the lists the two programs' calls
+    are recorded in: (packed (r, s), bitplane)."""
+    from ceph_tpu.ops import gf_matmul
+
+    monkeypatch.setattr(ec_backend, "_on_tpu", lambda: True)
+    packed, bitplane = [], []
+    built = packed_gf.prebuilt_decode_call
+    monkeypatch.setattr(
+        packed_gf, "prebuilt_decode_call",
+        lambda r, s: packed.append((r, s)) or built(r, s, interpret=True))
+    words = gf_matmul.gf_matrix_words
+    monkeypatch.setattr(
+        gf_matmul, "gf_matrix_words",
+        lambda *a, **kw: bitplane.append(1) or words(*a, **kw))
+    return packed, bitplane
+
+
+def _packed_count():
+    return kernel_stats().dump()["l_tpu_ec_decode_packed_calls"]
+
+
+@pytest.mark.parametrize("plugin,profile,lost,nstripes", [
+    ("jerasure", dict(technique="reed_sol_van", k="8", m="3", w="8"), (2, 9), 4),
+    ("jerasure", dict(technique="reed_sol_van", k="8", m="3", w="8"), (0, 7), 3),
+    ("isa", dict(k="4", m="2"), (1,), 5),
+])
+def test_a_whole_shard_rebuild_takes_the_packed_kernel(
+        monkeypatch, plugin, profile, lost, nstripes):
+    """Whole 128-word rows at w=8 on the TPU: ``stripe.decode`` runs
+    the packed decode kernel (3 and 5 stripes through the bucket's
+    pad), byte for byte the per-stripe ``ec._decode``, and counts it."""
+    ec = _code(plugin, backend="jax", **profile)
+    k = ec.get_data_chunk_count()
+    chunk = 512
+    sinfo = StripeInfo(k, k * chunk)
+    data = np.random.default_rng(nstripes).integers(
+        0, 256, nstripes * k * chunk, dtype=np.uint8)
+    shards = encode(sinfo, ec, data)
+    have = {p: s for p, s in shards.items() if p not in lost}
+    per_stripe = {  # the oracle, taken before the routing is patched
+        p: np.concatenate([
+            ec._decode({p}, {q: v[s * chunk:(s + 1) * chunk] for q, v in have.items()})[p]
+            for s in range(nstripes)])
+        for p in lost}
+    packed, bitplane = _route_through_the_interpreter(monkeypatch)
+    before = _packed_count()
+    seen = len(_records("ec_decode"))
+    got = decode(sinfo, ec, have, lost)
+    assert packed == [(len(lost), k)] and bitplane == []
+    assert _packed_count() == before + 1
+    (rec,) = _records("ec_decode")[seen:]
+    assert (rec["backend"], rec["ops"], rec["stripes"]) == ("jax", 1, nstripes)
+    assert rec["bytes_uploaded"] == rec["bytes_in"] == k * nstripes * chunk
+    for p in lost:
+        np.testing.assert_array_equal(got[p], per_stripe[p])
+        np.testing.assert_array_equal(got[p], shards[p])
+
+
+@pytest.mark.parametrize("why", ["clay_repair", "w16", "ragged_rows"])
+def test_other_shapes_keep_the_bitplane_program(monkeypatch, why):
+    """A CLAY sub-chunk repair (a 64 x 176 matrix: MXU work), w=16, and
+    a payload that is not whole 128-word rows take ``gf_matrix_words``
+    on the TPU too, and do not count as packed."""
+    if why == "clay_repair":
+        from ceph_tpu.ec.stripe import repair
+
+        ec = registry_instance().factory(
+            "clay", ErasureCodeProfile(backend="jax", k="8", m="4", d="11"))
+        cs = ec.get_sub_chunk_count() * 32
+        sinfo = StripeInfo(8, 8 * cs)
+        data = np.random.default_rng(5).integers(0, 256, 2 * 8 * cs, dtype=np.uint8)
+        shards = encode(sinfo, ec, data)
+        sc = cs // ec.get_sub_chunk_count()
+        minimum = ec.minimum_to_decode({3}, set(range(12)) - {3})
+        fragments = {
+            h: np.concatenate([
+                shards[h][s * cs + off * sc: s * cs + (off + cnt) * sc]
+                for s in range(2) for off, cnt in runs])
+            for h, runs in minimum.items()}
+        ec.repair_matrix(3, set(fragments))  # the probe, on the host
+        want, lost = {3: shards[3]}, 3
+
+        def rebuild():
+            return {3: repair(sinfo, ec, fragments, 3)}
+    else:
+        w, chunk = ("16", 512) if why == "w16" else ("8", 256)
+        ec = _code(technique="reed_sol_van", k="4", m="2", w=w, backend="jax")
+        sinfo = StripeInfo(4, 4 * chunk)
+        data = np.random.default_rng(6).integers(0, 256, 3 * 4 * chunk, dtype=np.uint8)
+        shards = encode(sinfo, ec, data)
+        have = {p: s for p, s in shards.items() if p not in (0, 4)}
+        want = {p: shards[p] for p in (0, 4)}
+
+        def rebuild():
+            return decode(sinfo, ec, have, (0, 4))
+    packed, bitplane = _route_through_the_interpreter(monkeypatch)
+    before = _packed_count()
+    got = rebuild()
+    assert sorted(got) == sorted(want)
+    for p in want:
+        np.testing.assert_array_equal(got[p], want[p])
+    assert packed == [] and bitplane == [1]
+    assert _packed_count() == before
 
 
 def test_the_packed_encode_path_brackets_its_stages(monkeypatch):

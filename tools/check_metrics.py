@@ -697,6 +697,31 @@ def check_process_counters() -> list[str]:
     return errors
 
 
+def check_kernel_declared_counters() -> list[str]:
+    """The packed kernels' counters are declared with the kernel-stats
+    set, not by the calls that count into them: every daemon's dump
+    has them, as u64s at 0 where no such call ran."""
+    from ceph_tpu.common.perf_counters import PERFCOUNTER_U64
+    from ceph_tpu.ops.kernel_stats import (
+        DECODE_PACKED_CALLS,
+        FOLD_OVERLAPPED_NS,
+        KernelStats,
+    )
+
+    ks = KernelStats()
+    dump = ks.dump()
+    errors = []
+    for name in (FOLD_OVERLAPPED_NS, DECODE_PACKED_CALLS):
+        counter = ks.perf._counters.get(name)
+        if counter is None or dump.get(name) != 0:
+            errors.append(
+                f"kernel schema: {name} is not declared at 0 with the set"
+            )
+        elif counter.kind != PERFCOUNTER_U64:
+            errors.append(f"kernel schema: {name} is not a u64")
+    return errors
+
+
 def product_event_samples() -> list[str]:
     """Generate one real clog entry and one real crash report through
     the product code paths and lint them — the schemas daemons
@@ -1085,8 +1110,9 @@ def product_counter_sets():
         ks.record(group)
     for suffix in ("pgs", "fallback_lanes", "host_ns", "host_overlapped_ns"):
         ks.counter("crush", suffix)
-    # the packed encode's l_tpu_ec_fold_overlapped_ns is declared by
-    # KernelStats itself, beside the compile-cache pair
+    # the packed encode's l_tpu_ec_fold_overlapped_ns and the packed
+    # decode's l_tpu_ec_decode_packed_calls are declared by KernelStats
+    # itself, beside the compile-cache pair (check_kernel_declared_counters)
     # residency + coalesced-encode families (ops/residency.py) join
     # the schema walk and the cross-set collision lint
     ensure_counters(ks)
@@ -1137,6 +1163,7 @@ def check_all(sets=None) -> list[str]:
         errors.extend(check_dispatch_counters())
         errors.extend(check_stage_counters())
         errors.extend(check_process_counters())
+        errors.extend(check_kernel_declared_counters())
         errors.extend(check_proc_counters())
         errors.extend(check_thrash_counters())
         errors.extend(check_objecter_counters())
